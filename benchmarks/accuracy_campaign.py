@@ -20,7 +20,7 @@ from __future__ import annotations
 
 import argparse
 
-from benchmarks.common import csv_line, emit
+from benchmarks.common import csv_line, emit, use_compile_cache
 from repro.core import campaign
 
 
@@ -103,4 +103,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -51,7 +51,7 @@ import numpy as np
 
 import jax.numpy as jnp
 
-from benchmarks.common import csv_line, emit, timed
+from benchmarks.common import csv_line, emit, timed, use_compile_cache
 from repro import codes
 from repro.core import scenario, sweep, voltage
 from repro.kernels import ops, paged_gather
@@ -326,4 +326,5 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

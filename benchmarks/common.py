@@ -7,6 +7,25 @@ import os
 import time
 
 OUT_DIR = os.path.join(os.path.dirname(__file__), "out")
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is honored as JAX reads it and
+    no other directory is set here. Otherwise the cache lives at the fixed
+    ``<repo>/.jax_cache`` (gitignored): the path is part of the cache key, so
+    it must never come from a temporary name, a process id or the clock.
+    Returns the directory in use.
+    """
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(REPO_ROOT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def timed(fn, *args, repeat=3, **kwargs):

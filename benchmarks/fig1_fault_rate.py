@@ -21,7 +21,7 @@ import time
 
 import numpy as np
 
-from benchmarks.common import csv_line, emit, timed
+from benchmarks.common import csv_line, emit, timed, use_compile_cache
 from repro.core import ecc, sweep, voltage
 from repro.core.faultsim import FaultField
 from repro.core.telemetry import FaultStats
@@ -125,4 +125,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

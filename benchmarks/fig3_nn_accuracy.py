@@ -18,7 +18,7 @@ from __future__ import annotations
 
 import numpy as np
 
-from benchmarks.common import csv_line, emit, timed
+from benchmarks.common import csv_line, emit, timed, use_compile_cache
 from repro.core import campaign, voltage
 from repro.core.nn_accel import EccMLP
 from repro.data import mnist
@@ -96,4 +96,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -1,8 +1,7 @@
 """Pallas kernel micro-benchmarks + roofline model.
 
-Every row is tagged with the kernel backend in force (``compiled`` where the
-platform lowers Pallas for real — TPU Mosaic / GPU Triton — ``interpret``
-elsewhere; kernels/backend.py). On a CPU runner the wall-times are
+Every row is tagged with the kernel backend in force (``compiled`` on TPU,
+``interpret`` on CPU; kernels/backend.py). On a CPU runner the wall-times are
 interpret-lane numbers (NOT TPU performance); the derived
 column reports the *kernel roofline model* for TPU v5e — the quantity used in
 EXPERIMENTS.md §Perf to compare the fused ECC-matmul read path against the
@@ -18,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.common import csv_line, emit, timed
+from benchmarks.common import csv_line, emit, timed, use_compile_cache
 from repro.kernels import backend as kbackend
 from repro.kernels import ops, ref
 
@@ -232,4 +231,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -20,34 +20,27 @@ a regression is a one-line diff against the previous commit's file).
 from __future__ import annotations
 
 import contextlib
+import importlib
 import io
 import json
 import os
 import sys
 import time
 
-from benchmarks import (
-    accuracy_campaign,
-    codec_compare,
-    fig1_fault_rate,
-    fig2_fault_types,
-    fig3_nn_accuracy,
-    kernel_micro,
-    roofline,
-    sharded_scrub,
-    table1_overhead,
-)
+from benchmarks.common import use_compile_cache
 
+# (section, module under benchmarks/). Modules are imported only when their
+# section runs, so this process touches JAX no earlier than it must.
 SECTIONS = [
-    ("fig1", fig1_fault_rate),
-    ("fig2", fig2_fault_types),
-    ("table1", table1_overhead),
-    ("fig3", fig3_nn_accuracy),
-    ("kernels", kernel_micro),
-    ("codecs", codec_compare),
-    ("mesh", sharded_scrub),
-    ("accuracy", accuracy_campaign),
-    ("roofline", roofline),
+    ("fig1", "fig1_fault_rate"),
+    ("fig2", "fig2_fault_types"),
+    ("table1", "table1_overhead"),
+    ("fig3", "fig3_nn_accuracy"),
+    ("kernels", "kernel_micro"),
+    ("codecs", "codec_compare"),
+    ("mesh", "sharded_scrub"),
+    ("accuracy", "accuracy_campaign"),
+    ("roofline", "roofline"),
 ]
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -85,8 +78,9 @@ def write_trajectory(name: str, rows: list[dict], seconds: float,
     return path
 
 
-def run_section(name: str, mod) -> list[dict]:
+def run_section(name: str, module: str) -> list[dict]:
     """Run one section, tee its CSV output, write its trajectory file."""
+    mod = importlib.import_module(f"benchmarks.{module}")
     t0 = time.time()
     print(f"# === {name} ===")
     buf = io.StringIO()
@@ -107,11 +101,12 @@ def run_section(name: str, mod) -> list[dict]:
 
 def main() -> None:
     only = sys.argv[1] if len(sys.argv) > 1 else None
+    use_compile_cache()
     print("name,us_per_call,derived")
-    for name, mod in SECTIONS:
+    for name, module in SECTIONS:
         if only and name != only:
             continue
-        run_section(name, mod)
+        run_section(name, module)
 
 
 if __name__ == "__main__":

@@ -40,7 +40,7 @@ import time
 
 import numpy as np
 
-from benchmarks.common import csv_line, emit
+from benchmarks.common import csv_line, emit, use_compile_cache
 
 N_LANES = 4
 MAX_LEN = 72
@@ -278,4 +278,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -13,7 +13,10 @@ Weak scaling (fixed per-shard slice) is the wrong experiment on a
 shared-cache host: total footprint then grows with the device count and the
 curve measures which sweep points happen to fit the cache hierarchy, not the
 scrub step. Each device count runs in its own subprocess
-(``--xla_force_host_platform_device_count`` is locked at jax init).
+(``--xla_force_host_platform_device_count`` is locked at jax init). The
+children are pinned to the CPU (``JAX_PLATFORMS=cpu``) and their rows say
+``platform=cpu``: forced host devices are what this sweep measures, and a
+child must never contend for an accelerator its parent may hold.
 
 Timing is *steady state* (DESIGN.md §18): the step is built payload-free
 (``with_payload=False`` — the scrub soak never reads the gathered page
@@ -38,7 +41,7 @@ import os
 import subprocess
 import sys
 
-from benchmarks.common import csv_line, emit
+from benchmarks.common import csv_line, emit, use_compile_cache
 
 DEFAULT_DEVICES = (1, 2, 4, 8)
 
@@ -117,6 +120,7 @@ def _worker(
         "words_per_s": total / (us / 1e6),
         "clean_words": int(np.asarray(cnt)[..., 0].sum()),
         "backend": kbackend.tag(),
+        "platform": jax.devices()[0].platform,
     }))
 
 
@@ -132,6 +136,7 @@ def run_points(
     for _ in range(max(trials, 1)):
         for n in devices:
             env = dict(os.environ)
+            env["JAX_PLATFORMS"] = "cpu"
             # preserve unrelated XLA flags; only the forced count is ours
             kept = [
                 f for f in env.get("XLA_FLAGS", "").split()
@@ -205,7 +210,7 @@ def main(argv=None) -> None:
         print(csv_line(
             f"mesh_scrub_d{r['devices']}", r["us_per_call"],
             f"words_per_s={r['words_per_s']:.3e};"
-            f"backend={r.get('backend', 'interpret')}",
+            f"backend={r['backend']};platform={r['platform']}",
         ))
     if len(rows) > 1:
         scale = rows[-1]["words_per_s"] / rows[0]["words_per_s"]
@@ -217,4 +222,5 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

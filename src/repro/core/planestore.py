@@ -53,6 +53,7 @@ from repro.core.faultsim import DeviceFaultField, FaultField
 from repro.core.telemetry import DomainFaultStats, FaultStats
 from repro.core.voltage import PlatformProfile
 from repro.codes import DEFAULT_CODEC
+from repro.kernels import backend as kbackend
 from repro.kernels import ops as kops
 
 
@@ -83,15 +84,12 @@ class PendingFaultStats:
 
 # Double-buffer donation (DESIGN.md §18): the stale faulty planes handed
 # back to XLA are matched to the step's outputs by shape/dtype
-# (input-output aliasing), so on these platforms the steady-state soak
-# rotates two plane buffers instead of allocating a third every step. CPU
-# and other interpret-lane platforms don't honor donation — they take the
-# plain launch with identical math.
-_DONATE_PLATFORMS = ("gpu", "cuda", "rocm", "tpu")
-
-
+# (input-output aliasing), so on the compiled lane (TPU) the steady-state
+# soak rotates two plane buffers instead of allocating a third every step.
+# The CPU doesn't honor donation — it takes the plain launch with identical
+# math.
 def _donation_supported() -> bool:
-    return jax.default_backend() in _DONATE_PLATFORMS
+    return kbackend.compiled_available()
 
 
 @functools.partial(
@@ -510,7 +508,10 @@ class PlaneStore:
             schedule, self.domains, profiles, n_shards, shard_multipliers=mult
         )
         counters, planes = [], {}
-        host = jax.devices()[0]
+        # Faulty planes are assembled on shard 0's chip; the engine copies
+        # the assembled weights to every replica's own chip before serving
+        # (a TP mesh would consume them sharded in place instead).
+        home = meshrel.shard_devices(self.mesh)[0]
         for g in self._groups:
             sg = g.sharded
             step = meshrel.make_rail_step(
@@ -522,11 +523,8 @@ class PlaneStore:
                 sg.lo, sg.hi, sg.check, sg.dom, jnp.asarray(rates)
             )
             counters.append(per_shard)
-            # The CPU engine's decode path is single-device, so the faulty
-            # planes are gathered once per rail step; a TP mesh would keep
-            # them sharded in place (the weights are consumed sharded).
             planes[g.name] = tuple(
-                jax.device_put(x, host) for x in (flo, fhi, fpar)
+                jax.device_put(x, home) for x in (flo, fhi, fpar)
             )
 
         def finish(host_counters):

@@ -16,7 +16,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.models import lm
@@ -120,10 +119,10 @@ def make_dp_compressed_train_step(cfg, tcfg, mesh, axis: str = "data",
         return new_params, new_opt, ef, loss
 
     rep = P()
-    return shard_map(
+    return jax.shard_map(
         local_step,
         mesh=mesh,
         in_specs=(rep, rep, rep, P(axis)),
         out_specs=(rep, rep, rep, rep),
-        check_rep=False,
+        check_vma=False,
     )

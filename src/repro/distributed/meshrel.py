@@ -41,7 +41,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -61,6 +60,7 @@ __all__ = [
     "reliability_axes",
     "reliability_shards",
     "schedule_rates",
+    "shard_devices",
 ]
 
 
@@ -82,6 +82,19 @@ def arena_sharding(mesh: Mesh) -> NamedSharding:
     reliability shard axes (word count must be a multiple of the shard
     count — ``pad_to_shards`` arranges that)."""
     return NamedSharding(mesh, _axes_spec(reliability_axes(mesh)))
+
+
+def shard_devices(mesh: Mesh) -> list:
+    """The chip of every reliability shard, in shard order: the (first)
+    device holding shard ``s``'s slice of an ``arena_sharding`` array. A
+    serving replica runs where its shard's words live."""
+    n = reliability_shards(mesh)
+    out = [None] * n
+    for dev, idx in arena_sharding(mesh).devices_indices_map((n,)).items():
+        s = idx[0].start or 0
+        if out[s] is None or dev.id < out[s].id:
+            out[s] = dev
+    return out
 
 
 def pad_to_shards(n: int, n_shards: int) -> int:
@@ -174,12 +187,12 @@ def make_rail_step(
         return flo, fhi, fpar, cnt[None]
 
     out_specs = (spec, spec, spec, spec) + ((P(),) if with_psum else ())
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec, spec, spec, spec, spec),
         out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
     # counters come back already sliced to the 8 telemetry lanes:
     # kops.inject_scrub_domains drops the lane padding and the spill row
@@ -240,12 +253,12 @@ def make_kv_scrub_step(
         return out + (cnt[None],)
 
     n_out = 6 if with_payload else 4
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(spec, spec, spec, spec),
         out_specs=(spec,) * n_out,
-        check_rep=False,
+        check_vma=False,
     )
     jitted = jax.jit(fn)
 

@@ -2,25 +2,21 @@
 
 Every kernel wrapper in ``kernels/ops.py`` (and the mesh/paged callers that
 bake ``interpret`` into a jit cache key) routes its lowering decision through
-this module (DESIGN.md §18):
+this module (DESIGN.md §18). The lane is a property of the platform, never a
+fallback:
 
-  * ``resolve()`` returns the backend in force — ``"compiled"`` when the
-    runtime platform has a Pallas lowering (TPU Mosaic, GPU Triton) that
-    passes a one-time compile probe, ``"interpret"`` otherwise.
-  * The choice can be forced with the ``REPRO_KERNEL_BACKEND`` environment
-    variable (``auto`` | ``compiled`` | ``interpret``) or ``set_backend()``.
-    Forcing ``compiled`` on a host whose platform cannot lower Pallas does
-    NOT error: the probe fails, the interpret lane engages automatically,
-    and ``fallback_engaged()`` reports it — CI asserts exactly this on
-    CPU-only runners (kernel-backend-smoke).
-  * Per-call ``interpret=False`` requests go through ``resolve_interpret``:
-    an explicit compiled request is honored when the probe passes and falls
-    back to interpret (recorded) when it cannot, so no call site ever has to
-    guard on the platform.
-
-The probe compiles and runs one tiny SECDED encode with ``interpret=False``
-and caches the verdict per JAX platform; it is the only place a compiled
-lowering is attempted speculatively.
+  * ``resolve()`` returns ``"compiled"`` on TPU (Mosaic) and ``"interpret"``
+    on CPU. Any other platform has no lane for these kernels and raises.
+    On TPU a Mosaic lowering or compile failure propagates from the kernel
+    call itself; nothing retries it in the interpreter.
+  * ``REPRO_KERNEL_BACKEND`` (``auto`` | ``compiled`` | ``interpret``) or
+    ``set_backend()`` states which lane the caller expects. ``auto`` takes
+    the platform's lane; any other value must match it, or
+    :class:`BackendUnavailable` is raised — so a compiled request on a CPU
+    host fails loudly instead of running the interpreter.
+  * A per-call ``interpret=True`` is always honored (the reference lane the
+    tests compare against); ``interpret=False`` raises where no lowering
+    exists.
 """
 
 from __future__ import annotations
@@ -31,22 +27,23 @@ import jax
 
 VALID = ("auto", "compiled", "interpret")
 
-# Platforms with a real Pallas lowering (Mosaic / Triton). Everything else
-# (cpu, plugin backends without Pallas) auto-selects the interpret lane
-# without even running the probe.
-_COMPILED_PLATFORMS = ("tpu", "gpu", "cuda", "rocm")
+# The kernels use Mosaic TPU primitives (pltpu scratch, TPU block tiling), so
+# TPU is the only platform with a compiled lane; CPU runs the interpreter.
+_LANES = {"tpu": "compiled", "cpu": "interpret"}
 
 _override: list[str | None] = [None]  # set_backend() beats the env var
-_probe_cache: dict[str, bool] = {}  # platform -> compiled lowering works
-_fallback: dict[str, bool] = {"engaged": False}
+
+
+class BackendUnavailable(RuntimeError):
+    """A kernel lane was requested that this platform cannot run."""
 
 
 def set_backend(mode: str | None) -> None:
-    """Force the lane programmatically (tests); ``None`` restores auto."""
+    """State the expected lane programmatically (tests); ``None`` restores
+    auto."""
     if mode is not None and mode not in VALID:
         raise ValueError(f"backend must be one of {VALID}, got {mode!r}")
     _override[0] = mode
-    _fallback["engaged"] = False
 
 
 def requested() -> str:
@@ -57,50 +54,41 @@ def requested() -> str:
     return mode if mode in VALID else "auto"
 
 
-def compiled_available() -> bool:
-    """Whether a compiled Pallas lowering works on this platform (cached
-    one-time probe; never raises)."""
+def platform_lane() -> str:
+    """The one lane the runtime platform supports."""
     platform = jax.default_backend()
-    if platform in _probe_cache:
-        return _probe_cache[platform]
-    ok = False
-    if platform in _COMPILED_PLATFORMS:
-        try:
-            import jax.numpy as jnp
+    try:
+        return _LANES[platform]
+    except KeyError:
+        raise BackendUnavailable(
+            f"no Pallas lane for platform {platform!r} (kernels lower with "
+            "Mosaic on TPU and run interpreted on CPU)"
+        ) from None
 
-            from repro.kernels import secded as _secded
 
-            z = jnp.zeros((8, 128), jnp.uint32)
-            jax.block_until_ready(
-                _secded.encode_2d(z, z, block=(8, 128), codec="secded72",
-                                  interpret=False)
-            )
-            ok = True
-        except Exception:  # lowering/compile failure -> interpret lane
-            ok = False
-    _probe_cache[platform] = ok
-    return ok
+def compiled_available() -> bool:
+    """Whether the runtime platform lowers these kernels (TPU)."""
+    return jax.default_backend() == "tpu"
 
 
 def resolve() -> str:
     """The lane in force: ``"compiled"`` or ``"interpret"``.
 
-    ``auto``: compiled wherever the probe passes. ``compiled``: same, but a
-    probe failure records the fallback (CI asserts it engaged on CPU).
-    ``interpret``: always the interpret lane, even on TPU/GPU.
-    """
+    Raises :class:`BackendUnavailable` when the requested mode names a lane
+    the platform does not have (a compiled request on CPU, an interpret
+    request on TPU)."""
+    lane = platform_lane()
     mode = requested()
-    if mode == "interpret":
-        return "interpret"
-    if compiled_available():
-        return "compiled"
-    if mode == "compiled":
-        _fallback["engaged"] = True
-    return "interpret"
+    if mode not in ("auto", lane):
+        raise BackendUnavailable(
+            f"kernel lane {mode!r} requested on platform "
+            f"{jax.default_backend()!r}, whose only lane is {lane!r}"
+        )
+    return lane
 
 
 def use_interpret() -> bool:
-    """Backwards-compatible boolean view of ``resolve()``."""
+    """Boolean view of ``resolve()``."""
     return resolve() == "interpret"
 
 
@@ -108,26 +96,18 @@ def resolve_interpret(interpret: bool | None) -> bool:
     """Resolve a per-call ``interpret`` request to a concrete lowering.
 
     ``None``  -> the lane in force (``resolve()``).
-    ``False`` -> explicit compiled request: honored when the platform can
-                 lower Pallas, otherwise the interpret fallback engages
-                 (recorded via ``fallback_engaged()``) instead of erroring.
-    ``True``  -> interpret, always honored.
+    ``False`` -> compiled; raises :class:`BackendUnavailable` where the
+                 platform has no lowering.
+    ``True``  -> interpret, always honored (the reference lane).
     """
     if interpret is None:
         return use_interpret()
-    if interpret is False and not compiled_available():
-        _fallback["engaged"] = True
-        return True
+    if not interpret and not compiled_available():
+        raise BackendUnavailable(
+            f"compiled kernel requested on platform {jax.default_backend()!r}, "
+            "which has no Pallas lowering"
+        )
     return bool(interpret)
-
-
-def fallback_engaged() -> bool:
-    """True once any compiled request has fallen back to interpret."""
-    return _fallback["engaged"]
-
-
-def reset_fallback() -> None:
-    _fallback["engaged"] = False
 
 
 def tag() -> str:

@@ -15,7 +15,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.kernels import backend as _backend
 from repro.kernels import ecc_matmul as _mm
@@ -50,9 +49,8 @@ def _round_up(x: int, m: int) -> int:
 
 
 def use_interpret() -> bool:
-    """True when the interpret lane is in force (see kernels/backend.py:
-    honors REPRO_KERNEL_BACKEND / set_backend and the compiled-lowering
-    probe, falling back to interpret automatically)."""
+    """True when the interpret lane is in force (the platform's lane, see
+    kernels/backend.py)."""
     return _backend.use_interpret()
 
 
@@ -189,6 +187,20 @@ class EccWeight:
         return cls(*children, *aux)
 
 
+@jax.jit
+def _pack_planes(qw):
+    """int8 (K, N) -> SECDED planes on device; bit-identical to the host
+    oracle ``ref.pack_ecc_weights_np`` (codeword i of column n packs
+    W[j*K/8 + i, n], j = 0..7)."""
+    from repro.core import ecc
+
+    k, n = qw.shape
+    wr = (qw.reshape(8, k // 8, n).astype(jnp.int32) & 0xFF).astype(jnp.uint32)
+    lo = wr[0] | (wr[1] << 8) | (wr[2] << 16) | (wr[3] << 24)
+    hi = wr[4] | (wr[5] << 8) | (wr[6] << 16) | (wr[7] << 24)
+    return lo, hi, ecc.encode(lo, hi)
+
+
 def pack_ecc_weights(w: jnp.ndarray, axis_scale: int | None = 1, fuse: bool = True) -> EccWeight:
     """Quantize a float (K, N) weight to int8 and SECDED-encode it."""
     from repro.core import quantize as q
@@ -196,9 +208,9 @@ def pack_ecc_weights(w: jnp.ndarray, axis_scale: int | None = 1, fuse: bool = Tr
     k, n = w.shape
     assert k % 8 == 0, f"K={k} must be a multiple of 8 (64-bit codewords)"
     qw, scale = q.quantize(w, axis=axis_scale)
-    lo, hi, parity = _ref.pack_ecc_weights_np(np.asarray(qw))
+    lo, hi, parity = _pack_planes(qw)
     return EccWeight(
-        jnp.asarray(lo), jnp.asarray(hi), jnp.asarray(parity),
+        lo, hi, parity,
         scale.reshape(-1) if axis_scale is not None else scale, k, n, fuse,
     )
 
@@ -210,6 +222,21 @@ def permute_k(x: jnp.ndarray, k: int) -> jnp.ndarray:
     return (
         x.reshape(*lead, 8, k8).swapaxes(-1, -2).reshape(*lead, k)
     )
+
+
+def matmul_tiling(m: int, k: int, n: int, block=(128, 512, 256)):
+    """Kernel block and padded (M, N) of the fused matmul for an
+    (m, k) x (k, n) product: ``((bm, bk, bn), mp, np)``."""
+    # bk8 must divide K/8 exactly: the 8i+j interleave mapping is global,
+    # so the K dimension cannot be padded after packing.
+    k8 = k // 8
+    bk8 = block[1] // 8
+    while k8 % bk8:
+        bk8 //= 2
+    # Pad M and N to block multiples (interpret-mode OOB reads are undefined).
+    bm = min(block[0], _round_up(m, 8))
+    bn = min(block[2], _round_up(n, 128))
+    return (bm, bk8 * 8, bn), _round_up(m, bm), _round_up(n, bn)
 
 
 def ecc_matmul(
@@ -231,23 +258,15 @@ def ecc_matmul(
     x2 = x.reshape(-1, w.k)
     if fuse:
         xp = permute_k(x2, w.k)
-        m, k8, n = x2.shape[0], w.k // 8, w.n
-        # bk8 must divide K/8 exactly: the 8i+j interleave mapping is global,
-        # so the K dimension cannot be padded after packing.
-        bk8 = block[1] // 8
-        while k8 % bk8:
-            bk8 //= 2
-        # Pad M and N to block multiples (interpret-mode OOB reads are undefined).
-        bm = min(block[0], _round_up(m, 8))
-        bn = min(block[2], _round_up(n, 128))
-        mp, np_ = _round_up(m, bm), _round_up(n, bn)
+        m, n = x2.shape[0], w.n
+        blk, mp, np_ = matmul_tiling(m, w.k, n, block)
         xp = jnp.pad(xp, ((0, mp - m), (0, 0)))
         pad_n = ((0, 0), (0, np_ - n))
         _count_launch()
         out = _mm.ecc_matmul_2d(
             xp,
             jnp.pad(w.lo, pad_n), jnp.pad(w.hi, pad_n), jnp.pad(w.parity, pad_n),
-            block=(bm, bk8 * 8, bn), interpret=interpret,
+            block=blk, interpret=interpret,
         )[:m, :n]
     else:
         lo, hi, _ = decode(w.lo, w.hi, w.parity, interpret=interpret)

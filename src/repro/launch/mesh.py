@@ -13,35 +13,23 @@ devices *before* any import).
 from __future__ import annotations
 
 import jax
-
-
-def compat_make_mesh(shape, axes):
-    """jax.make_mesh across versions: axis_types only exists on newer jax."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-
-
-def compat_abstract_mesh(shape, axes):
-    """AbstractMesh across versions: older jax takes ((name, size), ...)."""
-    try:
-        return jax.sharding.AbstractMesh(shape, axes)
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1):
     """Small mesh over whatever devices exist (tests / examples)."""
     n = len(jax.devices())
     assert n % model == 0, (n, model)
-    return compat_make_mesh((n // model, model), ("data", "model"))
+    return jax.make_mesh(
+        (n // model, model), ("data", "model"),
+        axis_types=(AxisType.Auto,) * 2,
+    )
 
 
 def make_reliability_mesh(n_shards: int | None = None, model: int = 1):

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import codes
 from repro.configs import shapes
 from repro.core import (
     EscalationPolicy,
@@ -39,6 +40,8 @@ from repro.core.kvpages import PAGE_TOKENS, KVGeometry, KVPageArena
 from repro.core.memory import EccMemoryDomain
 from repro.core.planestore import PlaneStore, leaf_seed
 from repro.core.telemetry import DomainFaultStats, FaultStats, ShardFaultStats
+from repro.distributed import meshrel
+from repro.kernels import backend as kbackend
 from repro.kernels import ops as kops
 from repro.models import lm
 from repro.models.base import ModelConfig
@@ -348,6 +351,20 @@ class ReliabilityConfig:
                 or self.codecs is None
                 or isinstance(self.codecs, str),
                 "per-domain codec dicts need multi_rail=True",
+            )
+        if kbackend.resolve() == "compiled":
+            names = set(shapes.domain_codecs(self.codecs).values())
+            if self.escalation is not None:
+                names |= set(self.escalation_policy.ladder)
+            lut = sorted(n for n in names if codes.get(n).lut_input_arrays())
+            _require(
+                not lut,
+                f"codec(s) {lut} resolve syndromes with a dense-LUT gather "
+                "(codes/base.py Codec.classify_jnp: jnp.take over a "
+                "2**n_check table), which Mosaic cannot lower on the "
+                "compiled TPU lane ('Only 2D gather is supported'); choose "
+                "gather-free codecs (parity65, secded72, ileave88) in "
+                "`codecs` and the escalation ladder",
             )
         if mesh is not None:
             _require(
@@ -760,17 +777,25 @@ class ServingEngine:
         cache = lm.init_cache(self.cfg, b, self.max_len)
         logits, cache = self._prefill(p, jnp.asarray(prompts), cache)
         tok = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
+        # decode at the serve lanes' padded batch (sched.DECODE_ROWS), so a
+        # request's rollout is the one serve() gives it
+        pad = sched.decode_rows(b) - b
+        tok = jnp.pad(tok, ((0, pad), (0, 0)))
+        cache = jax.tree.map(
+            lambda c: jnp.pad(c, [(0, 0), (0, pad)] + [(0, 0)] * (c.ndim - 2)),
+            cache,
+        )
         if not use_scan:
             outs = [tok]
             for i in range(n_tokens - 1):
                 logits, cache = self._decode(p, tok, cache, s0 + i)
                 tok = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
                 outs.append(tok)
-            return np.concatenate([np.asarray(o) for o in outs], axis=1)
+            return np.concatenate([np.asarray(o) for o in outs], axis=1)[:b]
         toks, _ = self._decode_loop(
             p, tok, cache, jnp.int32(s0), n_tokens - 1
         )
-        return np.concatenate([np.asarray(tok), np.asarray(toks)], axis=1)
+        return np.concatenate([np.asarray(tok), np.asarray(toks)], axis=1)[:b]
 
     # -- accuracy canary (DESIGN.md §15) ---------------------------------------
     def canary_divergence(self) -> float | None:
@@ -835,7 +860,10 @@ class ServingEngine:
         or scheduler.Request/``ServeRequest`` objects. The KV cache lives in
         SECDED pages on the `kv` voltage domain; every read scrubs. At
         nominal voltage the output tokens are bit-identical to `generate` on
-        the same batch composition (tested).
+        the same batch composition (tested): prefill groups must match, and
+        decode always runs at the batch padded to ``scheduler.DECODE_ROWS``
+        rows, so with up to that many lanes a request prefilled alone
+        serves exactly its own ``generate`` rollout.
 
         ``share_prefix=True`` enables the copy-on-write prefix-sharing trie:
         requests with identical full-page prompt prefixes share physical
@@ -1023,51 +1051,56 @@ class ServingEngine:
             else [None] * n_shards
         )
         reports = []
-        for s in range(n_shards):
+        for s, dev in enumerate(meshrel.shard_devices(self.mesh)):
             rail = kv_rails[s]
             # A previous serve's escalation persists per rail (DESIGN.md §12).
             kv_codec = rail.codec if rail is not None else base_codec
-            arena = KVPageArena(
-                geom,
-                profile,
-                n_pages,
-                seed=self.rel.seed,
-                ecc=self.rel.ecc,
-                codec=kv_codec,
-                shard=s,
-                env=self.rel.environment_profile,
-            )
-            if kv_voltage is not None:
-                arena.set_voltage(float(kv_voltage))
-            else:
-                arena.set_voltage(float(self.rails[s].get("kv", self.voltage)))
-            if rail is not None:
-                # The controller is the source of truth for a walked rail
-                # (see serve()); under `uniform` the shared rail resumes
-                # from wherever the previous shard's stream left it — the
-                # worst-shard canary by construction.
-                arena.set_voltage(rail.voltage)
-            report = sched.serve_stream(
-                self.params,
-                self.cfg,
-                self._paged_helpers(geom, kv_codec, draft_cfg=draft_cfg),
-                arena,
-                parts[s],
-                n_lanes=n_lanes,
-                max_len=self.max_len,
-                scrub_interval=scrub_interval,
-                max_block=max_block,
-                kv_controller=rail,
-                helpers_factory=lambda cname: self._paged_helpers(
-                    geom, cname, draft_cfg=draft_cfg
-                ),
-                share_prefix=share_prefix,
-                speculative=speculative,
-                draft_params=draft_params,
-                draft_cfg=draft_cfg,
-                recorder=self.recorder,
-                scrub_overlap=scrub_overlap,
-            )
+            # Replica s runs on shard s's chip: its own copy of the weights,
+            # and a KV arena and lane caches created there, so every decode
+            # dispatch lands there too.
+            params, draft = jax.device_put((self.params, draft_params), dev)
+            with jax.default_device(dev):
+                arena = KVPageArena(
+                    geom,
+                    profile,
+                    n_pages,
+                    seed=self.rel.seed,
+                    ecc=self.rel.ecc,
+                    codec=kv_codec,
+                    shard=s,
+                    env=self.rel.environment_profile,
+                )
+                if kv_voltage is not None:
+                    arena.set_voltage(float(kv_voltage))
+                else:
+                    arena.set_voltage(float(self.rails[s].get("kv", self.voltage)))
+                if rail is not None:
+                    # The controller is the source of truth for a walked rail
+                    # (see serve()); under `uniform` the shared rail resumes
+                    # from wherever the previous shard's stream left it — the
+                    # worst-shard canary by construction.
+                    arena.set_voltage(rail.voltage)
+                report = sched.serve_stream(
+                    params,
+                    self.cfg,
+                    self._paged_helpers(geom, kv_codec, draft_cfg=draft_cfg),
+                    arena,
+                    parts[s],
+                    n_lanes=n_lanes,
+                    max_len=self.max_len,
+                    scrub_interval=scrub_interval,
+                    max_block=max_block,
+                    kv_controller=rail,
+                    helpers_factory=lambda cname: self._paged_helpers(
+                        geom, cname, draft_cfg=draft_cfg
+                    ),
+                    share_prefix=share_prefix,
+                    speculative=speculative,
+                    draft_params=draft,
+                    draft_cfg=draft_cfg,
+                    recorder=self.recorder,
+                    scrub_overlap=scrub_overlap,
+                )
             reports.append(report)
             self._store.register_domain_words(
                 "kv", arena.n_words, codec=arena.codec_name, shard=s

@@ -50,6 +50,18 @@ from repro.core.kvpages import (
 from repro.core.controller import reader_weighted_stats
 from repro.core.telemetry import FaultStats
 
+# Decode programs run at a batch padded to a multiple of DECODE_ROWS. XLA
+# picks fusions (and with them where bf16 intermediates are rounded) per
+# program shape, so one request's greedy rollout is bit-reproducible only
+# across programs of the same batch: a 4-lane serve and a 1-row `generate`
+# agree because both decode 8 rows. Padding rows are idle lanes.
+DECODE_ROWS = 8
+
+
+def decode_rows(n: int) -> int:
+    """Rows of the decode batch that holds ``n`` lanes."""
+    return -(-n // DECODE_ROWS) * DECODE_ROWS
+
 
 @dataclasses.dataclass(frozen=True)
 class Request:
@@ -502,6 +514,7 @@ def serve_stream(
             voltage=float(arena.voltage), codec=arena.codec_name,
         )
     spec_k = int(speculative)
+    n_rows = decode_rows(n_lanes)
     if spec_k >= 2:
         assert draft_params is not None and draft_cfg is not None, (
             "speculative decode needs draft_params + draft_cfg"
@@ -512,12 +525,12 @@ def serve_stream(
         import jax
 
         draft_prefill = jax.jit(steps_mod.make_prefill_step(draft_cfg))
-        dcache = lm.init_cache(draft_cfg, n_lanes, max_len)
+        dcache = lm.init_cache(draft_cfg, n_rows, max_len)
     else:
         draft_prefill, dcache = None, None
-    cache = init_cache_fn(n_lanes)
-    cur_tok = np.zeros(n_lanes, np.int32)
-    pos_v = np.zeros(n_lanes, np.int32)
+    cache = init_cache_fn(n_rows)
+    cur_tok = np.zeros(n_rows, np.int32)
+    pos_v = np.zeros(n_rows, np.int32)
     steps = 0
     since_scrub = 0
     kv_voltages: list = []
@@ -840,8 +853,8 @@ def serve_stream(
         sched.drain_fresh_pages()  # wipe growth pages before the block commits
 
         # -- k decode steps + per-token page commits in one dispatch --------
-        page_ids = np.full((k, n_lanes), arena.scratch_page, np.int32)
-        slots = np.zeros((k, n_lanes), np.int32)
+        page_ids = np.full((k, n_rows), arena.scratch_page, np.int32)
+        slots = np.zeros((k, n_rows), np.int32)
         for i in active:
             st = sched.lanes[i]
             for j in range(k):

@@ -93,21 +93,23 @@ def _refresh_cache(cache, payload, n_tok, *, geom: KVGeometry):
 
     payload: (L, T, token_f32) decoded tokens in position order (T >= the
     cache depth S is sliced; T < S leaves the tail untouched); n_tok: (L,)
-    valid-token counts — positions >= n_tok keep their cache bits.
+    valid-token counts — positions >= n_tok keep their cache bits. The
+    payload covers cache lanes 0..L-1; lanes beyond (the decode batch's
+    padding rows) keep theirs.
     """
     length, t_total, _ = payload.shape
     out = {k: dict(v) for k, v in cache.items()}
     off = 0
     for j in geom.attn_positions:
         for name in ("k", "v"):
-            c = cache[f"p{j}"][name]  # (g, L, S, H, D)
+            c = cache[f"p{j}"][name][:, :length]  # (g, L, S, H, D)
             g, _, s, h, d = c.shape
             t = min(t_total, s)
             sz = g * h * d
             part = payload[:, :t, off : off + sz].reshape(length, t, g, h, d)
             part = jnp.moveaxis(part, 2, 0).astype(c.dtype)  # (g, L, t, H, D)
             valid = (jnp.arange(t)[None, :] < n_tok[:, None])[None, :, :, None, None]
-            out[f"p{j}"][name] = c.at[:, :, :t].set(
+            out[f"p{j}"][name] = cache[f"p{j}"][name].at[:, :length, :t].set(
                 jnp.where(valid, part, c[:, :, :t])
             )
             off += sz

@@ -68,6 +68,20 @@ def test_fused_kernel_corrects_all_single_bit_faults(rng):
     assert (status == 1).sum() == sel.sum()
 
 
+@pytest.mark.parametrize("kn", [(64, 128), (1024, 130)])
+def test_pack_ecc_weights_matches_numpy_oracle(kn, rng):
+    w = jnp.asarray(rng.standard_normal(kn) * 0.05, jnp.float32)
+    ew = ops.pack_ecc_weights(w)
+    from repro.core import quantize
+
+    qw, _ = quantize.quantize(w, axis=1)
+    for got, want in zip(
+        (ew.lo, ew.hi, ew.parity), ref.pack_ecc_weights_np(np.asarray(qw))
+    ):
+        assert got.dtype == want.dtype
+        assert np.array_equal(np.asarray(got), want)
+
+
 def test_int8_word_packing_roundtrip(rng):
     from repro.core import quantize
 
@@ -132,37 +146,52 @@ def test_compiled_matches_interpret_bit_for_bit(name, rng):
 
 
 def test_forced_compiled_falls_back_cleanly_on_cpu(rng):
-    """Forcing backend=compiled on a host without a Pallas lowering must not
-    error: the interpret lane engages, fallback is recorded, and results are
-    bit-identical to an explicit interpret run. (On hosts where compiled IS
-    available this degenerates to the identity test above — fallback stays
-    false.)"""
+    """A compiled request never degrades to the interpreter: on a host with
+    no Pallas lowering, forcing backend=compiled raises (globally and per
+    call) while an explicit interpret=True reference call still runs; where
+    the lowering exists the forced lane is bit-identical to that reference."""
     arrays = _backend_case_arrays(rng)
     want = jax.tree.leaves(ops.inject_scrub(*arrays, interpret=True))
     backend.set_backend("compiled")
     try:
-        backend.reset_fallback()
-        got = jax.tree.leaves(ops.inject_scrub(*arrays, interpret=None))
-        assert backend.fallback_engaged() == (not backend.compiled_available())
-        for g, w in zip(got, want):
-            assert np.array_equal(np.asarray(g), np.asarray(w))
+        if backend.compiled_available():
+            got = jax.tree.leaves(ops.inject_scrub(*arrays, interpret=None))
+            for g, w in zip(got, want):
+                assert np.array_equal(np.asarray(g), np.asarray(w))
+        else:
+            with pytest.raises(backend.BackendUnavailable):
+                ops.inject_scrub(*arrays, interpret=None)
+            with pytest.raises(backend.BackendUnavailable):
+                backend.resolve()
     finally:
         backend.set_backend(None)
+    if not backend.compiled_available():
+        with pytest.raises(backend.BackendUnavailable):
+            ops.inject_scrub(*arrays, interpret=False)
 
 
 def test_backend_modes_and_tag():
     assert backend.requested() in backend.VALID
-    assert backend.tag() in ("compiled", "interpret")
+    lane = backend.platform_lane()
+    assert backend.tag() == lane
     with pytest.raises(ValueError):
         backend.set_backend("mosaic")
-    backend.set_backend("interpret")
+    other = "interpret" if lane == "compiled" else "compiled"
+    backend.set_backend(lane)
     try:
-        assert backend.use_interpret() is True
-        assert backend.resolve() == "interpret"
-        # an explicit per-call interpret=False is a *request*: honored only
-        # when the probe passes, silent interpret fallback otherwise
-        assert backend.resolve_interpret(False) == (
-            not backend.compiled_available()
-        )
+        assert backend.use_interpret() is (lane == "interpret")
+        assert backend.resolve() == lane
+        # an explicit per-call interpret=True is the reference lane and is
+        # honored everywhere; interpret=False needs a real lowering
+        assert backend.resolve_interpret(True) is True
+        if lane == "interpret":
+            with pytest.raises(backend.BackendUnavailable):
+                backend.resolve_interpret(False)
+        else:
+            assert backend.resolve_interpret(False) is False
+        # the platform has exactly one lane: requesting the other raises
+        backend.set_backend(other)
+        with pytest.raises(backend.BackendUnavailable):
+            backend.resolve()
     finally:
         backend.set_backend(None)

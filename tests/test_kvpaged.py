@@ -89,6 +89,35 @@ def test_paged_matches_dense_single_request(setup, engine):
         np.testing.assert_array_equal(ref, rep.outputs[i])
 
 
+def test_refresh_covers_payload_lanes_only(setup):
+    """The lane cache holds the decode batch padded to DECODE_ROWS; an
+    interval refresh writes the scrubbed payload into the real lanes'
+    valid positions and leaves the padding rows as they are."""
+    from repro.serving import steps
+    from repro.serving.scheduler import decode_rows
+
+    cfg, _, _ = setup
+    geom = KVGeometry.from_config(cfg)
+    rows = decode_rows(3)
+    assert rows == 8 and decode_rows(8) == 8 and decode_rows(9) == 16
+    rng = np.random.default_rng(0)
+    cache = jax.tree.map(
+        lambda c: jnp.asarray(rng.standard_normal(c.shape), c.dtype),
+        lm.init_cache(cfg, rows, 16),
+    )
+    payload = jnp.asarray(rng.standard_normal((3, 16, geom.token_f32)), jnp.float32)
+    n_tok = jnp.asarray([16, 5, 0], jnp.int32)
+    out = steps._refresh_cache(cache, payload, n_tok, geom=geom)
+    for c, o in zip(jax.tree.leaves(cache), jax.tree.leaves(out)):
+        np.testing.assert_array_equal(np.asarray(o[:, 2:]), np.asarray(c[:, 2:]))
+        np.testing.assert_array_equal(np.asarray(o[:, 1, 5:]), np.asarray(c[:, 1, 5:]))
+    dt = jax.tree.leaves(cache)[0].dtype
+    want = np.asarray(payload.astype(dt).astype(jnp.float32))
+    got = np.asarray(steps._extract_span(out, start=0, stop=16, geom=geom))
+    np.testing.assert_array_equal(got[0], want[0])
+    np.testing.assert_array_equal(got[1, :5], want[1, :5])
+
+
 @settings(max_examples=12, deadline=None)
 @given(
     n_lanes=st.integers(1, 4),

@@ -149,6 +149,9 @@ def test_mesh8_serve_acceptance(tmp_path):
             "kv_locks": [s["kv"] for s in e.rails],
             "p8": e.power_report()["total_w"],
             "p1": e1.power_report()["total_w"],
+            "replica_devices": [
+                sorted(d.id for d in a.lo.devices()) for a in e.kv_arenas
+            ],
         }))
         """
     )
@@ -172,6 +175,9 @@ def test_mesh8_serve_acceptance(tmp_path):
     # fleet power at equal voltage == 8x one chip, within noise (per-shard
     # arena padding shifts domain fractions by well under a percent)
     assert res["p8"] == pytest.approx(8 * res["p1"], rel=0.02)
+    # every replica's KV arena (an output of its decode dispatches) sits on
+    # its own shard's device
+    assert res["replica_devices"] == [[s] for s in range(8)]
 
 
 def test_mesh_uniform_policy_shared_walk(setup):

@@ -12,6 +12,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AbstractMesh
 
 from conftest import tiny_cfg
 from repro.core.controller import MeshRailController
@@ -22,20 +23,20 @@ from repro.core.voltage import PLATFORMS
 from repro.distributed import meshrel
 from repro.distributed.sharding import reliability_axes, reliability_shards
 from repro.kernels import ops as kops
-from repro.launch.mesh import compat_abstract_mesh, make_reliability_mesh
+from repro.launch.mesh import make_reliability_mesh
 
 
 # ---------------------------------------------------------------------------
 # axis conventions
 # ---------------------------------------------------------------------------
 def test_reliability_axes_conventions():
-    m = compat_abstract_mesh((2, 4), ("data", "model"))
+    m = AbstractMesh((2, 4), ("data", "model"))
     assert reliability_axes(m) == ("data",)
     assert reliability_shards(m) == 2
-    mp = compat_abstract_mesh((2, 4, 4), ("pod", "data", "model"))
+    mp = AbstractMesh((2, 4, 4), ("pod", "data", "model"))
     assert reliability_axes(mp) == ("pod", "data")
     assert reliability_shards(mp) == 8
-    bare = compat_abstract_mesh((4,), ("shard",))
+    bare = AbstractMesh((4,), ("shard",))
     assert reliability_axes(bare) == ("shard",)
     assert reliability_shards(bare) == 4
     assert meshrel.pad_to_shards(10, 4) == 12
@@ -256,8 +257,8 @@ def test_kv_shard_streams_disjoint_100_intervals():
     legacy = KVPageArena(geom, prof, n_pages=2, seed=7)  # pre-mesh signature
     s0 = arena(0)
     assert np.array_equal(
-        np.asarray(jax.random.key_data(s0._key) if hasattr(jax.random, "key_data") else s0._key),
-        np.asarray(jax.random.key_data(legacy._key) if hasattr(jax.random, "key_data") else legacy._key),
+        np.asarray(jax.random.key_data(s0._key)),
+        np.asarray(jax.random.key_data(legacy._key)),
     )
     arenas = [arena(s) for s in range(3)]
     for step in range(100):

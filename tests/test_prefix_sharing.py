@@ -464,3 +464,34 @@ def test_validate_raises_typed_errors():
     # a valid config returns itself for chaining
     ok = ReliabilityConfig(mode="inline", rails=RailsConfig(multi_rail=True))
     assert ok.validate() is ok
+
+
+@pytest.mark.parametrize(
+    "protection",
+    [
+        ProtectionConfig(codecs={"kv": "dected79"}),
+        ProtectionConfig(escalation=("secded72", "dected79")),
+    ],
+    ids=["codecs", "escalation"],
+)
+def test_validate_rejects_lut_codec_on_compiled_lane(protection, monkeypatch):
+    """The dense-LUT DEC-TED decoder has no Mosaic lowering: on the compiled
+    lane the config is refused up front (naming the kernel limit) instead of
+    crashing at the first escalation mid-serve. The interpret lane keeps
+    accepting it."""
+    from repro.kernels import backend
+
+    rel = ReliabilityConfig(
+        mode="inline", rails=RailsConfig(multi_rail=True), protection=protection
+    )
+    assert rel.validate() is rel  # this host's interpret lane
+    monkeypatch.setattr(backend, "resolve", lambda: "compiled")
+    with pytest.raises(ReliabilityConfigError, match="dense-LUT gather"):
+        rel.validate()
+    # gather-free ladders stay valid on the compiled lane
+    ok = ReliabilityConfig(
+        mode="inline",
+        rails=RailsConfig(multi_rail=True),
+        protection=ProtectionConfig(escalation=("parity65", "secded72", "ileave88")),
+    )
+    assert ok.validate() is ok

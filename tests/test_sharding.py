@@ -9,16 +9,16 @@ import textwrap
 import pytest
 
 import jax
+from jax.sharding import AbstractMesh, AxisType
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed import sharding as shd
-from repro.launch.mesh import compat_abstract_mesh, compat_make_mesh
 
 
 @pytest.fixture(scope="module")
 def mesh():
     # single-device mesh: rule logic only depends on axis names/sizes
-    return compat_make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 
 
 def test_spec_rules_basic(mesh):
@@ -33,7 +33,7 @@ def test_spec_rules_basic(mesh):
 
 def test_spec_divisibility_fallback():
     # AbstractMesh: rule logic only needs axis names/sizes, no devices
-    m = compat_abstract_mesh((1, 2), ("data", "model"))
+    m = AbstractMesh((1, 2), ("data", "model"))
     # 3 not divisible by model=2 -> replicate, next axis picks model up
     assert shd.spec_for(("experts", "ffn"), (3, 8), m, False) == P(None, "model")
 
@@ -45,16 +45,15 @@ def test_dryrun_8dev_subprocess(tmp_path):
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, dataclasses, json, sys
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
         from repro.configs import get_smoke_config
         from repro.distributed import sharding as shd
-        from repro.launch.mesh import compat_make_mesh
         from repro.models import lm
         from repro.optim import adamw
         from repro.train import train_step as ts
 
         cfg = get_smoke_config("qwen3-0.6b")
-        mesh = compat_make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         tcfg = ts.TrainConfig(optimizer=adamw.AdamWConfig(), remat="full")
         fn = ts.make_train_step(cfg, tcfg)
         pstruct = lm.param_struct(cfg)

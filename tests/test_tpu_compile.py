@@ -1,0 +1,124 @@
+"""Main-path Pallas kernels compiled for a described TPU v5e chip.
+
+Interpret mode accepts layouts Mosaic refuses (unaligned slices, too much
+VMEM, unsupported gathers), so every kernel the serving path launches is
+lowered and compiled here by the TPU compiler at the published qwen3-0.6b
+widths, against one chip of a ``v5e:2x2`` topology that is described, not
+attached. A compile that passes is not a run: results and times come only
+from ``chip_smoke.py`` on the chip.
+
+The topology is described inside a module-scoped fixture (never at import):
+only the worker that runs this file loads the TPU compiler library.
+"""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.qwen3_0_6b import config as qwen3_config
+from repro.core.kvpages import KVGeometry
+from repro.kernels import ecc_matmul, inject_scrub, ops, paged_gather, secded
+
+CFG = qwen3_config()
+D, HD = CFG.d_model, CFG.hd
+# (K, N) of every protected projection: wq, wk/wv, wo, w1/w3, w2
+PROJECTIONS = [
+    (D, CFG.n_heads * HD),
+    (D, CFG.n_kv_heads * HD),
+    (CFG.n_heads * HD, D),
+    (D, CFG.d_ff),
+    (CFG.d_ff, D),
+]
+ROWS, LANES = 2048, ops.LANES  # one 1M-word arena block in the ops 2D layout
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this environment
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    # A compile for a described chip can be written to the persistent cache
+    # but never read back without one: keep the cache out of these tests.
+    from jax.experimental.compilation_cache import compilation_cache
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _compile(fn, chip, *shapes, **static):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=chip) for s, dt in shapes]
+    hlo = jax.jit(lambda *a: fn(*a, interpret=False, **static)).lower(*args).compile()
+    assert "tpu_custom_call" in hlo.as_text()
+    return hlo
+
+
+@pytest.mark.parametrize("m", [8, 512], ids=["decode", "prefill"])
+@pytest.mark.parametrize("kn", PROJECTIONS, ids=lambda kn: f"{kn[0]}x{kn[1]}")
+def test_ecc_matmul_compiles(chip, kn, m):
+    k, n = kn
+    block, mp, np_ = ops.matmul_tiling(m, k, n)
+    _compile(
+        ecc_matmul.ecc_matmul_2d, chip,
+        ((mp, k), CFG.compute_dtype),
+        ((k // 8, np_), jnp.uint32), ((k // 8, np_), jnp.uint32),
+        ((k // 8, np_), jnp.uint8),
+        block=block,
+    )
+
+
+def test_inject_scrub_domains_compiles(chip):
+    p32, p8 = ((ROWS, LANES), jnp.uint32), ((ROWS, LANES), jnp.uint8)
+    _compile(
+        inject_scrub.inject_scrub_domains_2d, chip,
+        p32, p32, p8, p32, p32, p8, ((ROWS, LANES), jnp.int32),
+        n_domains=3, block=(256, LANES),
+    )
+
+
+@pytest.mark.parametrize("pages", [4, 32])
+def test_gather_scrub_compiles(chip, pages):
+    words = KVGeometry.from_config(CFG).words_per_page
+    bp = min(16, pages)  # gather_scrub_pages' page block
+    p32, p8 = ((pages, words), jnp.uint32), ((pages, words), jnp.uint8)
+    _compile(paged_gather.gather_scrub_2d, chip, p32, p32, p8, page_block=bp)
+
+
+def test_secded_encode_decode_compile(chip):
+    p32, p8 = ((ROWS, LANES), jnp.uint32), ((ROWS, LANES), jnp.uint8)
+    _compile(secded.encode_2d, chip, p32, p32, block=(256, LANES))
+    _compile(secded.decode_2d, chip, p32, p32, p8, block=(256, LANES))
+
+
+@pytest.mark.parametrize("codec", ["ileave88", "parity65"])
+def test_other_gather_free_codecs_compile(chip, codec):
+    from repro import codes
+
+    check = jnp.dtype(codes.get(codec).check_dtype)
+    p32, pc = ((ROWS, LANES), jnp.uint32), ((ROWS, LANES), check)
+    _compile(
+        inject_scrub.inject_scrub_2d, chip, p32, p32, pc, p32, p32, pc,
+        codec=codec, block=(256, LANES),
+    )
+
+
+def test_dected79_lut_decode_has_no_lowering(chip):
+    """The limit ReliabilityConfig.validate guards on the compiled lane:
+    the dense-LUT DEC-TED classify (a 1-D jnp.take) does not lower."""
+    p32, pc = ((ROWS, LANES), jnp.uint32), ((ROWS, LANES), jnp.uint32)
+    with pytest.raises(NotImplementedError, match="gather"):
+        _compile(secded.decode_2d, chip, p32, p32, pc, codec="dected79", block=(256, LANES))

@@ -8,9 +8,9 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
 
 from repro.checkpoint import manager as ckpt
-from repro.launch.mesh import compat_make_mesh
 from repro.data.pipeline import DataConfig, TokenPipeline
 from repro.optim.adamw import AdamWConfig
 from repro.train.train_step import TrainConfig
@@ -100,7 +100,7 @@ def test_checkpoint_reshard_on_load():
     with tempfile.TemporaryDirectory() as d:
         tree = {"w": np.arange(64, dtype=np.float32).reshape(8, 8)}
         ckpt.save(d, 1, tree)
-        mesh = compat_make_mesh((1,), ("data",))
+        mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         shard = {"w": NamedSharding(mesh, P("data"))}
@@ -114,7 +114,7 @@ def test_elastic_rescale_keeps_state():
         tr = Trainer(CFG, TC, TokenPipeline(DC), d, ckpt_every=100)
         tr.run(3)
         l3 = tr.history[-1]["loss"]
-        mesh = compat_make_mesh((1,), ("data",))
+        mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
         tr.rescale(mesh)  # re-place on a "new" mesh
         h = tr.run(1)
         assert np.isfinite(h[-1]["loss"]) and h[-1]["loss"] < l3 + 1.0
@@ -127,7 +127,7 @@ def test_compressed_dp_step_matches_uncompressed():
     )
     from repro.models import lm as lm_mod
 
-    mesh = compat_make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     params = lm_mod.init_params(CFG, jax.random.PRNGKey(0))
     from repro.optim import adamw
 
