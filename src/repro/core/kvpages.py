@@ -640,19 +640,18 @@ class KVPageArena:
         """Write one token per row: payload (N, token_f32) f32, page_ids and
         slots (N,) int32 (slot = position within the page). Rows steered to
         the scratch page are don't-cares (inactive lanes)."""
-        self.lo, self.hi, self.parity = obs_profile.call(
-            "kv.commit_tokens",
-            _commit_tokens,
-            self.lo,
-            self.hi,
-            self.parity,
-            payload,
-            jnp.asarray(page_ids, jnp.int32),
-            jnp.asarray(slots, jnp.int32),
-            token_words=self.geom.token_words,
-            words_per_page=self.geom.words_per_page,
-            codec=self.codec_name,
-        )
+        with obs_profile.span("kv.commit_tokens", rows=payload.shape[0]):
+            self.lo, self.hi, self.parity = _commit_tokens(
+                self.lo,
+                self.hi,
+                self.parity,
+                payload,
+                jnp.asarray(page_ids, jnp.int32),
+                jnp.asarray(slots, jnp.int32),
+                token_words=self.geom.token_words,
+                words_per_page=self.geom.words_per_page,
+                codec=self.codec_name,
+            )
 
     def scrub_pages_async(self, page_ids):
         """Asynchronously dispatched scrub-on-read of ``page_ids`` (any
@@ -660,19 +659,26 @@ class KVPageArena:
         and returns (payload (P, page_tokens, token_f32) f32 device array,
         counters (P, 8) int32 DEVICE array) with no host sync — the caller
         defers the counter harvest (``np.asarray``) past whatever decode
-        work it wants the scrub to overlap (DESIGN.md §18)."""
+        work it wants the scrub to overlap (DESIGN.md §18). Its span counts
+        the table's entries (``pages``) and those that are not the scratch
+        page (``live_pages``): the scrub gathers every entry alike."""
         ids = jnp.asarray(page_ids, jnp.int32).reshape(-1)
-        self.lo, self.hi, self.parity, olo, ohi, cnt = obs_profile.call(
+        with obs_profile.span(
             "kv.paged_gather_scrub",
-            _scrub_rows,
-            self.lo,
-            self.hi,
-            self.parity,
-            ids,
-            words_per_page=self.geom.words_per_page,
-            codec=self.codec_name,
-            interpret=kops.use_interpret(),
-        )
+            pages=ids.shape[0],
+            live_pages=lambda: int(
+                np.count_nonzero(np.asarray(page_ids) != self.scratch_page)
+            ),
+        ):
+            self.lo, self.hi, self.parity, olo, ohi, cnt = _scrub_rows(
+                self.lo,
+                self.hi,
+                self.parity,
+                ids,
+                words_per_page=self.geom.words_per_page,
+                codec=self.codec_name,
+                interpret=kops.use_interpret(),
+            )
         payload = _planes_to_payload(
             olo.reshape(-1, self.geom.token_words),
             ohi.reshape(-1, self.geom.token_words),

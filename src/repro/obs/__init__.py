@@ -3,8 +3,8 @@
 One deterministic, causally-ordered record of what the reliability stack
 did and why: typed trace events on a step-clock (never wall-clock), a
 metrics registry fed from the existing FaultStats containers, JSONL /
-Chrome-trace / markdown exporters, and opt-in wall-clock kernel profiling
-hooks kept strictly outside the deterministic event log.
+Chrome-trace / markdown exporters, and host spans on the profiler's clock
+(``obs.profile``) kept strictly outside the deterministic event log.
 
 Quick use::
 
@@ -31,7 +31,6 @@ from repro.obs.export import (
     to_jsonl,
 )
 from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry
-from repro.obs.profile import KernelProfiler
 from repro.obs.recorder import TraceRecorder
 
 __all__ = [
@@ -41,7 +40,6 @@ __all__ = [
     "EventSchemaError",
     "Gauge",
     "Histogram",
-    "KernelProfiler",
     "MetricsRegistry",
     "TraceRecorder",
     "read_jsonl",

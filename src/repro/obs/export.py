@@ -238,7 +238,4 @@ def summary_markdown(recorder_or_events) -> str:
                 )
             lines.append(f"| `{name}` | {val} |")
 
-    profiler = getattr(recorder_or_events, "profiler", None)
-    if profiler is not None and profiler.rows:
-        lines += ["", profiler.summary_markdown()]
     return "\n".join(lines) + "\n"

@@ -1,151 +1,80 @@
-"""Opt-in wall-clock kernel profiling hooks (DESIGN.md §17).
+"""Host spans on the profiler's clock (DESIGN.md §17).
 
-Wall-clock is the one thing the deterministic trace must never contain, so
-profiling rows live here, beside the recorder rather than inside it. A
-``KernelProfiler`` is installed globally (``enable()``); instrumented
-dispatch sites route through :func:`call`, which is a single module-global
-``None`` check when profiling is off — the hot path pays nothing and the
-dispatch result is returned untouched either way.
+The serving path names its phases and dispatch sites with
+``jax.profiler.TraceAnnotation`` spans. Inside a profiler session
+(``jax.profiler.start_trace`` .. ``stop_trace``) each span lands in the same
+XSpace as the device ops, on the same clock, and its counters travel as span
+arguments (``ProfileEvent.stats`` when the trace is read back). Outside a
+session :func:`span` returns one shared null context after a single
+``TraceAnnotation.is_enabled()`` check, so the serving path does no other
+work and its outputs are bit-identical either way.
 
-When profiling is on, each call is bracketed with ``jax.block_until_ready``
-on the dispatch *result* (async dispatch would otherwise attribute device
-time to whoever synchronizes next) and the row is tagged ``interpret`` or
-``compiled`` from the kernel backend actually in force
-(kernels/backend.resolve) — the BENCH trajectory story's key column.
-
-Besides the timing rows, the profiler carries *gauges*: wall-clock-derived
-scalars that are observations about overlap/efficiency rather than per-call
-latencies — e.g. ``serve.scrub_overlap_frac``, the fraction of each async
-scrub's dispatch-to-counters-ready window that decode blocks covered
-(DESIGN.md §18). Gauges live here and NOT in the recorder's metrics for the
-same reason the timing rows do: wall-clock must never enter the
-deterministic trace.
+Spans never block: a dispatch's span covers the host's dispatch, and the
+device work it launched appears on the device planes. Wall time stays out
+of the deterministic step-clock log (obs/recorder.py).
 """
 
 from __future__ import annotations
 
-import time
+import functools
+
+from jax.profiler import TraceAnnotation
 
 
-class KernelProfiler:
-    """Aggregating wall-clock rows for named dispatch sites."""
+class _NullSpan:
+    """What :func:`span` returns outside a profiler session."""
 
-    def __init__(self):
-        self.rows: dict[str, dict] = {}
-        self.gauges: dict[str, dict] = {}
+    __slots__ = ()
 
-    def record_gauge(self, name: str, value: float) -> None:
-        """Observe one wall-clock-derived scalar (running mean + last +
-        min/max), e.g. the §18 scrub overlap fraction."""
-        g = self.gauges.get(name)
-        if g is None:
-            g = self.gauges[name] = {
-                "name": name, "n": 0, "sum": 0.0,
-                "last": 0.0, "min": None, "max": None,
-            }
-        v = float(value)
-        g["n"] += 1
-        g["sum"] += v
-        g["last"] = v
-        g["min"] = v if g["min"] is None else min(g["min"], v)
-        g["max"] = v if g["max"] is None else max(g["max"], v)
+    def __enter__(self):
+        return self
 
-    def gauge_rows(self) -> list[dict]:
-        return [
-            {**g, "mean": g["sum"] / max(g["n"], 1)}
-            for _, g in sorted(self.gauges.items())
-        ]
+    def __exit__(self, *exc):
+        return False
 
-    def record(self, name: str, ms: float) -> None:
-        row = self.rows.get(name)
-        if row is None:
-            row = self.rows[name] = {
-                "name": name, "calls": 0, "total_ms": 0.0,
-                "min_ms": None, "max_ms": 0.0, "backend": backend_tag(),
-            }
-        row["calls"] += 1
-        row["total_ms"] += ms
-        row["min_ms"] = ms if row["min_ms"] is None else min(row["min_ms"], ms)
-        row["max_ms"] = max(row["max_ms"], ms)
-
-    def to_rows(self) -> list[dict]:
-        """BENCH-shaped rows (sorted by name, mean included)."""
-        return [
-            {**r, "mean_ms": r["total_ms"] / max(r["calls"], 1)}
-            for _, r in sorted(self.rows.items())
-        ]
-
-    def summary_markdown(self) -> str:
-        lines = [
-            "## Kernel profile (wall-clock)", "",
-            "| dispatch | backend | calls | mean ms | min ms | max ms |",
-            "|---|---|---|---|---|---|",
-        ]
-        for r in self.to_rows():
-            lines.append(
-                f"| {r['name']} | {r['backend']} | {r['calls']} "
-                f"| {r['mean_ms']:.3f} | {r['min_ms']:.3f} "
-                f"| {r['max_ms']:.3f} |"
-            )
-        if self.gauges:
-            lines += [
-                "", "| gauge | n | mean | last | min | max |",
-                "|---|---|---|---|---|---|",
-            ]
-            for g in self.gauge_rows():
-                lines.append(
-                    f"| {g['name']} | {g['n']} | {g['mean']:.3f} "
-                    f"| {g['last']:.3f} | {g['min']:.3f} | {g['max']:.3f} |"
-                )
-        return "\n".join(lines) + "\n"
+    def set_metadata(self, **stats) -> None:
+        pass
 
 
-_ACTIVE: KernelProfiler | None = None
+_NULL = _NullSpan()
 
 
-def backend_tag() -> str:
-    """``interpret`` / ``compiled``: which Pallas lowering is in force."""
-    from repro.kernels import backend as _backend
-
-    return _backend.tag()
+def enabled() -> bool:
+    """True while a profiler session is recording host spans."""
+    return TraceAnnotation.is_enabled()
 
 
-def gauge(name: str, value: float) -> None:
-    """Record a wall-clock-derived gauge on the active profiler (no-op —
-    one global ``None`` check — when profiling is off)."""
-    if _ACTIVE is not None:
-        _ACTIVE.record_gauge(name, value)
+def span(name: str, **stats):
+    """A context manager that records ``name`` with ``stats`` as its
+    arguments while a profiler session runs, and does nothing otherwise.
 
-
-def enable(profiler: KernelProfiler | None = None) -> KernelProfiler:
-    """Install (and return) the active profiler."""
-    global _ACTIVE
-    _ACTIVE = profiler or KernelProfiler()
-    return _ACTIVE
-
-
-def disable() -> None:
-    global _ACTIVE
-    _ACTIVE = None
-
-
-def active() -> KernelProfiler | None:
-    return _ACTIVE
+    A stat given as a zero-argument callable is computed only inside a
+    session: pass one where a counter costs work (a pass over a page
+    table). Counters known only once the span's work is done are added
+    with ``set_metadata(**stats)`` on the entered span.
+    """
+    if not TraceAnnotation.is_enabled():
+        return _NULL
+    return TraceAnnotation(
+        name, **{k: v() if callable(v) else v for k, v in stats.items()}
+    )
 
 
 def call(name: str, fn, *args, **kwargs):
-    """Dispatch ``fn(*args, **kwargs)``, profiled when a profiler is active.
-
-    The off path is one global ``None`` check; the on path blocks on the
-    result so the row measures the dispatch it brackets, not the next sync
-    point downstream.
-    """
-    if _ACTIVE is None:
+    """Dispatch ``fn(*args, **kwargs)`` inside ``span(name)``; never blocks."""
+    with span(name):
         return fn(*args, **kwargs)
-    import jax
 
-    t0 = time.perf_counter()
-    out = fn(*args, **kwargs)
-    out = jax.block_until_ready(out)
-    _ACTIVE.record(name, (time.perf_counter() - t0) * 1e3)
-    return out
+
+def spanned(name: str):
+    """Decorator: every call of the function runs inside ``span(name)``."""
+
+    def wrap(fn):
+        @functools.wraps(fn)
+        def wrapped(*args, **kwargs):
+            with span(name):
+                return fn(*args, **kwargs)
+
+        return wrapped
+
+    return wrap

@@ -29,14 +29,11 @@ class TraceRecorder:
     and a malformed event fails at the source instead of at export.
     """
 
-    def __init__(self, strict: bool = True, profiler=None):
+    def __init__(self, strict: bool = True):
         self.events: list[dict] = []
         self.step = 0
         self.metrics = MetricsRegistry()
         self.strict = strict
-        # Optional obs.profile.KernelProfiler. Wall-clock rows live on the
-        # profiler, NOT in the event log — the log must stay deterministic.
-        self.profiler = profiler
 
     def __bool__(self) -> bool:  # `if rec:` guards at instrumented sites
         return True

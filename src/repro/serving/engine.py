@@ -45,6 +45,7 @@ from repro.kernels import backend as kbackend
 from repro.kernels import ops as kops
 from repro.models import lm
 from repro.models.base import ModelConfig
+from repro.obs import profile as obs_profile
 from repro.serving import scheduler as sched
 from repro.serving import steps as serve_steps
 
@@ -941,23 +942,24 @@ class ServingEngine:
                 # protected under it — controller state and applied
                 # protection must never diverge (DESIGN.md §12).
                 kv_codec = rail.codec
-        arena = KVPageArena(
-            geom,
-            profile,
-            n_pages,
-            seed=self.rel.seed if self.rel else 0,
-            ecc=self.rel.ecc if self.rel else True,
-            codec=kv_codec,
-            env=envp,
-        )
-        if kv_voltage is None:
-            if self.rails is not None and "kv" in self.rails:
-                kv_voltage = self.rails["kv"]
-            elif self.rel is not None:
-                kv_voltage = self.voltage
-            else:
-                kv_voltage = profile.v_nom
-        arena.set_voltage(float(kv_voltage))
+        with obs_profile.span("serve.arena", n_pages=n_pages):
+            arena = KVPageArena(
+                geom,
+                profile,
+                n_pages,
+                seed=self.rel.seed if self.rel else 0,
+                ecc=self.rel.ecc if self.rel else True,
+                codec=kv_codec,
+                env=envp,
+            )
+            if kv_voltage is None:
+                if self.rails is not None and "kv" in self.rails:
+                    kv_voltage = self.rails["kv"]
+                elif self.rel is not None:
+                    kv_voltage = self.voltage
+                else:
+                    kv_voltage = profile.v_nom
+            arena.set_voltage(float(kv_voltage))
 
         kv_controller = None
         if walk_kv:

@@ -93,6 +93,10 @@ class RequestState:
     admit_step: int = -1  # clock at FIRST admission (re-admissions keep it)
     first_token_step: int = -1
     finish_step: int = -1
+    # host ns since the stream started, stamped only inside a profiler
+    # session (ContinuousBatchingScheduler.stamp_ns); -1 otherwise
+    admit_ns: int = -1
+    first_token_ns: int = -1
 
     @property
     def rid(self) -> int:
@@ -237,6 +241,15 @@ class ContinuousBatchingScheduler:
         self.preemptions = 0
         self._admit_counter = 0
         self.fresh_pages: list = []  # allocated since last wipe drain
+        self._t0_ns = time.perf_counter_ns() if obs_profile.enabled() else None
+
+    def stamp_ns(self) -> int:
+        """Host ns since the stream started; read only while a profiler
+        session runs that was already running at the start (-1 otherwise),
+        so the clock is never read on the untraced path."""
+        if self._t0_ns is None or not obs_profile.enabled():
+            return -1
+        return time.perf_counter_ns() - self._t0_ns
 
     def _alloc(self, owner):
         """Page for ``owner``; recycles the dirty list when the clean free
@@ -316,6 +329,8 @@ class ContinuousBatchingScheduler:
             st.admit_seq = self._admit_counter
             self._admit_counter += 1
             self.lanes[lane] = st
+            if st.admit_ns < 0:
+                st.admit_ns = self.stamp_ns()
             rec = self.recorder
             if rec:
                 if st.admit_step < 0:
@@ -373,27 +388,34 @@ class ContinuousBatchingScheduler:
         self.waiting.appendleft(st)
 
     def retire(self, st: RequestState) -> None:
-        rec = self.recorder
-        if rec:
-            st.finish_step = rec.step
-            lat = rec.step - st.admit_step if st.admit_step >= 0 else 0
-            rec.emit(
-                "retire", request_id=st.rid, shard=self.shard,
-                tokens=len(st.tokens), latency_steps=lat,
-                first_token_step=st.first_token_step,
-                preemptions=st.preemptions,
-            )
-            rec.metrics.histogram("request.latency_steps").observe(lat)
-            if st.first_token_step >= 0 and st.admit_step >= 0:
-                rec.metrics.histogram("request.first_token_steps").observe(
-                    st.first_token_step - st.admit_step
+        with obs_profile.span(
+            "serve.retire",
+            request_id=st.rid,
+            admit_ns=st.admit_ns,
+            first_token_ns=st.first_token_ns,
+            done_ns=self.stamp_ns,
+        ):
+            rec = self.recorder
+            if rec:
+                st.finish_step = rec.step
+                lat = rec.step - st.admit_step if st.admit_step >= 0 else 0
+                rec.emit(
+                    "retire", request_id=st.rid, shard=self.shard,
+                    tokens=len(st.tokens), latency_steps=lat,
+                    first_token_step=st.first_token_step,
+                    preemptions=st.preemptions,
                 )
-        self.alloc.free(st.pages, st.rid)
-        self.lanes[st.lane] = None
-        st.pages, st.lane = [], -1
-        st.shared_tokens = 0
-        st.status = "finished"
-        self.finished[st.rid] = st
+                rec.metrics.histogram("request.latency_steps").observe(lat)
+                if st.first_token_step >= 0 and st.admit_step >= 0:
+                    rec.metrics.histogram("request.first_token_steps").observe(
+                        st.first_token_step - st.admit_step
+                    )
+            self.alloc.free(st.pages, st.rid)
+            self.lanes[st.lane] = None
+            st.pages, st.lane = [], -1
+            st.shared_tokens = 0
+            st.status = "finished"
+            self.finished[st.rid] = st
 
 
 def serve_stream(
@@ -549,6 +571,7 @@ def serve_stream(
         )
     pending_scrub = None  # deferred interval harvest (overlap mode)
 
+    @obs_profile.spanned("serve.scrub_dispatch")
     def _dispatch_scrub():
         """Interval scrub device work: tick, scrub-on-read, cache refresh —
         all async dispatch, no host sync. Returns the capture the deferred
@@ -601,26 +624,16 @@ def serve_stream(
         cap["gauges"] = (
             sched.alloc.free_pages, len(sched.waiting), len(sched.running)
         )
-        cap["t_dispatch"] = time.perf_counter()
         return cap
 
+    @obs_profile.spanned("serve.scrub_harvest")
     def _harvest_scrub(cap):
         """The deferred half of the interval scrub: the one host sync plus
         all stats / controller / recorder work, bit-identical to running
         inline (same counters, same reduction order, same rail move)."""
         nonlocal helpers
-        t0 = time.perf_counter()
-        cnt = np.asarray(cap["cnt"])
-        t1 = time.perf_counter()
-        if overlap and obs_profile.active():
-            # Overlap efficiency: fraction of the dispatch->counters-ready
-            # window the decode blocks covered; the residue (t1 - t0) is
-            # what serving still waited on the scrub.
-            span = max(t1 - cap["t_dispatch"], 1e-9)
-            obs_profile.gauge(
-                "serve.scrub_overlap_frac",
-                (t0 - cap["t_dispatch"]) / span,
-            )
+        with obs_profile.span("serve.scrub_sync"):
+            cnt = np.asarray(cap["cnt"])
         interval = FaultStats()  # reader-weighted attribution
         if cap["mode"] == "private":
             cnt = cnt.reshape(n_lanes, cap["p_cols"], 8)
@@ -741,16 +754,16 @@ def serve_stream(
                 )
         kv_voltages.append(arena.voltage)
 
-    while sched.unfinished:
-        # -- admission: batch same-shape prefills, commit the prompts' KV --
-        groups: dict = {}
-        for lane, st, seq in sched.admit():
-            groups.setdefault((len(seq), st.shared_tokens), []).append(
-                (lane, st, seq)
-            )
-        sched.drain_fresh_pages()  # wipe before the prompt commits below
-        for (s0, sh), grp in groups.items():
-            m = len(grp)
+
+    def _prefill_group(s0, sh, grp):
+        """One admission group of equal (prompt length, shared prefix):
+        prefill (or chunk-prefill past the shared pages), commit the
+        prompts' KV to pages and load each row into its lane."""
+        nonlocal cache, dcache, prefix_hit_tokens
+        m = len(grp)
+        with obs_profile.span(
+            "serve.prefill_group", m=m, prompt_len=s0, shared_tokens=sh
+        ):
             cachem = init_cache_fn(m)
             seqs = np.stack([seq for _, _, seq in grp])
             if sh:
@@ -815,13 +828,15 @@ def serve_stream(
             if draft_prefill is not None:
                 dcachem = lm.init_cache(draft_cfg, m, max_len)
                 _, dcachem = draft_prefill(draft_params, jnp.asarray(seqs), dcachem)
-            tok_host = np.asarray(tokm).reshape(-1)
+            with obs_profile.span("serve.prefill_sync"):
+                tok_host = np.asarray(tokm).reshape(-1)
             for row, (lane, st, _) in enumerate(grp):
                 cache = helpers["load_lane"](cache, cachem, row, lane)
                 if draft_prefill is not None:
                     dcache = helpers["load_lane"](dcache, dcachem, row, lane)
                 if not st.tokens:  # fresh admission: keep the prefill's token
                     st.tokens = [int(tok_host[row])]
+                    st.first_token_ns = sched.stamp_ns()
                     if rec and st.first_token_step < 0:
                         st.first_token_step = rec.step
                 if st.done:  # budget met by the prefill token alone
@@ -830,137 +845,167 @@ def serve_stream(
                 cur_tok[lane] = st.tokens[-1]
                 pos_v[lane] = s0
 
-        # -- block size: no lane's budget, and no scrub deadline, overrun ---
-        running = sched.running
-        if not running:
-            if not sched.unfinished:
-                break
-            assert sched.waiting, "deadlock: no lanes active and queue empty"
-            continue  # freed pages let admission proceed next iteration
-        k = min(st.req.max_new_tokens - len(st.tokens) for st in running)
-        k = max(1, min(k, max_block))
-        if scrub_interval:
-            k = max(1, min(k, scrub_interval - since_scrub))
-        k = 1 << (k.bit_length() - 1)  # power-of-two bucket: few scan shapes
+    def _decode_block() -> bool:
+        """One decode block: its size, the pages it grows into, its dispatch
+        and the tokens it books. False if page growth left no lane."""
+        nonlocal cache, dcache, steps, since_scrub, spec_dispatches, spec_emitted
+        with obs_profile.span("serve.decode_block") as block:
+            # -- block size: no lane's budget, and no scrub deadline, overrun -
+            running = sched.running
+            k = min(st.req.max_new_tokens - len(st.tokens) for st in running)
+            k = max(1, min(k, max_block))
+            if scrub_interval:
+                k = max(1, min(k, scrub_interval - since_scrub))
+            k = 1 << (k.bit_length() - 1)  # power-of-two bucket: few scan shapes
 
-        # -- page growth for the whole block; preempt on pressure -----------
-        for st in list(running):
-            if st.status == "running":  # an earlier growth may have evicted it
-                sched.ensure_pages(st, until=st.stored + k - 1)
-        active = [i for i, st in enumerate(sched.lanes) if st is not None]
-        if not active:
-            continue
-        sched.drain_fresh_pages()  # wipe growth pages before the block commits
+            # -- page growth for the whole block; preempt on pressure -------
+            with obs_profile.span("serve.page_growth") as growth:
+                for st in list(running):
+                    if st.status == "running":  # an earlier growth may evict it
+                        sched.ensure_pages(st, until=st.stored + k - 1)
+                growth.set_metadata(pages_added=len(sched.fresh_pages))
+                active = [i for i, st in enumerate(sched.lanes) if st is not None]
+                if active:
+                    sched.drain_fresh_pages()  # wipe before the block commits
+            block.set_metadata(k=k, lanes_active=len(active))
+            if not active:
+                return False
 
-        # -- k decode steps + per-token page commits in one dispatch --------
-        page_ids = np.full((k, n_rows), arena.scratch_page, np.int32)
-        slots = np.zeros((k, n_rows), np.int32)
-        for i in active:
-            st = sched.lanes[i]
-            for j in range(k):
-                t = pos_v[i] + j
-                page_ids[j, i] = st.pages[t // geom.page_tokens]
-                slots[j, i] = t % geom.page_tokens
-        if spec_k >= 2 and k >= 2:
-            # Draft k-1 tokens, verify all k in one chunked target forward;
-            # page commits land only for the accepted prefix (rejected rows
-            # steer to the scratch page inside the dispatch).
-            kk = min(k, spec_k)
-            greedy, n_emit, cache, dcache, arena.lo, arena.hi, arena.parity = (
-                helpers["spec_multistep"](
+            # -- k decode steps + per-token page commits in one dispatch ----
+            page_ids = np.full((k, n_rows), arena.scratch_page, np.int32)
+            slots = np.zeros((k, n_rows), np.int32)
+            for i in active:
+                st = sched.lanes[i]
+                for j in range(k):
+                    t = pos_v[i] + j
+                    page_ids[j, i] = st.pages[t // geom.page_tokens]
+                    slots[j, i] = t % geom.page_tokens
+            if spec_k >= 2 and k >= 2:
+                # Draft k-1 tokens, verify all k in one chunked target
+                # forward; page commits land only for the accepted prefix
+                # (rejected rows steer to the scratch page in the dispatch).
+                kk = min(k, spec_k)
+                greedy, n_emit, cache, dcache, arena.lo, arena.hi, arena.parity = (
+                    helpers["spec_multistep"](
+                        params,
+                        draft_params,
+                        jnp.asarray(cur_tok[:, None]),
+                        cache,
+                        dcache,
+                        arena.lo,
+                        arena.hi,
+                        arena.parity,
+                        jnp.asarray(pos_v),
+                        jnp.asarray(page_ids[:kk]),
+                        jnp.asarray(slots[:kk]),
+                        k=kk,
+                        scratch_page=arena.scratch_page,
+                    )
+                )
+                with obs_profile.span("serve.block_sync"):
+                    greedy_host = np.asarray(greedy)
+                    n_host = np.asarray(n_emit)
+                steps += 1
+                spec_dispatches += 1
+                adv = max((int(n_host[i]) for i in active), default=0)
+                if rec:
+                    # clock first so same-dispatch retires see the post-block step
+                    rec.advance(max(adv, 1))
+                    rec.emit(
+                        "spec_block", shard=arena.shard, k=kk,
+                        lanes=len(active),
+                        emitted=int(sum(n_host[i] for i in active)),
+                        slots=kk * len(active),
+                    )
+                    rec.metrics.counter("spec.slots").inc(kk * len(active))
+                    rec.metrics.counter("spec.emitted").inc(
+                        int(sum(n_host[i] for i in active))
+                    )
+                for i in active:
+                    st = sched.lanes[i]
+                    n = int(n_host[i])
+                    st.tokens.extend(int(t) for t in greedy_host[i, :n])
+                    spec_emitted += n
+                    cur_tok[i] = st.tokens[-1]
+                    pos_v[i] += n
+                    if st.done:
+                        sched.retire(st)
+                since_scrub += adv
+            else:
+                toks, cache, arena.lo, arena.hi, arena.parity = helpers["multistep"](
                     params,
-                    draft_params,
                     jnp.asarray(cur_tok[:, None]),
                     cache,
-                    dcache,
                     arena.lo,
                     arena.hi,
                     arena.parity,
                     jnp.asarray(pos_v),
-                    jnp.asarray(page_ids[:kk]),
-                    jnp.asarray(slots[:kk]),
-                    k=kk,
-                    scratch_page=arena.scratch_page,
+                    jnp.asarray(page_ids),
+                    jnp.asarray(slots),
                 )
-            )
-            greedy_host = np.asarray(greedy)
-            n_host = np.asarray(n_emit)
-            steps += 1
-            spec_dispatches += 1
-            adv = max((int(n_host[i]) for i in active), default=0)
-            if rec:
-                # clock first so same-dispatch retires see the post-block step
-                rec.advance(max(adv, 1))
-                rec.emit(
-                    "spec_block", shard=arena.shard, k=kk,
-                    lanes=len(active),
-                    emitted=int(sum(n_host[i] for i in active)),
-                    slots=kk * len(active),
-                )
-                rec.metrics.counter("spec.slots").inc(kk * len(active))
-                rec.metrics.counter("spec.emitted").inc(
-                    int(sum(n_host[i] for i in active))
-                )
-            for i in active:
-                st = sched.lanes[i]
-                n = int(n_host[i])
-                st.tokens.extend(int(t) for t in greedy_host[i, :n])
-                spec_emitted += n
-                cur_tok[i] = st.tokens[-1]
-                pos_v[i] += n
-                if st.done:
-                    sched.retire(st)
-            since_scrub += adv
-        else:
-            toks, cache, arena.lo, arena.hi, arena.parity = helpers["multistep"](
-                params,
-                jnp.asarray(cur_tok[:, None]),
-                cache,
-                arena.lo,
-                arena.hi,
-                arena.parity,
-                jnp.asarray(pos_v),
-                jnp.asarray(page_ids),
-                jnp.asarray(slots),
-            )
-            toks_host = np.asarray(toks)
-            steps += k
-            since_scrub += k
-            if rec:
-                rec.advance(k)  # the deterministic clock IS decode progress
-            for i in active:
-                st = sched.lanes[i]
-                st.tokens.extend(int(t) for t in toks_host[:, i])
-                cur_tok[i] = st.tokens[-1]
-                pos_v[i] += k
-                if st.done:
-                    sched.retire(st)
+                with obs_profile.span("serve.block_sync"):
+                    toks_host = np.asarray(toks)
+                steps += k
+                since_scrub += k
+                if rec:
+                    rec.advance(k)  # the deterministic clock IS decode progress
+                for i in active:
+                    st = sched.lanes[i]
+                    st.tokens.extend(int(t) for t in toks_host[:, i])
+                    cur_tok[i] = st.tokens[-1]
+                    pos_v[i] += k
+                    if st.done:
+                        sched.retire(st)
+        return True
 
-        # -- scrub interval: inject at the kv rail, scrub-on-read, refresh --
-        if scrub_interval and since_scrub >= scrub_interval:
-            since_scrub = 0
-        else:
-            continue
-        # Off-critical-path scrub (§18): interval N's counters are
-        # harvested immediately before interval N+1's tick, so the
-        # controller's rail move still lands before the next injection —
-        # exactly where the serialized path puts it — while the decode
-        # blocks in between overlapped interval N's scrub device work.
+    with obs_profile.span("serve.stream", requests=len(requests), lanes=n_lanes):
+        while sched.unfinished:
+            # -- admission: batch same-shape prefills, commit the prompts' KV
+            admitted = list(sched.admit())
+            sched.drain_fresh_pages()  # wipe before the prompt commits below
+            if admitted:
+                with obs_profile.span("serve.admit", admitted=len(admitted)):
+                    groups: dict = {}
+                    for lane, st, seq in admitted:
+                        groups.setdefault((len(seq), st.shared_tokens), []).append(
+                            (lane, st, seq)
+                        )
+                    for (s0, sh), grp in groups.items():
+                        _prefill_group(s0, sh, grp)
+
+            if not sched.running:
+                if not sched.unfinished:
+                    break
+                assert sched.waiting, "deadlock: no lanes active and queue empty"
+                continue  # freed pages let admission proceed next iteration
+            if not _decode_block():
+                continue
+
+            # -- scrub interval: inject at the kv rail, scrub-on-read, refresh
+            if scrub_interval and since_scrub >= scrub_interval:
+                since_scrub = 0
+            else:
+                continue
+            # Off-critical-path scrub (§18): interval N's counters are
+            # harvested immediately before interval N+1's tick, so the
+            # controller's rail move still lands before the next injection —
+            # exactly where the serialized path puts it — while the decode
+            # blocks in between overlapped interval N's scrub device work.
+            if pending_scrub is not None:
+                _harvest_scrub(pending_scrub)
+                pending_scrub = None
+            if sched.running:
+                cap = _dispatch_scrub()
+                if overlap:
+                    pending_scrub = cap
+                else:
+                    _harvest_scrub(cap)
+
         if pending_scrub is not None:
+            # Stream drained with a scrub in flight: harvest before teardown
+            # so the report's stats/voltages match the serialized path.
             _harvest_scrub(pending_scrub)
             pending_scrub = None
-        if sched.running:
-            cap = _dispatch_scrub()
-            if overlap:
-                pending_scrub = cap
-            else:
-                _harvest_scrub(cap)
-
-    if pending_scrub is not None:
-        # Stream drained with a scrub in flight: harvest before teardown so
-        # the report's stats/voltages match the serialized path exactly.
-        _harvest_scrub(pending_scrub)
-        pending_scrub = None
 
     if trie is not None:
         # Serve teardown: the prefix cache has no meaning past this stream,

@@ -29,21 +29,13 @@ from repro.models.base import ModelConfig
 from repro.obs import profile as obs_profile
 
 
-def _profiled(name: str, fn):
-    """Route a jit'd dispatch through the opt-in wall-clock profiler.
-
-    When no profiler is enabled this is a single ``is None`` check on top
-    of the call (obs/profile.call) — the deterministic event log never
-    sees these timings, so traces stay bit-reproducible either way.
-    """
-    if fn is None:
-        return None
-
-    @functools.wraps(fn)
-    def wrapped(*args, **kwargs):
-        return obs_profile.call(name, fn, *args, **kwargs)
-
-    return wrapped
+def _jit_named(name: str, fn, static_argnames=(), **bound):
+    """``jax.jit`` of ``fn`` with ``bound`` keywords fixed, compiled as the
+    program ``jit_<name>``: a bare ``functools.partial`` has no name, and
+    every such program would show up as ``jit__unknown`` in a trace."""
+    f = functools.partial(fn, **bound)
+    f.__name__ = f.__qualname__ = name
+    return jax.jit(f, static_argnames=static_argnames)
 
 
 def make_prefill_step(cfg: ModelConfig):
@@ -322,39 +314,36 @@ def make_paged_helpers(
     """Build the jit'd :class:`PagedHelpers` bundle for one (config,
     geometry, codec) triple. ``draft_cfg`` enables ``spec_multistep`` (the
     draft model's decode runs inside the same scanned dispatch)."""
+    spanned = obs_profile.spanned
     spec = None
     if draft_cfg is not None:
-        spec = _profiled(
-            "decode.spec_multistep",
-            jax.jit(
-                functools.partial(
-                    _spec_multistep,
-                    cfg=cfg, dcfg=draft_cfg, geom=geom, codec=codec,
-                ),
+        spec = spanned("decode.spec_multistep")(
+            _jit_named(
+                "spec_multistep",
+                _spec_multistep,
                 static_argnames=("k", "scratch_page"),
-            ),
+                cfg=cfg, dcfg=draft_cfg, geom=geom, codec=codec,
+            )
         )
     return PagedHelpers(
         codec=codec,
-        prefill=_profiled("decode.prefill", jax.jit(make_prefill_step(cfg))),
-        multistep=_profiled(
-            "decode.multistep",
-            jax.jit(
-                functools.partial(_multistep, cfg=cfg, geom=geom, codec=codec)
-            ),
+        prefill=spanned("decode.prefill")(jax.jit(make_prefill_step(cfg))),
+        multistep=spanned("decode.multistep")(
+            _jit_named("multistep", _multistep, cfg=cfg, geom=geom, codec=codec)
         ),
-        extract_range=jax.jit(
-            functools.partial(_extract_range, geom=geom), static_argnames=("s0",)
+        extract_range=_jit_named(
+            "extract_range", _extract_range, static_argnames=("s0",), geom=geom
         ),
-        extract_span=jax.jit(
-            functools.partial(_extract_span, geom=geom),
+        extract_span=_jit_named(
+            "extract_span",
+            _extract_span,
             static_argnames=("start", "stop"),
+            geom=geom,
         ),
         load_lane=jax.jit(_load_lane),
-        refresh=jax.jit(functools.partial(_refresh_cache, geom=geom)),
-        chunk=_profiled(
-            "decode.chunk_prefill",
-            jax.jit(functools.partial(_chunk_prefill, cfg=cfg)),
+        refresh=_jit_named("refresh", _refresh_cache, geom=geom),
+        chunk=spanned("decode.chunk_prefill")(
+            _jit_named("chunk_prefill", _chunk_prefill, cfg=cfg)
         ),
         spec_multistep=spec,
     )

@@ -16,7 +16,6 @@ from repro.models import lm
 from repro.obs import (
     EVENT_KINDS,
     EventSchemaError,
-    KernelProfiler,
     MetricsRegistry,
     TraceRecorder,
     read_jsonl,
@@ -177,27 +176,6 @@ def test_faultstats_to_dict_and_coverage_row():
 
 
 # ---------------------------------------------------------------------------
-# profiler (wall-clock strictly quarantined from the event log)
-# ---------------------------------------------------------------------------
-def test_profiler_records_only_when_enabled():
-    calls = []
-    fn = lambda x: (calls.append(x), x * 2)[1]
-    assert obs_profile.active() is None
-    assert obs_profile.call("noop", fn, 3) == 6  # off: passthrough
-    prof = obs_profile.enable(KernelProfiler())
-    try:
-        assert obs_profile.call("timed", fn, 4) == 8
-    finally:
-        obs_profile.disable()
-    assert obs_profile.active() is None
-    rows = prof.to_rows()
-    assert [r["name"] for r in rows] == ["timed"]
-    assert rows[0]["calls"] == 1 and rows[0]["total_ms"] >= 0.0
-    assert rows[0]["backend"] in ("interpret", "compiled")
-    assert calls == [3, 4]
-
-
-# ---------------------------------------------------------------------------
 # serve traces: determinism, bit-identity, export round-trips
 # ---------------------------------------------------------------------------
 @pytest.fixture(scope="module")
@@ -212,8 +190,8 @@ def serve_setup():
     return cfg, params, reqs
 
 
-def _serve(cfg, params, reqs, recorder=None):
-    eng = ServingEngine(
+def _engine(cfg, params, recorder=None):
+    return ServingEngine(
         cfg, params,
         rel=ReliabilityConfig(
             mode="inline", voltage=0.58, multi_rail=True,
@@ -221,6 +199,10 @@ def _serve(cfg, params, reqs, recorder=None):
         ),
         max_len=64, recorder=recorder,
     )
+
+
+def _serve(cfg, params, reqs, recorder=None, eng=None):
+    eng = eng or _engine(cfg, params, recorder)
     return eng.serve(reqs, n_lanes=2, scrub_interval=2, walk_kv=True,
                      kv_voltage=0.57)
 
@@ -319,6 +301,123 @@ def test_summary_markdown_renders(serve_setup, tmp_path):
     out = tmp_path / "t.md"
     assert report_cli.main([str(p), "--out", str(out), "--validate"]) == 0
     assert "## Event counts" in out.read_text()
+
+
+# ---------------------------------------------------------------------------
+# host spans (obs/profile.py): on the profiler's clock, never in the event log
+# ---------------------------------------------------------------------------
+class _NoAnnotation:
+    """Stands in for TraceAnnotation outside a session: entering one fails."""
+
+    @staticmethod
+    def is_enabled():
+        return False
+
+    def __init__(self, *a, **kw):
+        raise AssertionError("a span was entered outside a profiler session")
+
+
+def test_span_outside_a_session_is_null_and_computes_no_stat(monkeypatch):
+    monkeypatch.setattr(obs_profile, "TraceAnnotation", _NoAnnotation)
+    calls = []
+    fn = lambda x: (calls.append(x), x * 2)[1]
+    assert obs_profile.call("noop", fn, 3) == 6  # passthrough, no block
+    assert calls == [3]
+
+    def costly():
+        raise AssertionError("a stat was computed outside a profiler session")
+
+    sp = obs_profile.span("kv.paged_gather_scrub", pages=4, live_pages=costly)
+    assert sp is obs_profile.span("serve.decode_block")  # one shared null
+    with sp as entered:
+        entered.set_metadata(k=costly)
+    assert not obs_profile.enabled()
+
+
+@pytest.fixture(scope="module")
+def traced_serve(serve_setup, tmp_path_factory):
+    """Two engines alike, each with its own recorder, each serving the
+    stream twice (the first serve compiles): one with no profiler session,
+    where entering a span or reading the clock for the request stamps
+    fails; one whose second serve runs inside a session. That session's
+    XSpace is read back as program spans by bench/benchlib/spans.py, the
+    reader the benchmark uses."""
+    import os
+    import sys
+
+    from repro.serving import scheduler
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(__file__)), "bench")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from benchlib import spans, tracing
+
+    def no_clock():
+        raise AssertionError("the host clock was read outside a session")
+
+    cfg, params, reqs = serve_setup
+    rec_off, rec_on = TraceRecorder(), TraceRecorder()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(obs_profile, "TraceAnnotation", _NoAnnotation)
+        mp.setattr(scheduler.time, "perf_counter_ns", no_clock)
+        eng = _engine(cfg, params, rec_off)
+        r_off = [_serve(cfg, params, reqs, eng=eng) for _ in range(2)]
+    eng = _engine(cfg, params, rec_on)
+    r_on = [_serve(cfg, params, reqs, eng=eng)]
+    trace_dir = tmp_path_factory.mktemp("xspace")
+    jax.profiler.start_trace(str(trace_dir))
+    try:
+        assert obs_profile.enabled()
+        r_on.append(_serve(cfg, params, reqs, eng=eng))
+    finally:
+        jax.profiler.stop_trace()
+    sp = spans.program_spans(tracing.load(str(trace_dir)))
+    return {"off": (r_off, rec_off), "on": (r_on, rec_on), "spans": sp, "reqs": reqs}
+
+
+def test_serve_outside_a_session_enters_no_span(traced_serve):
+    """The whole serving path with no profiler session entered no span and
+    never read the clock (the fixture's guards would have raised)."""
+    r_off, _ = traced_serve["off"]
+    assert [len(r.outputs) for r in r_off] == [len(traced_serve["reqs"])] * 2
+
+
+def test_session_spans_nest_and_count(traced_serve):
+    sp = traced_serve["spans"]
+    named = lambda n: [s for s in sp if s[0] == n]
+    blocks = named("serve.decode_block")
+    assert blocks and named("decode.multistep")
+    for _, s, e, _ in named("decode.multistep"):
+        assert any(b[1] <= s and e <= b[2] for b in blocks)
+    for _, s, e, _ in named("serve.block_sync"):
+        assert any(b[1] <= s and e <= b[2] for b in blocks)
+    assert all(st["k"] >= 1 and st["lanes_active"] >= 1 for *_, st in blocks)
+    scrubs = named("kv.paged_gather_scrub")
+    assert scrubs
+    for *_, st in scrubs:
+        assert 0 < st["live_pages"] <= st["pages"]
+    retires = named("serve.retire")
+    assert sorted(st["request_id"] for *_, st in retires) == list(
+        range(len(traced_serve["reqs"]))
+    )
+    for *_, st in retires:
+        assert 0 <= st["admit_ns"] <= st["first_token_ns"] <= st["done_ns"]
+    (stream,) = named("serve.stream")
+    assert stream[3] == {"requests": len(traced_serve["reqs"]), "lanes": 2}
+    assert all(stream[1] <= s[1] and s[2] <= stream[2] for s in blocks + scrubs)
+
+
+def test_session_leaves_outputs_and_recorder_byte_identical(traced_serve, tmp_path):
+    (r_offs, rec_off), (r_ons, rec_on) = traced_serve["off"], traced_serve["on"]
+    for r_off, r_on in zip(r_offs, r_ons):
+        assert set(r_off.outputs) == set(r_on.outputs)
+        for rid in r_off.outputs:
+            assert r_off.outputs[rid].tobytes() == r_on.outputs[rid].tobytes(), rid
+        assert r_off.kv_stats.counters().tolist() == r_on.kv_stats.counters().tolist()
+        assert r_off.kv_voltages == r_on.kv_voltages
+    rec_off.to_jsonl(tmp_path / "off.jsonl")
+    rec_on.to_jsonl(tmp_path / "on.jsonl")
+    assert (tmp_path / "off.jsonl").read_bytes() == (tmp_path / "on.jsonl").read_bytes()
 
 
 def test_to_jsonl_accepts_events_or_recorder(tmp_path):
