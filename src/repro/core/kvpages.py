@@ -18,10 +18,12 @@ Layout
     continuous-batching scheduler (serving/scheduler.py) allocates one page
     per ``page_tokens`` positions per request and frees them on retire or
     preemption.
-  * Writes encode (kernels/ops.encode); reads gather page rows and travel
-    through the scrub-on-read kernel (kernels/paged_gather.py) which
-    corrects single-bit faults, writes the corrected planes back, and emits
-    per-page (clean, corrected, detected) counters.
+  * Writes encode (kernels/ops.encode); reads slice each page out of the
+    flat planes as one contiguous ``words_per_page`` window addressed by its
+    start offset (`_gather_pages`), travel through the scrub-on-read kernel
+    (kernels/paged_gather.py) which corrects single-bit faults and emits
+    per-page (clean, corrected, detected) counters, and the corrected pages
+    are written back window by window (`_scatter_pages`).
   * `tick()` injects one interval's undervolting faults at the current `kv`
     rail voltage. Unlike the weight store — which keeps clean planes and
     re-derives the faulty view per voltage — the cache is mutable, so faults
@@ -439,19 +441,52 @@ def _planes_to_payload(lo, hi):
 
 
 @jax.jit
-def _scatter_rows(plane, idx, rows):
-    return plane.at[idx].set(rows)
-
-
-@jax.jit
 def _xor_into(plane, mask):
     return plane ^ mask
 
 
-def _row_index(page_ids, words_per_page):
-    """(P,) page ids -> (P, words_per_page) flat word indices."""
-    return page_ids[:, None] * words_per_page + jnp.arange(
-        words_per_page, dtype=jnp.int32
+# A flat plane addressed by page: one int32 start offset ``page * W`` per
+# page and a contiguous W-word window, so no per-word index exists.
+_PAGE_WINDOW = jax.lax.GatherDimensionNumbers(
+    offset_dims=(1,), collapsed_slice_dims=(), start_index_map=(0,)
+)
+_PAGE_UPDATE = jax.lax.ScatterDimensionNumbers(
+    update_window_dims=(1,), inserted_window_dims=(), scatter_dims_to_operand_dims=(0,)
+)
+
+
+def _gather_pages(plane, page_ids, words_per_page):
+    """(n_words,) flat plane, (P,) page ids -> (P, words_per_page) rows."""
+    return jax.lax.gather(
+        plane,
+        (page_ids * words_per_page)[:, None],
+        _PAGE_WINDOW,
+        slice_sizes=(words_per_page,),
+        mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS,
+    )
+
+
+def _scatter_pages(plane, page_ids, rows, words_per_page):
+    """Write (P, words_per_page) rows back over their pages of the flat
+    plane. Repeated ids (idle lanes' scratch page) carry identical rows, so
+    the order in which duplicates land does not matter."""
+    return jax.lax.scatter(
+        plane,
+        (page_ids * words_per_page)[:, None],
+        rows,
+        _PAGE_UPDATE,
+        mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS,
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("words_per_page",))
+def _zero_pages(lo, hi, par, page_ids, *, words_per_page):
+    """All-zero data and check bits over every page of ``page_ids``."""
+    return tuple(
+        _scatter_pages(
+            p, page_ids, jnp.zeros((page_ids.shape[0], words_per_page), p.dtype), words_per_page
+        )
+        for p in (lo, hi, par)
     )
 
 
@@ -473,12 +508,22 @@ def _commit_tokens(
     jax.jit, static_argnames=("words_per_page", "codec", "interpret")
 )
 def _scrub_rows(lo, hi, par, page_ids, *, words_per_page, codec, interpret):
-    """Gather page rows, scrub-on-read, write corrected planes back."""
-    idx = _row_index(page_ids, words_per_page)
+    """Gather page rows, scrub-on-read, write corrected planes back.
+
+    Each page moves as one contiguous ``words_per_page`` window addressed by
+    its start offset (`_gather_pages` / `_scatter_pages`), so the (P, W)
+    rows the kernel reads are sliced, not gathered word by word. Returns
+    ``(lo, hi, par, olo (P, W), ohi (P, W), counters (P, 8))``."""
     olo, ohi, opar, cnt = paged_gather.gather_scrub_pages(
-        lo[idx], hi[idx], par[idx], codec=codec, interpret=interpret
+        *(_gather_pages(p, page_ids, words_per_page) for p in (lo, hi, par)),
+        codec=codec,
+        interpret=interpret,
     )
-    return lo.at[idx].set(olo), hi.at[idx].set(ohi), par.at[idx].set(opar), olo, ohi, cnt
+    lo, hi, par = (
+        _scatter_pages(p, page_ids, o, words_per_page)
+        for p, o in ((lo, olo), (hi, ohi), (par, opar))
+    )
+    return lo, hi, par, olo, ohi, cnt
 
 
 class KVPageArena:
@@ -628,12 +673,9 @@ class KVPageArena:
         ids = jnp.asarray(page_ids, jnp.int32).reshape(-1)
         if ids.size == 0:
             return
-        idx = _row_index(ids, self.geom.words_per_page)
-        z32 = jnp.zeros(idx.shape, jnp.uint32)
-        self.lo = _scatter_rows(self.lo, idx, z32)
-        self.hi = _scatter_rows(self.hi, idx, z32)
-        self.parity = _scatter_rows(
-            self.parity, idx, jnp.zeros(idx.shape, self.parity.dtype)
+        self.lo, self.hi, self.parity = _zero_pages(
+            self.lo, self.hi, self.parity, ids,
+            words_per_page=self.geom.words_per_page,
         )
 
     def commit_tokens(self, payload, page_ids, slots) -> None:

@@ -230,27 +230,19 @@ def make_kv_scrub_step(
     counters but never reads the gathered payload, and skipping it removes
     2 * table_cols * words_per_page words of per-step output traffic.
     """
-    from repro.kernels import paged_gather
+    from repro.core.kvpages import _scrub_rows
 
     axes = reliability_axes(mesh)
     spec = _axes_spec(axes)
     interpret = kops.use_interpret()
 
     def body(lo, hi, par, table):
-        idx = table[0][:, None] * words_per_page + jnp.arange(
-            words_per_page, dtype=jnp.int32
+        lo, hi, par, olo, ohi, cnt = _scrub_rows(
+            lo, hi, par, table[0],
+            words_per_page=words_per_page, codec=codec, interpret=interpret,
         )
-        olo, ohi, opar, cnt = paged_gather.gather_scrub_pages(
-            lo[idx], hi[idx], par[idx], codec=codec, interpret=interpret
-        )
-        out = (
-            lo.at[idx].set(olo),
-            hi.at[idx].set(ohi),
-            par.at[idx].set(opar),
-        )
-        if with_payload:
-            out += (olo[None], ohi[None])
-        return out + (cnt[None],)
+        payload = (olo[None], ohi[None]) if with_payload else ()
+        return (lo, hi, par, *payload, cnt[None])
 
     n_out = 6 if with_payload else 4
     fn = jax.shard_map(

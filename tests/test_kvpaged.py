@@ -194,10 +194,10 @@ def test_allocator_invariants():
     assert alloc.alloc("d") == a
 
 
-def _mk_arena(page_tokens=2, n_pages=3):
+def _mk_arena(page_tokens=2, n_pages=3, codec="secded72"):
     cfg = get_smoke_config("qwen3-0.6b")
     geom = KVGeometry.from_config(cfg, page_tokens)
-    return KVPageArena(geom, vmod.PLATFORMS["vc707"], n_pages), geom
+    return KVPageArena(geom, vmod.PLATFORMS["vc707"], n_pages, codec=codec), geom
 
 
 def test_per_page_counters_single_and_double_bit():
@@ -257,6 +257,58 @@ def test_fresh_page_wipe_clears_accumulated_free_page_faults():
     _, cnt2 = arena.scrub_pages([2])
     assert cnt2[0, 0] == geom.words_per_page
     assert cnt2[0, 1] == 0 and cnt2[0, 2] == 0
+
+
+def _word_index_scrub(lo, hi, par, page_ids, *, words_per_page, codec):
+    """The scrub as it addressed pages through one int32 index per word: the
+    reference the page-window form must match bit for bit."""
+    from repro.kernels import ops, paged_gather
+
+    idx = page_ids[:, None] * words_per_page + jnp.arange(words_per_page, dtype=jnp.int32)
+    olo, ohi, opar, cnt = paged_gather.gather_scrub_pages(
+        lo[idx], hi[idx], par[idx], codec=codec, interpret=ops.use_interpret()
+    )
+    return lo.at[idx].set(olo), hi.at[idx].set(ohi), par.at[idx].set(opar), olo, ohi, cnt
+
+
+@pytest.mark.parametrize("table", ["unsorted_scratch", "dedup"])
+@pytest.mark.parametrize("codec", ["secded72", "dected79", "ileave88"])
+def test_page_window_scrub_matches_word_index_form(codec, table):
+    """`_scrub_rows` slices whole pages by their start offsets; its planes,
+    payload planes and counters equal the per-word-index formulation's on an
+    arena with single-, double- and triple-bit flips, one of them in the
+    scratch page that idle lanes repeat."""
+    from repro.core.kvpages import _scrub_rows, dedup_page_table
+    from repro.kernels import ops
+
+    arena, geom = _mk_arena(page_tokens=2, n_pages=6, codec=codec)
+    rng = np.random.default_rng(7)
+    n_tok = geom.page_tokens * arena.n_pages
+    arena.commit_tokens(
+        jnp.asarray(rng.standard_normal((n_tok, geom.token_f32)).astype(np.float32)),
+        np.repeat(np.arange(arena.n_pages), geom.page_tokens),
+        np.tile(np.arange(geom.page_tokens), arena.n_pages),
+    )
+    w, s = geom.words_per_page, arena.scratch_page
+    lo, hi, par = (np.asarray(p).copy() for p in (arena.lo, arena.hi, arena.parity))
+    lo[0 * w + 3] ^= np.uint32(1 << 7)  # single
+    hi[2 * w + 5] ^= np.uint32(0b101)  # double
+    lo[4 * w + 1] ^= np.uint32(0b10011)  # triple
+    par[3 * w + 2] ^= par.dtype.type(1)  # check bit
+    hi[s * w + 4] ^= np.uint32(1 << 30)  # the scratch page
+    ids = np.array([4, s, 0, 3, s, s, 2, 5], np.int32)
+    if table == "dedup":
+        ids = dedup_page_table(np.array([[4, 2, s], [2, 0, s], [5, 4, 3]]), s)[0]
+    ids = jnp.asarray(ids)
+    planes = tuple(jnp.asarray(p) for p in (lo, hi, par))
+    want = _word_index_scrub(*planes, ids, words_per_page=w, codec=codec)
+    got = _scrub_rows(
+        *planes, ids, words_per_page=w, codec=codec, interpret=ops.use_interpret()
+    )
+    assert np.asarray(want[5])[:, 1:3].sum() > 0  # the flips were seen
+    for name, a, b in zip(("lo", "hi", "par", "olo", "ohi", "cnt"), want, got):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
 
 
 def test_kv_rail_walks_independently_of_weight_rails(setup):

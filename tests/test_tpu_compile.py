@@ -12,6 +12,7 @@ only the worker that runs this file loads the TPU compiler library.
 """
 
 import os
+import re
 
 import pytest
 
@@ -20,7 +21,7 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.qwen3_0_6b import config as qwen3_config
-from repro.core.kvpages import KVGeometry
+from repro.core.kvpages import KVGeometry, _gather_pages, _scatter_pages
 from repro.kernels import ecc_matmul, inject_scrub, ops, paged_gather, secded
 
 CFG = qwen3_config()
@@ -96,6 +97,28 @@ def test_gather_scrub_compiles(chip, pages):
     bp = min(16, pages)  # gather_scrub_pages' page block
     p32, p8 = ((pages, words), jnp.uint32), ((pages, words), jnp.uint8)
     _compile(paged_gather.gather_scrub_2d, chip, p32, p32, p8, page_block=bp)
+
+
+@pytest.mark.parametrize("helper", ["gather", "scatter"])
+def test_page_window_addressing_has_no_word_index(chip, helper):
+    """The interval scrub's page addressing at the qwen3-0.6b page width and
+    a 128-entry table: one int32 start offset per page, so the compiled
+    program holds no int32 array of P x W elements (a per-word index)."""
+    pages, words = 128, KVGeometry.from_config(CFG).words_per_page
+    arena = ((115 * words,), jnp.uint32)
+    shapes = [arena, ((pages,), jnp.int32)]
+    fn = _gather_pages
+    if helper == "scatter":
+        shapes.append(((pages, words), jnp.uint32))
+        fn = _scatter_pages
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=chip) for s, dt in shapes]
+    text = jax.jit(fn, static_argnums=len(args)).lower(*args, words).compile().as_text()
+    assert f"u32[{pages},{words}]" in text
+    for dims in re.findall(r"s32\[([\d,]+)\]", text):
+        n = 1
+        for d in dims.split(","):
+            n *= int(d)
+        assert n < pages * words, f"s32[{dims}]"
 
 
 def test_secded_encode_decode_compile(chip):
