@@ -1,9 +1,10 @@
 """The comparison that decides ``correct``.
 
 After the window closes, a sample of the requests the window finished,
-drawn from the seed and always holding the longest, is run through the
-plain float32 reference once, teacher-forced on each prompt and its served
-tokens. At every served position the gap is the reference's best logit
+drawn from the seed and always holding the longest, is run once through
+the plain float32 reference that the configuration's family names
+(``bench/reference/<REFERENCE>.py``), teacher-forced on each prompt and
+its served tokens. At every served position the gap is the reference's best logit
 minus the reference's logit of the token the program served; the number
 compared is the widest gap of the sample. Greedy serving with exact
 arithmetic gives 0; rounding in the program's bfloat16 path shows as small
@@ -40,14 +41,14 @@ def sample(finished: list, seed: int, target_tokens: int) -> list[int]:
     return picked
 
 
-def widest_gap(rw: dict, cfg: dict, items: list, max_len: int, rounding: str | None = None) -> dict:
-    """Widest gap over ``items`` [(prompt, served)].
+def widest_gap(reference, rw: dict, cfg: dict, items: list, max_len: int,
+               rounding: str | None = None) -> dict:
+    """Widest gap over ``items`` [(prompt, served)] under ``reference``,
+    the module ``cfg``'s family names.
 
     ``rounding=None``: gap of each served token. Otherwise: gap of the
     token the reference computed at ``rounding`` puts first."""
     import jax.numpy as jnp
-
-    from reference import dense_lm
 
     per_request = []
     for prompt, served in items:
@@ -57,11 +58,11 @@ def widest_gap(rw: dict, cfg: dict, items: list, max_len: int, rounding: str | N
         body = np.concatenate([prompt, served[:-1]])
         seq[: len(body)] = body
         pos = np.arange(len(prompt) - 1, len(prompt) - 1 + len(served))
-        ref = dense_lm.logits(rw, cfg, seq)[pos]
+        ref = reference.logits(rw, cfg, seq)[pos]
         if rounding is None:
             choice = jnp.asarray(served)
         else:
-            choice = jnp.argmax(dense_lm.logits(rw, cfg, seq, rounding)[pos], axis=-1)
+            choice = jnp.argmax(reference.logits(rw, cfg, seq, rounding)[pos], axis=-1)
         picked = jnp.take_along_axis(ref, choice[:, None], axis=-1)[:, 0]
         gap = jnp.max(ref, axis=-1) - picked
         per_request.append(float(jnp.max(gap)))

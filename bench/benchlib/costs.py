@@ -52,28 +52,11 @@ def gather_scrub(pages: int, words_per_page: int, check_bytes: int = 1):
     return 0.0, 2.0 * words * (8 + check_bytes) + pages * 128 * 4
 
 
-def matmul_params(cfg: dict) -> int:
-    """Parameters that each token multiplies: every projection and the head
-    (the embedding gather is not a matmul)."""
-    d, f, n_l = cfg["hidden_size"], cfg["intermediate_size"], cfg["num_hidden_layers"]
-    hd = cfg["head_dim"]
-    qd, kd = cfg["num_attention_heads"] * hd, cfg["num_key_value_heads"] * hd
-    per_layer = d * qd + 2 * d * kd + qd * d + 3 * d * f
-    return n_l * per_layer + d * cfg["vocab_size"]
-
-
-def token_flops(cfg: dict, context: int) -> float:
-    """Model FLOPs of one token that attends over ``context`` positions
-    (itself included): 2 per matmul parameter, and 4 * head_dim per query
-    head per attended position for the scores and the weighted sum."""
-    attn = 4.0 * cfg["num_hidden_layers"] * cfg["num_attention_heads"] * cfg["head_dim"] * context
-    return 2.0 * matmul_params(cfg) + attn
-
-
-def request_flops(cfg: dict, prompt: int, output: int) -> float:
+def request_flops(cfg: dict, prompt: int, output: int, family) -> float:
     """Model FLOPs of one served request: the prompt's forward pass, then
     one forward per generated token after the first (the first comes out
-    of the prefill)."""
-    total = sum(token_flops(cfg, i + 1) for i in range(prompt))
-    total += sum(token_flops(cfg, prompt + j + 1) for j in range(output - 1))
+    of the prefill), each token's FLOPs from ``cfg``'s family."""
+    flops = family.token_flops
+    total = sum(flops(cfg, i + 1) for i in range(prompt))
+    total += sum(flops(cfg, prompt + j + 1) for j in range(output - 1))
     return total

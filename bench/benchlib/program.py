@@ -1,9 +1,10 @@
 """The benchmark's only contact with the system under test.
 
-Builds the program's model configuration and parameter tree from a
-configuration file and the canonical weights, builds ``ServingEngine`` with
-the configuration's protection, and drives ``serve()``: the entry the
-window measures. Also warms up every shape a cell's waves can reach.
+Builds ``ServingEngine`` with the configuration's protection, from the
+program's parameter tree and model configuration that the configuration's
+family (``bench/families/<family>.py``) makes, and drives ``serve()``: the
+entry the window measures. Also warms up every shape a cell's waves can
+reach.
 """
 
 from __future__ import annotations
@@ -11,50 +12,7 @@ from __future__ import annotations
 from benchlib import traffic as traffic_mod
 
 
-def model_config(cfg: dict):
-    import jax.numpy as jnp
-
-    from repro.models.base import ModelConfig
-
-    dtype = jnp.dtype(cfg["torch_dtype"])
-    return ModelConfig(
-        name=cfg["name"],
-        family="dense",
-        n_layers=cfg["num_hidden_layers"],
-        d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"],
-        head_dim=cfg["head_dim"],
-        d_ff=cfg["intermediate_size"],
-        vocab=cfg["vocab_size"],
-        qkv_bias=cfg["attention_bias"],
-        qk_norm=cfg["qk_norm"],
-        rope_theta=float(cfg["rope_theta"]),
-        tie_embeddings=cfg["tie_word_embeddings"],
-        param_dtype=dtype,
-        compute_dtype=dtype,
-    )
-
-
-def program_params(w: dict, cfg: dict) -> dict:
-    """Canonical weights -> the program's parameter tree (models/lm.py)."""
-    attn = {k: w[k] for k in ("wq", "wk", "wv", "wo")}
-    for k in ("bq", "bk", "bv", "q_norm", "k_norm"):
-        if k in w:
-            attn[k] = w[k]
-    layer = {
-        "ln1": {"gamma": w["ln1"]},
-        "ln2": {"gamma": w["ln2"]},
-        "attn": attn,
-        "mlp": {"w1": w["gate"], "w3": w["up"], "w2": w["down"]},
-    }
-    tree = {"embed": w["embed"], "blocks": {"p0": layer}, "final_norm": {"gamma": w["final_norm"]}}
-    if not cfg["tie_word_embeddings"]:
-        tree["lm_head"] = w["lm_head"]
-    return tree
-
-
-def build_engine(cfg: dict, params, max_len: int):
+def build_engine(cfg: dict, params, max_len: int, family):
     from repro.serving.engine import (
         FaultModelConfig,
         RailsConfig,
@@ -71,7 +29,7 @@ def build_engine(cfg: dict, params, max_len: int):
         rails=RailsConfig(multi_rail=prot["multi_rail"]),
         fault_model=FaultModelConfig(mask_source=prot["mask_source"]),
     )
-    return ServingEngine(model_config(cfg), params, rel, max_len=max_len)
+    return ServingEngine(family.model_config(cfg), params, rel, max_len=max_len)
 
 
 def serve(eng, requests, mix: dict, cfg: dict):

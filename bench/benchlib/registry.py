@@ -4,15 +4,21 @@ readers by the names ``BENCHMARK.json`` gives them.
 Everything that belongs to one configuration, one traffic mix or one
 per-layer metric lives in a file of its own under ``bench/``:
 
-  bench/configs/<config>.json   sizes, precision, protection, serve settings
+  bench/configs/<config>.json   sizes, precision, protection, serve settings,
+                                and ``family``: the layer structure
+  bench/families/<family>.py    weight layout, program adapter, FLOP count
+                                and the name of the plain reference
+  bench/reference/<module>.py   the plain reference a family names
   bench/traffic/<traffic>.json  wave composition (lengths, lanes, max_len)
   bench/metrics/<metric>.py     a reader ``read(ctx) -> float | None``
 
-so a new cell, configuration or metric is added with files and entries only.
+so a new cell, configuration, layer structure or metric is added with files
+and entries only.
 """
 
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 import os
@@ -78,12 +84,39 @@ def metrics_for(bench: dict, workload: str, trace: bool) -> list[dict]:
     return [m for m in entries if workload in m.get("workloads", [workload])]
 
 
+def _import(path: str, module_name: str, what: str):
+    if not os.path.isfile(path):
+        raise LookupFailed(f"missing {what} {path}")
+    spec = importlib.util.spec_from_file_location(module_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def metric_reader(name: str, root: str = ROOT):
     """The ``read(ctx)`` function of ``bench/metrics/<name>.py``."""
     path = os.path.join(root, "bench", "metrics", f"{name}.py")
-    if not os.path.isfile(path):
-        raise LookupFailed(f"missing metric reader {path}")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _import(path, f"bench_metric_{name}", "metric reader").read
+
+
+@functools.lru_cache(maxsize=None)
+def load_family(name: str, root: str = ROOT):
+    """The module ``bench/families/<name>.py`` (its interface is in
+    ``bench/families/dense.py``); imported once per file."""
+    path = os.path.join(root, "bench", "families", f"{name}.py")
+    return _import(path, f"bench_family_{name}", "family")
+
+
+def config_family(cfg: dict, root: str = ROOT):
+    """The family module that a configuration names under ``family``."""
+    if "family" not in cfg:
+        raise LookupFailed(f"config {cfg.get('name')!r} has no key 'family'")
+    return load_family(cfg["family"], root)
+
+
+@functools.lru_cache(maxsize=None)
+def load_reference(name: str, root: str = ROOT):
+    """The plain reference ``bench/reference/<name>.py``: ``prepare(w, cfg)``
+    and ``logits(rw, cfg, tokens, rounding=None)``; imported once per file."""
+    path = os.path.join(root, "bench", "reference", f"{name}.py")
+    return _import(path, f"bench_reference_{name}", "reference")

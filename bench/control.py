@@ -39,28 +39,29 @@ def main(argv=None) -> int:
     bench = registry.load_benchmark(ROOT)
     work = registry.find_workload(bench, args.workload)
     cfg = registry.load_config(bench, work["config"], ROOT)
+    family = registry.config_family(cfg, ROOT)
+    reference = registry.load_reference(family.REFERENCE, ROOT)
     mix = registry.load_traffic(work["traffic"], ROOT)
     limits = registry.load_limits(args.workload, ROOT)
     run.check_devices(work["chips"])
     run.use_compile_cache(ROOT)
-    from reference import dense_lm
 
     for seed in args.seeds:
-        params = program.program_params(weights.make(cfg, seed), cfg)
-        eng = program.build_engine(cfg, params, mix["max_len"])
+        params = family.program_params(weights.make(cfg, seed, family), cfg)
+        eng = program.build_engine(cfg, params, mix["max_len"], family)
         del params
         cell = {"cfg": cfg, "mix": mix, "seed": seed}
         win = run.run_window(eng, cell, 0.0, trace=False)
         finished = [(p, s) for p, s, n in win["requests"] if s is not None and len(s) == n]
         del eng, win
         gc.collect()
-        rw = dense_lm.prepare(weights.make(cfg, seed), cfg)
+        rw = reference.prepare(weights.make(cfg, seed, family), cfg)
         items = [finished[i] for i in correctness.sample(finished, seed, limits["sample_tokens"])]
         row = {
             "seed": seed,
-            "program": correctness.widest_gap(rw, cfg, items, mix["max_len"]),
+            "program": correctness.widest_gap(reference, rw, cfg, items, mix["max_len"]),
             "control": correctness.widest_gap(
-                rw, cfg, items, mix["max_len"], cfg["precision"]["control"]
+                reference, rw, cfg, items, mix["max_len"], cfg["precision"]["control"]
             ),
         }
         del rw

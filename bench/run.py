@@ -5,8 +5,9 @@
 
 Order of work, all in this one process:
 
-  1. the cell, its configuration and its traffic mix are looked up by name
-     (BENCHMARK.json, bench/configs/, bench/traffic/);
+  1. the cell, its configuration, the configuration's family with its plain
+     reference, and its traffic mix are looked up by name (BENCHMARK.json,
+     bench/configs/, bench/families/, bench/reference/, bench/traffic/);
   2. JAX must find a TPU with as many chips as the cell asks for, or the run
      fails with no result;
   3. the persistent compilation cache is turned on at a fixed path in the
@@ -19,8 +20,8 @@ Order of work, all in this one process:
      counts. With ``--trace 1`` the window is traced by the profiler and the
      per-layer metrics are read from it;
   6. the device's peak memory is read, the program's state is freed, and a
-     seeded sample of the finished requests is compared with the plain
-     float32 reference (bench/benchlib/correctness.py). With ``--control 1``
+     seeded sample of the finished requests is compared with the family's
+     plain float32 reference (bench/benchlib/correctness.py). With ``--control 1``
      the reference computed one precision lower (``precision.control``) is
      compared in the program's place, at the same positions, through the
      same checks and limits, so that run has to read ``correct: false``;
@@ -150,6 +151,8 @@ def main(argv=None, *, root: str = ROOT, device_check=check_devices,
     bench = registry.load_benchmark(root)
     work = registry.find_workload(bench, args.workload)
     cfg = registry.load_config(bench, work["config"], root)
+    family = registry.config_family(cfg, root)
+    reference = registry.load_reference(family.REFERENCE, root)
     mix = registry.load_traffic(work["traffic"], root)
     limits = registry.load_limits(args.workload, root)
     wanted = registry.metrics_for(bench, args.workload, bool(args.trace))
@@ -172,8 +175,8 @@ def main(argv=None, *, root: str = ROOT, device_check=check_devices,
     cell = {"cfg": cfg, "mix": mix, "seed": args.seed}
 
     # -- set-up: weights, engine, warm-up ------------------------------------
-    params = program.program_params(weights.make(cfg, args.seed), cfg)
-    eng = program.build_engine(cfg, params, mix["max_len"])
+    params = family.program_params(weights.make(cfg, args.seed, family), cfg)
+    eng = program.build_engine(cfg, params, mix["max_len"], family)
     del params
     n_warm = program.warm_up(eng, mix, cfg, cfg["vocab_size"], args.seed)
     setup_s = time.perf_counter() - T_START
@@ -205,7 +208,7 @@ def main(argv=None, *, root: str = ROOT, device_check=check_devices,
         red = tracing.reduce(tracing.load(trace_dir))
         ctx = {
             "reduced": red, "reports": win["reports"], "waves": win["waves"],
-            "cfg": cfg, "mix": mix, "peaks": pk,
+            "cfg": cfg, "family": family, "mix": mix, "peaks": pk,
         }
         for m in wanted:
             value = registry.metric_reader(m["name"], root)(ctx)
@@ -231,12 +234,11 @@ def main(argv=None, *, root: str = ROOT, device_check=check_devices,
     # -- correctness: the reference once the program's state is freed -------
     del eng, win
     gc.collect()
-    from reference import dense_lm
-
-    rw = dense_lm.prepare(weights.make(cfg, args.seed), cfg)
+    rw = reference.prepare(weights.make(cfg, args.seed, family), cfg)
     picked = correctness.sample(finished, args.seed, limits["sample_tokens"])
     rounding = cfg["precision"]["control"] if args.control else None
-    gap = correctness.widest_gap(rw, cfg, [finished[i] for i in picked], mix["max_len"], rounding)
+    gap = correctness.widest_gap(reference, rw, cfg, [finished[i] for i in picked], mix["max_len"],
+                                 rounding)
     failed = len(reqs) - len(finished)
     checks = {
         "logit_gap": {"value": gap["widest"], "limit": limits["logit_gap"]},

@@ -24,7 +24,7 @@ for p in (BENCH, os.path.join(ROOT, "src")):
         sys.path.insert(0, p)
 
 import run  # noqa: E402
-from benchlib import correctness, costs, weights  # noqa: E402
+from benchlib import correctness, costs, registry, weights  # noqa: E402
 from reference import dense_lm  # noqa: E402
 
 CELL = "tiny.burst"
@@ -137,7 +137,7 @@ def test_control_run_is_not_correct(checkout, monkeypatch, capsys):
 def test_control_fails_the_limit_sound_runs_pass(checkout):
     with open(os.path.join(checkout, "bench/configs/tiny.json")) as f:
         cfg = json.load(f)
-    rw = dense_lm.prepare(weights.make(cfg, 3), cfg)
+    rw = dense_lm.prepare(weights.make(cfg, 3, registry.load_family("dense")), cfg)
     gen = np.random.default_rng(0)
     items = []
     for _ in range(3):
@@ -146,6 +146,6 @@ def test_control_fails_the_limit_sound_runs_pass(checkout):
         for _ in range(12):  # greedy under the reference itself: gap 0
             seq.append(int(np.argmax(np.asarray(dense_lm.logits(rw, cfg, np.asarray(seq)))[-1])))
         items.append((prompt, np.asarray(seq[8:])))
-    exact = correctness.widest_gap(rw, cfg, items, 24)
-    control = correctness.widest_gap(rw, cfg, items, 24, cfg["precision"]["control"])
+    exact = correctness.widest_gap(dense_lm, rw, cfg, items, 24)
+    control = correctness.widest_gap(dense_lm, rw, cfg, items, 24, cfg["precision"]["control"])
     assert exact["widest"] <= LIMIT < control["widest"]

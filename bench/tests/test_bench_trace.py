@@ -121,8 +121,9 @@ def test_breakdown_lists_top_ops_and_labelled_gaps(reduced):
 def test_model_flops_count_prompt_and_decode():
     cfg = {"hidden_size": 8, "intermediate_size": 16, "num_hidden_layers": 2, "head_dim": 4,
            "num_attention_heads": 2, "num_key_value_heads": 1, "vocab_size": 32}
+    dense = registry.load_family("dense")
     per_layer = 8 * 8 + 2 * 8 * 4 + 8 * 8 + 3 * 8 * 16
-    assert costs.matmul_params(cfg) == 2 * per_layer + 8 * 32
+    assert dense.matmul_params(cfg) == 2 * per_layer + 8 * 32
     # prompt of 3 (contexts 1, 2, 3) and 2 outputs (one decode, context 4)
-    want = sum(2 * costs.matmul_params(cfg) + 4 * 2 * 2 * 4 * c for c in (1, 2, 3, 4))
-    assert costs.request_flops(cfg, 3, 2) == want
+    want = sum(2 * dense.matmul_params(cfg) + 4 * 2 * 2 * 4 * c for c in (1, 2, 3, 4))
+    assert costs.request_flops(cfg, 3, 2, dense) == want
