@@ -50,11 +50,13 @@ LONG_CONTEXT_ARCHS = {"rwkv6-3b", "mixtral-8x22b", "jamba-1.5-large-398b"}
 # the registry; `domain_of` classifies a flattened-pytree leaf key into one.
 # Substrings are matched in order, so e.g. "['blocks']['p0']['attn']['wq']"
 # lands in "attention" before the "mlp" patterns are consulted.
-MEMORY_DOMAINS: tuple[str, ...] = ("embedding", "attention", "mlp", "kv")
+MEMORY_DOMAINS: tuple[str, ...] = ("embedding", "attention", "mlp", "kv", "ssm")
 
 _DOMAIN_PATTERNS: tuple[tuple[str, tuple[str, ...]], ...] = (
     ("kv", ("kv", "cache")),
     ("embedding", ("embed", "unembed", "vocab")),
+    # Mamba-2 projections and recurrent state (core/statestore.py)
+    ("ssm", ("mamba2", "ssm")),
     ("attention", ("attn", "attention", "w_r", "w_k", "w_v", "w_g", "w_o")),
     ("mlp", ("mlp", "ffn", "moe", "expert", "in_proj", "out_proj")),
 )
@@ -119,19 +121,28 @@ def rail_policy(name: str) -> str:
     return name
 
 
-def supports_paged_kv(cfg: ModelConfig) -> bool:
-    """Whether the paged SECDED KV cache (core/kvpages.py) covers this arch.
-
-    Paging fixed-size token pages assumes every mixer is full-context
-    attention with a position-indexed cache: SSM/RWKV state is not paged
-    (it is O(1) per lane, not per token), SWA ring buffers and quantized
-    caches keep their own layouts, and codebook decoders interleave tokens.
-    """
-    all_attn = all(
-        cfg.layer_kind(j)["mixer"] == "attn" for j in range(cfg.period)
+def has_state_layers(cfg: ModelConfig) -> bool:
+    """Whether some mixer keeps a per-lane recurrent state in the SECDED
+    state store (core/statestore.py) instead of pages."""
+    return any(
+        cfg.layer_kind(j)["mixer"] == "mamba2" for j in range(cfg.period)
     )
+
+
+def supports_paged_kv(cfg: ModelConfig) -> bool:
+    """Whether the protected serve() path covers this arch.
+
+    Paging fixed-size token pages assumes every attention mixer is
+    full-context with a position-indexed cache; the only other mixer
+    admitted is Mamba-2, whose O(1)-per-lane state lives in the SECDED state
+    store beside the pages. Mamba-1 and RWKV state has no protected store,
+    SWA ring buffers and quantized caches keep their own layouts, and
+    codebook decoders interleave tokens.
+    """
+    mixers = {cfg.layer_kind(j)["mixer"] for j in range(cfg.period)}
     return (
-        all_attn
+        "attn" in mixers
+        and mixers <= {"attn", "mamba2"}
         and not cfg.sliding_window
         and not cfg.kv_quant
         and not cfg.n_codebooks

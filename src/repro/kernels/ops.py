@@ -169,7 +169,12 @@ def inject_scrub_domains(
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class EccWeight:
-    """SECDED-encoded int8 weight matrix (K, N) as word planes (K/8, N)."""
+    """SECDED-encoded int8 weight matrix (K, N) as word planes (K/8, N').
+
+    N' is N, or N padded with zero columns (valid codewords of zero weights)
+    to the fused kernel's padded width when N spans more than one N block
+    and is not a multiple of it (``planes_width``): the padding is paid
+    once here, not by a copy of the planes in every call."""
 
     lo: Any  # (K/8, N) uint32
     hi: Any  # (K/8, N) uint32
@@ -201,6 +206,13 @@ def _pack_planes(qw):
     return lo, hi, ecc.encode(lo, hi)
 
 
+def planes_width(n: int, block_n: int = 256) -> int:
+    """Stored plane width of an N-column weight: N padded to the fused
+    kernel's N block where N is wider than one block and not a multiple of
+    it (narrower matrices keep N: their per-call pad is one small block)."""
+    return _round_up(n, block_n) if n > block_n and n % block_n else n
+
+
 def pack_ecc_weights(w: jnp.ndarray, axis_scale: int | None = 1, fuse: bool = True) -> EccWeight:
     """Quantize a float (K, N) weight to int8 and SECDED-encode it."""
     from repro.core import quantize as q
@@ -209,6 +221,9 @@ def pack_ecc_weights(w: jnp.ndarray, axis_scale: int | None = 1, fuse: bool = Tr
     assert k % 8 == 0, f"K={k} must be a multiple of 8 (64-bit codewords)"
     qw, scale = q.quantize(w, axis=axis_scale)
     lo, hi, parity = _pack_planes(qw)
+    pad = planes_width(n) - n
+    if pad:
+        lo, hi, parity = (jnp.pad(a, ((0, 0), (0, pad))) for a in (lo, hi, parity))
     return EccWeight(
         lo, hi, parity,
         scale.reshape(-1) if axis_scale is not None else scale, k, n, fuse,
@@ -261,16 +276,15 @@ def ecc_matmul(
         m, n = x2.shape[0], w.n
         blk, mp, np_ = matmul_tiling(m, w.k, n, block)
         xp = jnp.pad(xp, ((0, mp - m), (0, 0)))
-        pad_n = ((0, 0), (0, np_ - n))
+        planes = (w.lo, w.hi, w.parity)
+        pad_n = np_ - w.lo.shape[-1]
+        if pad_n:
+            planes = tuple(jnp.pad(a, ((0, 0), (0, pad_n))) for a in planes)
         _count_launch()
-        out = _mm.ecc_matmul_2d(
-            xp,
-            jnp.pad(w.lo, pad_n), jnp.pad(w.hi, pad_n), jnp.pad(w.parity, pad_n),
-            block=blk, interpret=interpret,
-        )[:m, :n]
+        out = _mm.ecc_matmul_2d(xp, *planes, block=blk, interpret=interpret)[:m, :n]
     else:
         lo, hi, _ = decode(w.lo, w.hi, w.parity, interpret=interpret)
-        w_i8 = _ref.unpack_ecc_weights(lo, hi)  # materialised (K, N) int8
+        w_i8 = _ref.unpack_ecc_weights(lo, hi)[:, : w.n]  # materialised (K, N) int8
         out = jnp.dot(x2.astype(jnp.float32), w_i8.astype(jnp.float32))
     out = out * w.scale
     return out.reshape(*lead, w.n)

@@ -54,6 +54,10 @@ class ModelConfig:
     d_conv: int = 4
     ssm_expand: int = 2
     rwkv_head_dim: int = 64
+    # hybrid SSM layers: "mamba" (Mamba-1, Jamba) | "mamba2" (SSD, Granite-4.0-H)
+    ssm_mixer: str = "mamba"
+    ssm_head_dim: int = 64  # Mamba-2: d_inner // ssm_head_dim heads, one B/C group
+    ssm_chunk: int = 256  # Mamba-2 chunked-SSD prefill chunk
     # vlm
     cross_attn_every: int = 0  # one cross-attn layer per this many layers
     n_img_tokens: int = 0
@@ -68,6 +72,13 @@ class ModelConfig:
     scan_unroll: bool = False
     flash_chunk: int = 1024  # q/kv chunk for flash-style attention
     kv_quant: bool = False  # int8 KV cache (+per-token scales) for decode
+    # Granite-4.0 scalars; each default is a no-op that adds no operation
+    rope: bool = True  # False: attention without position encoding (NoPE)
+    attn_scale: float = 0.0  # attention score scale; 0 -> 1/sqrt(head_dim)
+    norm_eps: float = 1e-6  # RMSNorm epsilon
+    embed_mult: float = 1.0  # embeddings times this
+    residual_mult: float = 1.0  # each residual add is x + residual_mult * f(x)
+    logits_div: float = 1.0  # logits divided by this
 
     @property
     def unroll(self):
@@ -100,8 +111,8 @@ class ModelConfig:
     def layer_kind(self, pos: int) -> dict:
         """Describe period position `pos`: mixer type + ffn type."""
         if self.family == "hybrid":
-            mixer = "attn" if pos == self.attn_every // 2 else "mamba"
-            ffn = "moe" if (pos % 2 == 1) else "mlp"
+            mixer = "attn" if pos == self.attn_every // 2 else self.ssm_mixer
+            ffn = "moe" if (self.n_experts and pos % 2 == 1) else "mlp"
         elif self.family == "vlm":
             mixer = "cross" if pos == self.period - 1 else "attn"
             ffn = "mlp"
@@ -143,6 +154,12 @@ def _layer_params(cfg: ModelConfig, kind: dict) -> tuple[int, int]:
     elif kind["mixer"] == "mamba":
         di, ds = cfg.d_inner, cfg.d_state
         m = d * 2 * di + di * cfg.d_conv + di * (2 * ds + math.ceil(d / 16)) + di * d + di
+        t += m
+        a += m
+    elif kind["mixer"] == "mamba2":
+        di, ds, k = cfg.d_inner, cfg.d_state, cfg.d_conv
+        h, conv = di // cfg.ssm_head_dim, di + 2 * ds
+        m = d * (di + conv + h) + conv * (k + 1) + 3 * h + di + di * d
         t += m
         a += m
     elif kind["mixer"] == "rwkv":

@@ -52,10 +52,11 @@ def layer_norm(x, gamma, beta, eps=1e-5):
     return ((x32 - mu) * jax.lax.rsqrt(var + eps)).astype(dt) * gamma + beta
 
 
-def apply_norm(x, p, norm_type):
+def apply_norm(x, p, norm_type, eps=1e-6):
+    """``eps`` is the RMSNorm epsilon; LayerNorm keeps its own 1e-5."""
     if norm_type == "layernorm":
         return layer_norm(x, p["gamma"], p["beta"])
-    return rms_norm(x, p["gamma"])
+    return rms_norm(x, p["gamma"], eps)
 
 
 # ---------------------------------------------------------------------------

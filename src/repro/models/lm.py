@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models import base, layers, mamba, moe, rwkv6
+from repro.models import base, layers, mamba, mamba2, moe, rwkv6
 from repro.models.base import ModelConfig, Spec
 
 REMAT_POLICIES = {
@@ -111,6 +111,21 @@ def _mamba_spec(cfg):
     }
 
 
+def _mamba2_spec(cfg):
+    d, di, k = cfg.d_model, cfg.d_inner, cfg.d_conv
+    h, _, _, conv = mamba2.dims(cfg)
+    return {
+        "in_proj": Spec((d, di + conv + h), ("embed", "ffn")),
+        "conv_w": Spec((conv, k), ("ffn", None), scale=0.5),
+        "conv_b": Spec((conv,), ("ffn",), "zeros"),
+        "dt_bias": Spec((h,), (None,), "zeros"),
+        "a_log": Spec((h,), (None,), "zeros"),
+        "d_skip": Spec((h,), (None,), "ones"),
+        "norm": Spec((di,), ("ffn",), "ones"),
+        "out_proj": Spec((di, d), ("ffn", "embed")),
+    }
+
+
 def _rwkv_tm_spec(cfg):
     d = cfg.d_model
     rh = f"heads:{d // cfg.rwkv_head_dim}"
@@ -155,6 +170,8 @@ def _layer_spec(cfg, pos):
             p["gate_ffn"] = Spec((1,), (None,), "zeros")
     elif kind["mixer"] == "mamba":
         p["mamba"] = _mamba_spec(cfg)
+    elif kind["mixer"] == "mamba2":
+        p["mamba2"] = _mamba2_spec(cfg)
     elif kind["mixer"] == "rwkv":
         p["tm"] = _rwkv_tm_spec(cfg)
     if kind["ffn"] == "moe":
@@ -241,6 +258,10 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, img_tokens: int = 0):
         elif kind["mixer"] == "mamba":
             c["conv"] = jnp.zeros((g, batch, cfg.d_conv - 1, cfg.d_inner), dt)
             c["ssm"] = jnp.zeros((g, batch, cfg.d_inner, cfg.d_state), dt)
+        elif kind["mixer"] == "mamba2":  # float32 state (core/statestore seals it)
+            h, p_, n, conv = mamba2.dims(cfg)
+            c["conv"] = jnp.zeros((g, batch, cfg.d_conv - 1, conv), jnp.float32)
+            c["ssm"] = jnp.zeros((g, batch, h, p_, n), jnp.float32)
         elif kind["mixer"] == "rwkv":
             n = cfg.rwkv_head_dim
             c["shift_tm"] = jnp.zeros((g, batch, cfg.d_model), dt)
@@ -272,7 +293,7 @@ def _dequant_kv(kq, vq, scale, dtype):
 
 def _attn_block(x, p, cfg, *, mode, cache, pos, img=None, cross=False):
     b, s, _ = x.shape
-    h = layers.apply_norm(x, p["ln1"], cfg.norm_type)
+    h = layers.apply_norm(x, p["ln1"], cfg.norm_type, cfg.norm_eps)
     if cross:
         q, _, _ = layers.qkv_proj(h, p["attn"], cfg)
         new_cache = cache
@@ -302,7 +323,7 @@ def _attn_block(x, p, cfg, *, mode, cache, pos, img=None, cross=False):
             out = layers.full_attention(q, k, v, causal=False)
         out = layers.out_proj(out, p["attn"]) * jnp.tanh(p["gate_attn"])
         x = x + out
-        h2 = layers.apply_norm(x, p["ln2"], cfg.norm_type)
+        h2 = layers.apply_norm(x, p["ln2"], cfg.norm_type, cfg.norm_eps)
         x = x + layers.mlp(h2, p["mlp"], cfg) * jnp.tanh(p["gate_ffn"])
         return x, new_cache, 0.0
 
@@ -322,8 +343,11 @@ def _attn_block(x, p, cfg, *, mode, cache, pos, img=None, cross=False):
         positions = base[:, None] + jnp.arange(s)[None, :]
     else:
         positions = jnp.arange(s)[None, :]
-    q = layers.apply_rope(q, positions, cfg.rope_theta)
-    k = layers.apply_rope(k, positions, cfg.rope_theta)
+    if cfg.rope:
+        q = layers.apply_rope(q, positions, cfg.rope_theta)
+        k = layers.apply_rope(k, positions, cfg.rope_theta)
+    if cfg.attn_scale:  # the attention bodies divide scores by sqrt(head_dim)
+        q = (q * (cfg.attn_scale * np.sqrt(cfg.hd))).astype(q.dtype)
 
     new_cache = cache
     w = cfg.sliding_window
@@ -398,18 +422,18 @@ def _attn_block(x, p, cfg, *, mode, cache, pos, img=None, cross=False):
             )
         else:
             out = layers.full_attention(q, k, v, causal=True, window=w)
-    x = x + layers.out_proj(out, p["attn"])
+    x = x + _residual(layers.out_proj(out, p["attn"]), cfg)
 
-    h2 = layers.apply_norm(x, p["ln2"], cfg.norm_type)
+    h2 = layers.apply_norm(x, p["ln2"], cfg.norm_type, cfg.norm_eps)
     if "moe" in p:
         y, aux = moe.moe_ffn(h2, p["moe"], cfg)
     else:
         y, aux = layers.mlp(h2, p["mlp"], cfg), 0.0
-    return x + y, new_cache, aux
+    return x + _residual(y, cfg), new_cache, aux
 
 
 def _mamba_block(x, p, cfg, *, mode, cache):
-    h = layers.apply_norm(x, p["ln1"], cfg.norm_type)
+    h = layers.apply_norm(x, p["ln1"], cfg.norm_type, cfg.norm_eps)
     state = None
     if mode == "decode":
         state = {"conv": cache["conv"], "ssm": cache["ssm"]}
@@ -419,7 +443,7 @@ def _mamba_block(x, p, cfg, *, mode, cache):
     if mode in ("decode", "prefill"):
         new_cache = {"conv": new_state["conv"].astype(cache["conv"].dtype),
                      "ssm": new_state["ssm"].astype(cache["ssm"].dtype)}
-    h2 = layers.apply_norm(x, p["ln2"], cfg.norm_type)
+    h2 = layers.apply_norm(x, p["ln2"], cfg.norm_type, cfg.norm_eps)
     if "moe" in p:
         y, aux = moe.moe_ffn(h2, p["moe"], cfg)
     else:
@@ -427,14 +451,37 @@ def _mamba_block(x, p, cfg, *, mode, cache):
     return x + y, new_cache, aux
 
 
+def _residual(y, cfg):
+    """A branch's output as added to the residual stream."""
+    return y if cfg.residual_mult == 1.0 else y * cfg.residual_mult
+
+
+def _mamba2_block(x, p, cfg, *, mode, cache):
+    h = layers.apply_norm(x, p["ln1"], cfg.norm_type, cfg.norm_eps)
+    new_cache = cache
+    if mode == "decode" and "ssm_lo" in cache:  # a protected lane slot
+        y, new_cache = mamba2.step_protected(h, p["mamba2"], cfg, cache)
+    elif mode == "decode":
+        y, new_cache = mamba2.step(h, p["mamba2"], cfg, cache)
+    elif mode in ("train", "prefill"):
+        y, state = mamba2.forward(h, p["mamba2"], cfg)
+        if mode == "prefill":
+            new_cache = state
+    else:
+        raise NotImplementedError(f"mamba2 layers have no {mode!r} mode")
+    x = x + _residual(y, cfg)
+    h2 = layers.apply_norm(x, p["ln2"], cfg.norm_type, cfg.norm_eps)
+    return x + _residual(layers.mlp(h2, p["mlp"], cfg), cfg), new_cache, 0.0
+
+
 def _rwkv_block(x, p, cfg, *, mode, cache):
-    h = layers.apply_norm(x, p["ln1"], cfg.norm_type)
+    h = layers.apply_norm(x, p["ln1"], cfg.norm_type, cfg.norm_eps)
     st = None
     if mode == "decode":
         st = {"shift": cache["shift_tm"], "wkv": cache["wkv"]}
     y, tm_state = rwkv6.time_mix(h, p["tm"], cfg, st)
     x = x + y
-    h2 = layers.apply_norm(x, p["ln2"], cfg.norm_type)
+    h2 = layers.apply_norm(x, p["ln2"], cfg.norm_type, cfg.norm_eps)
     st2 = {"shift": cache["shift_cm"]} if mode == "decode" else None
     y2, cm_state = rwkv6.channel_mix(h2, p["cm"], cfg, st2)
     x = x + y2
@@ -448,12 +495,36 @@ def _rwkv_block(x, p, cfg, *, mode, cache):
     return x, new_cache, 0.0
 
 
+def _mamba2_run(x, ps, cfg, *, mode):
+    """Consecutive Mamba-2 layers (train / prefill) as one scan over their
+    stacked weights, so that the layer is compiled once. Returns (x, the
+    layers' new caches)."""
+    stacked = jax.tree.map(lambda *a: jnp.stack(a), *ps)
+
+    def layer(h, p):
+        h, nc, _ = _mamba2_block(h, p, cfg, mode=mode, cache={})
+        return h, nc
+
+    x, ncs = jax.lax.scan(layer, x, stacked)
+    return x, [jax.tree.map(lambda a: a[i], ncs) for i in range(len(ps))]
+
+
 def _group_body(x, pgroup, cfg, *, mode, cache_group, pos, img):
     """One scan step: run the `period` layers of a group."""
     aux_total = 0.0
     new_cache = {}
-    for j in range(cfg.period):
+    j = 0
+    while j < cfg.period:
         kind = cfg.layer_kind(j)
+        run = 1
+        if kind["mixer"] == "mamba2" and mode in ("train", "prefill"):
+            while j + run < cfg.period and cfg.layer_kind(j + run)["mixer"] == "mamba2":
+                run += 1
+        if run > 1:
+            x, ncs = _mamba2_run(x, [pgroup[f"p{i}"] for i in range(j, j + run)], cfg, mode=mode)
+            new_cache.update({f"p{j + i}": nc for i, nc in enumerate(ncs)})
+            j += run
+            continue
         p = pgroup[f"p{j}"]
         c = cache_group.get(f"p{j}", {}) if cache_group is not None else {}
         if kind["mixer"] in ("attn", "cross"):
@@ -463,10 +534,13 @@ def _group_body(x, pgroup, cfg, *, mode, cache_group, pos, img):
             )
         elif kind["mixer"] == "mamba":
             x, nc, aux = _mamba_block(x, p, cfg, mode=mode, cache=c)
+        elif kind["mixer"] == "mamba2":
+            x, nc, aux = _mamba2_block(x, p, cfg, mode=mode, cache=c)
         else:
             x, nc, aux = _rwkv_block(x, p, cfg, mode=mode, cache=c)
         new_cache[f"p{j}"] = nc
         aux_total = aux_total + aux
+        j += 1
     return x, new_cache, aux_total
 
 
@@ -485,7 +559,8 @@ def _embed(params, tokens, cfg):
         x = x + _sinusoid(s, cfg.d_model, x.dtype)
     else:
         x = jnp.take(params["embed"], tokens, axis=0)
-    return x.astype(cfg.compute_dtype)
+    x = x.astype(cfg.compute_dtype)
+    return x if cfg.embed_mult == 1.0 else x * cfg.embed_mult
 
 
 def _sinusoid(s, d, dtype, offset=0):
@@ -502,6 +577,10 @@ def _unembed_matrix(params, cfg):
         e = params["embed"]
         return e.swapaxes(-1, -2) if cfg.n_codebooks else e.T
     return params["lm_head"]
+
+
+def _scale_logits(logits, cfg):
+    return logits if cfg.logits_div == 1.0 else logits / cfg.logits_div
 
 
 def forward(params, tokens, cfg: ModelConfig, *, img=None, cache=None,
@@ -543,7 +622,7 @@ def forward(params, tokens, cfg: ModelConfig, *, img=None, cache=None,
             step, (x, zero), (params["blocks"], cache), unroll=cfg.unroll
         )
 
-    x = layers.apply_norm(x, params["final_norm"], cfg.norm_type)
+    x = layers.apply_norm(x, params["final_norm"], cfg.norm_type, cfg.norm_eps)
     return x, new_cache, aux
 
 
@@ -613,9 +692,9 @@ def sequence_logits(params, tokens, cfg: ModelConfig, *, img=None):
     assert not cfg.n_codebooks, "sequence_logits: single-codebook LMs only"
     hidden, _, _ = forward(params, tokens, cfg, img=img, mode="train")
     un = _unembed_matrix(params, cfg)
-    return jnp.einsum(
+    return _scale_logits(jnp.einsum(
         "bsd,dv->bsv", hidden.astype(jnp.float32), un.astype(jnp.float32)
-    )
+    ), cfg)
 
 
 def prefill(params, tokens, cfg: ModelConfig, cache, *, img=None):
@@ -629,7 +708,7 @@ def prefill(params, tokens, cfg: ModelConfig, cache, *, img=None):
         logits = jnp.einsum("bd,kdv->bkv", last.astype(jnp.float32), un.astype(jnp.float32))
     else:
         logits = jnp.einsum("bd,dv->bv", last.astype(jnp.float32), un.astype(jnp.float32))
-    return logits, new_cache
+    return _scale_logits(logits, cfg), new_cache
 
 
 def greedy_decode_loop(params, tok0, cfg: ModelConfig, cache, start_pos, n_steps: int,
@@ -670,7 +749,7 @@ def decode_step(params, tokens, cfg: ModelConfig, cache, pos, *, img=None):
         logits = jnp.einsum("bd,kdv->bkv", last.astype(jnp.float32), un.astype(jnp.float32))
     else:
         logits = jnp.einsum("bd,dv->bv", last.astype(jnp.float32), un.astype(jnp.float32))
-    return logits, new_cache
+    return _scale_logits(logits, cfg), new_cache
 
 
 def chunk_step(params, tokens, cfg: ModelConfig, cache, pos0):
@@ -689,7 +768,7 @@ def chunk_step(params, tokens, cfg: ModelConfig, cache, pos0):
     logits = jnp.einsum(
         "bd,dv->bv", last.astype(jnp.float32), un.astype(jnp.float32)
     )
-    return logits, new_cache
+    return _scale_logits(logits, cfg), new_cache
 
 
 def chunk_logits(params, tokens, cfg: ModelConfig, cache, pos0):
@@ -704,4 +783,4 @@ def chunk_logits(params, tokens, cfg: ModelConfig, cache, pos0):
     logits = jnp.einsum(
         "bsd,dv->bsv", hidden.astype(jnp.float32), un.astype(jnp.float32)
     )
-    return logits, new_cache
+    return _scale_logits(logits, cfg), new_cache

@@ -36,6 +36,7 @@ from repro.core import (
     voltage as vmod,
 )
 from repro.core.faultsim import FaultField
+from repro.core import statestore
 from repro.core.kvpages import PAGE_TOKENS, KVGeometry, KVPageArena
 from repro.core.memory import EccMemoryDomain
 from repro.core.planestore import PlaneStore, leaf_seed
@@ -418,9 +419,9 @@ def _decode_gather_table(ew: kops.EccWeight, codec: str = "secded72") -> jnp.nda
     if lo.ndim == 3:  # layer-stacked (G, K/8, N): unpack per group
         w_i8 = jnp.stack(
             [kref.unpack_ecc_weights(lo[g], hi[g]) for g in range(lo.shape[0])]
-        )
+        )[..., : ew.n]
         return w_i8.astype(jnp.float32) * ew.scale[:, None, :]
-    w_i8 = kref.unpack_ecc_weights(lo, hi)
+    w_i8 = kref.unpack_ecc_weights(lo, hi)[:, : ew.n]
     return w_i8.astype(jnp.float32) * ew.scale
 
 
@@ -442,6 +443,10 @@ def _pack_stacked(leaf) -> kops.EccWeight:
     )
 
 
+# memory domains whose matrices the inline layout reads through ecc_matmul
+_MATMUL_DOMAINS = ("attention", "mlp", "ssm")
+
+
 def protect_params_inline(
     params, cfg: ModelConfig, seed: int = 0, include_embed: bool = False
 ):
@@ -457,11 +462,13 @@ def protect_params_inline(
     out, fields = [], {}
     for path, leaf in flat:
         key = jax.tree_util.keystr(path)
-        wanted = "attn" in key or "mlp" in key or (include_embed and "embed" in key)
+        domain = shapes.domain_of(key, default="")
+        wanted = domain in _MATMUL_DOMAINS or (include_embed and domain == "embedding")
         if not hasattr(leaf, "ndim") or not wanted:
             out.append(leaf)
             continue
-        if leaf.ndim == 2 and leaf.shape[0] % 8 == 0 and min(leaf.shape) >= 64:
+        stacked = key.startswith("['blocks']")  # (G, ...) layer stacks
+        if leaf.ndim == 2 and not stacked and leaf.shape[0] % 8 == 0 and min(leaf.shape) >= 64:
             ew = kops.pack_ecc_weights(jnp.asarray(leaf, jnp.float32))
         elif leaf.ndim == 3 and leaf.shape[1] % 8 == 0 and min(leaf.shape[1:]) >= 64:
             ew = _pack_stacked(leaf)
@@ -898,6 +905,13 @@ class ServingEngine:
         assert shapes.supports_paged_kv(self.cfg), (
             f"{self.cfg.name}: paged KV unsupported (see shapes.supports_paged_kv)"
         )
+        has_state = shapes.has_state_layers(self.cfg)
+        if has_state and (share_prefix or int(speculative) >= 2 or self.mesh is not None):
+            raise ReliabilityConfigError(
+                f"{self.cfg.name}: recurrent state lives in the per-lane SECDED "
+                "state store (core/statestore.py), which serves one chip without "
+                "prefix sharing or speculative decoding"
+            )
         if int(speculative) >= 2:
             assert draft_params is not None and draft_cfg is not None, (
                 "speculative decode needs draft_params + draft_cfg"
@@ -942,7 +956,12 @@ class ServingEngine:
                 # protected under it — controller state and applied
                 # protection must never diverge (DESIGN.md §12).
                 kv_codec = rail.codec
-        with obs_profile.span("serve.arena", n_pages=n_pages):
+        state_words = (
+            sched.decode_rows(n_lanes) * statestore.words_per_lane(self.cfg)
+            if has_state
+            else 0
+        )
+        with obs_profile.span("serve.arena", n_pages=n_pages, state_words=state_words):
             arena = KVPageArena(
                 geom,
                 profile,
@@ -1001,10 +1020,17 @@ class ServingEngine:
         # domain now has real words (power weighting) and real counters.
         self.stats.accumulate(report.kv_stats)
         self.rail_stats.accumulate(DomainFaultStats({"kv": report.kv_stats}))
+        if has_state:
+            self.stats.accumulate(report.state_stats)
+            self.rail_stats.accumulate(DomainFaultStats({"ssm": report.state_stats}))
         if self.rel is not None and self.rel.mode == "inline":
             self._store.register_domain_words(
                 "kv", arena.n_words, codec=arena.codec_name
             )
+            if has_state:
+                self._store.register_domain_words(
+                    "ssm", state_words, codec=statestore.CODEC
+                )
         if self.rails is not None:
             self.rails["kv"] = arena.voltage
         self.kv_arena = arena

@@ -87,6 +87,8 @@ class RequestState:
     pages: list = dataclasses.field(default_factory=list)
     tokens: list = dataclasses.field(default_factory=list)  # generated so far
     stats: FaultStats = dataclasses.field(default_factory=FaultStats)
+    # state-store telemetry of the lane while it served this request
+    state_stats: FaultStats = dataclasses.field(default_factory=FaultStats)
     preemptions: int = 0
     shared_tokens: int = 0  # leading tokens served from trie-shared pages
     # flight-recorder bookkeeping (step-clock values; -1 = never/not traced)
@@ -138,6 +140,9 @@ class ServeReport:
     prefix_hit_tokens: int = 0  # prompt tokens served from shared pages
     spec_dispatches: int = 0  # speculative verify blocks executed
     spec_emitted: int = 0  # tokens emitted by speculative blocks
+    # aggregate SECDED state-store telemetry (`ssm` domain; models with
+    # recurrent state, core/statestore.py), one decode step a read
+    state_stats: FaultStats = dataclasses.field(default_factory=FaultStats)
 
 
 def normalize_requests(requests) -> list:
@@ -418,6 +423,16 @@ class ContinuousBatchingScheduler:
             self.finished[st.rid] = st
 
 
+def _block_steps(want: int, max_block: int, scrub_left: int | None) -> int:
+    """A decode block's steps: ``want`` cut to ``max_block`` and to the
+    steps left before the next scrub, bucketed down to a power of two (few
+    scan shapes)."""
+    k = max(1, min(want, max_block))
+    if scrub_left is not None:
+        k = max(1, min(k, scrub_left))
+    return 1 << (k.bit_length() - 1)
+
+
 def serve_stream(
     params,
     cfg,
@@ -499,13 +514,17 @@ def serve_stream(
     which must stay synchronous with the scrub that flushed the arena, so
     those streams auto-demote to the serialized path.
     """
+    import jax
     import jax.numpy as jnp
 
+    from repro.configs import shapes
+    from repro.core import statestore
     from repro.models import lm
     from repro.serving import steps as steps_mod
 
     geom = arena.geom
     requests = normalize_requests(requests)
+    has_state = shapes.has_state_layers(cfg)
     for r in requests:
         total = len(r.prompt) + r.max_new_tokens
         assert total <= max_len, (r.rid, total, max_len)
@@ -544,13 +563,16 @@ def serve_stream(
         assert helpers.get("spec_multistep") is not None, (
             "helpers were built without a draft config (spec_multistep)"
         )
-        import jax
-
         draft_prefill = jax.jit(steps_mod.make_prefill_step(draft_cfg))
         dcache = lm.init_cache(draft_cfg, n_rows, max_len)
     else:
         draft_prefill, dcache = None, None
     cache = init_cache_fn(n_rows)
+    state_stats = FaultStats()
+    if has_state:  # lane slots of the state store: SECDED planes only
+        cache = statestore.seal(cache, cfg)
+        state_words = statestore.words_per_lane(cfg)
+    k_cap = _block_steps(max_block, max_block, scrub_interval or None)  # largest block
     cur_tok = np.zeros(n_rows, np.int32)
     pos_v = np.zeros(n_rows, np.int32)
     steps = 0
@@ -761,11 +783,14 @@ def serve_stream(
         prompts' KV to pages and load each row into its lane."""
         nonlocal cache, dcache, prefix_hit_tokens
         m = len(grp)
+        pf_rows = steps_mod.program_rows(cfg, m, n_rows)
         with obs_profile.span(
             "serve.prefill_group", m=m, prompt_len=s0, shared_tokens=sh
         ):
-            cachem = init_cache_fn(m)
+            cachem = init_cache_fn(pf_rows)
             seqs = np.stack([seq for _, _, seq in grp])
+            if pf_rows > m:
+                seqs = np.concatenate([seqs, np.repeat(seqs[-1:], pf_rows - m, axis=0)])
             if sh:
                 # Prefix hit: refresh the shared pages' payload into the
                 # batch cache (scrub-on-read — each *unique* page once, its
@@ -814,11 +839,12 @@ def serve_stream(
                     [st.pages[t // geom.page_tokens] for t in tok_idx]
                     for _, st, _ in grp
                 ]
+                + [[arena.scratch_page] * len(tok_idx)] * (pf_rows - m)
             )
             arena.commit_tokens(
-                payload_sfx.reshape(m * len(tok_idx), -1),
+                payload_sfx.reshape(page_ids.size, -1),
                 page_ids.reshape(-1),
-                np.tile(tok_idx % geom.page_tokens, m),
+                np.tile(tok_idx % geom.page_tokens, len(page_ids)),
             )
             if trie is not None:
                 # register the prompts' complete pages (partial tail pages
@@ -828,6 +854,13 @@ def serve_stream(
             if draft_prefill is not None:
                 dcachem = lm.init_cache(draft_cfg, m, max_len)
                 _, dcachem = draft_prefill(draft_params, jnp.asarray(seqs), dcachem)
+            if has_state:
+                # the prefill's final state, encoded into the admitted lanes'
+                # slots (a slot's previous request is overwritten here)
+                lanes = np.full(pf_rows, n_rows, np.int32)  # padding rows: dropped
+                lanes[:m] = [lane for lane, _, _ in grp]
+                with obs_profile.span("state.commit", lanes=m, words=m * state_words):
+                    cache = helpers["commit_state"](cache, cachem, jnp.asarray(lanes))
             with obs_profile.span("serve.prefill_sync"):
                 tok_host = np.asarray(tokm).reshape(-1)
             for row, (lane, st, _) in enumerate(grp):
@@ -852,11 +885,11 @@ def serve_stream(
         with obs_profile.span("serve.decode_block") as block:
             # -- block size: no lane's budget, and no scrub deadline, overrun -
             running = sched.running
-            k = min(st.req.max_new_tokens - len(st.tokens) for st in running)
-            k = max(1, min(k, max_block))
-            if scrub_interval:
-                k = max(1, min(k, scrub_interval - since_scrub))
-            k = 1 << (k.bit_length() - 1)  # power-of-two bucket: few scan shapes
+            k = _block_steps(
+                min(st.req.max_new_tokens - len(st.tokens) for st in running),
+                max_block,
+                scrub_interval - since_scrub if scrub_interval else None,
+            )
 
             # -- page growth for the whole block; preempt on pressure -------
             with obs_profile.span("serve.page_growth") as growth:
@@ -872,8 +905,9 @@ def serve_stream(
                 return False
 
             # -- k decode steps + per-token page commits in one dispatch ----
-            page_ids = np.full((k, n_rows), arena.scratch_page, np.int32)
-            slots = np.zeros((k, n_rows), np.int32)
+            rows_k = steps_mod.program_rows(cfg, k, k_cap)
+            page_ids = np.full((rows_k, n_rows), arena.scratch_page, np.int32)
+            slots = np.zeros((rows_k, n_rows), np.int32)
             for i in active:
                 st = sched.lanes[i]
                 for j in range(k):
@@ -932,7 +966,12 @@ def serve_stream(
                         sched.retire(st)
                 since_scrub += adv
             else:
-                toks, cache, arena.lo, arena.hi, arena.parity = helpers["multistep"](
+                state_args = ()  # the live lanes and the block's size
+                if has_state:
+                    live_v = np.zeros(n_rows, np.int32)
+                    live_v[active] = 1
+                    state_args = (jnp.asarray(live_v), jnp.int32(k))
+                toks, cache, arena.lo, arena.hi, arena.parity, *counts = helpers["multistep"](
                     params,
                     jnp.asarray(cur_tok[:, None]),
                     cache,
@@ -942,9 +981,27 @@ def serve_stream(
                     jnp.asarray(pos_v),
                     jnp.asarray(page_ids),
                     jnp.asarray(slots),
+                    *state_args,
                 )
                 with obs_profile.span("serve.block_sync"):
-                    toks_host = np.asarray(toks)
+                    if has_state:
+                        toks_host, counts = jax.device_get((toks, counts))
+                        toks_host = toks_host[:k]
+                    else:
+                        toks_host = np.asarray(toks)
+                if has_state:
+                    block_stats = FaultStats()
+                    for i in active:
+                        rs = FaultStats.from_counters(
+                            np.pad(counts[0][i], (0, 5)), words=int(counts[0][i].sum())
+                        )
+                        sched.lanes[i].state_stats.accumulate(rs)
+                        block_stats.accumulate(rs)
+                    state_stats.accumulate(block_stats)
+                    block.set_metadata(
+                        state_corrected=block_stats.corrected,
+                        state_detected=block_stats.detected,
+                    )
                 steps += k
                 since_scrub += k
                 if rec:
@@ -1039,4 +1096,5 @@ def serve_stream(
         prefix_hit_tokens=prefix_hit_tokens,
         spec_dispatches=spec_dispatches,
         spec_emitted=spec_emitted,
+        state_stats=state_stats,
     )

@@ -23,6 +23,8 @@ from typing import Any, Callable, Optional, Protocol, runtime_checkable
 import jax
 import jax.numpy as jnp
 
+from repro.configs import shapes
+from repro.core import statestore
 from repro.core.kvpages import KVGeometry
 from repro.models import lm
 from repro.models.base import ModelConfig
@@ -108,23 +110,33 @@ def _refresh_cache(cache, payload, n_tok, *, geom: KVGeometry):
     return out
 
 
-def _load_lane(cache, cachem, src_row, lane):
-    """Copy row ``src_row`` of a prefilled batch-of-m cache into ``lane``."""
-    return jax.tree_util.tree_map(
-        lambda c, cm: jax.lax.dynamic_update_slice_in_dim(
-            c,
-            jax.lax.dynamic_slice_in_dim(cm.astype(c.dtype), src_row, 1, 1),
-            lane,
-            1,
-        ),
-        cache,
-        cachem,
+def _load_lane(cache, cachem, src_row, lane, *, skip=()):
+    """Copy row ``src_row`` of a prefilled batch-of-m cache into ``lane``.
+    Entries named in ``skip`` are left as they are: a state layer's lane
+    slot holds SECDED planes, which ``statestore.commit`` writes."""
+    copy = lambda c, cm: jax.lax.dynamic_update_slice_in_dim(
+        c, jax.lax.dynamic_slice_in_dim(cm.astype(c.dtype), src_row, 1, 1), lane, 1
     )
+    return {
+        k: cache[k] if k in skip else jax.tree_util.tree_map(copy, cache[k], cachem[k])
+        for k in sorted(cache)
+    }
+
+
+def program_rows(cfg: ModelConfig, rows: int, cap: int) -> int:
+    """Rows a prefill group of ``rows`` prompts, or a decode block of
+    ``rows`` steps, is compiled at. An all-attention model compiles each
+    size. A model with state layers, whose unrolled hybrid period makes
+    every program costly to compile, runs each size at ``cap``: a prefill
+    group at the decode rows (the last prompt repeated, its commits to the
+    scratch page), a decode block in the largest block's program
+    (``_multistep`` loops over the first ``rows``)."""
+    return cap if shapes.has_state_layers(cfg) else rows
 
 
 def _multistep(
-    params, tok, cache, lo, hi, par, pos0, page_ids, slots, *, cfg, geom,
-    codec="secded72",
+    params, tok, cache, lo, hi, par, pos0, page_ids, slots, live=None, n_steps=None,
+    *, cfg, geom, codec="secded72",
 ):
     """Decode ``k`` tokens per lane in one dispatch (multi-step scheduling).
 
@@ -133,10 +145,21 @@ def _multistep(
     steps — decode, extract the written token's KV, commit it to the page
     arena — into one scanned program. page_ids/slots: (k, L) per-step page
     targets (precomputed on host; inactive lanes point at the scratch page).
+    ``live`` (L,): the lanes holding a request, given where the model keeps
+    recurrent state in the SECDED state store (core/statestore.py). Such a
+    model compiles one program for every block size: page_ids/slots then
+    hold the largest block's rows, and a loop runs the first ``n_steps`` ()
+    of them (its unrolled hybrid period makes each program costly to
+    compile).
 
-    Returns (tokens (k, L), cache, lo, hi, par).
+    Returns (tokens (k, L), cache, lo, hi, par), and with ``live`` also the
+    block's (L, 3) clean / corrected / detected state-word counts; rows of
+    ``tokens`` past ``n_steps`` are zero.
     """
     from repro.core.kvpages import _commit_tokens
+
+    if live is not None:
+        cache = statestore.arm(cache, live, cfg)
 
     def body(carry, xs):
         tok, cache, lo, hi, par, pos = carry
@@ -152,10 +175,23 @@ def _multistep(
         )
         return (nxt, cache, lo, hi, par, pos + 1), nxt[:, 0]
 
-    (tok, cache, lo, hi, par, _), toks = jax.lax.scan(
-        body, (tok, cache, lo, hi, par, pos0), (page_ids, slots)
+    if live is None:
+        (tok, cache, lo, hi, par, _), toks = jax.lax.scan(
+            body, (tok, cache, lo, hi, par, pos0), (page_ids, slots)
+        )
+        return toks, cache, lo, hi, par
+
+    def step(i, carry):
+        state, toks = carry
+        state, nxt = body(state, (page_ids[i], slots[i]))
+        return state, toks.at[i].set(nxt)
+
+    (tok, cache, lo, hi, par, _), toks = jax.lax.fori_loop(
+        0, n_steps, step,
+        ((tok, cache, lo, hi, par, pos0), jnp.zeros(page_ids.shape, jnp.int32)),
     )
-    return toks, cache, lo, hi, par
+    cache, counts = statestore.harvest(cache, cfg)
+    return toks, cache, lo, hi, par, counts
 
 
 def _chunk_prefill(params, tokens, cache, pos0, *, cfg):
@@ -267,6 +303,8 @@ class PagedHelpers:
       load_lane(cache, cachem, src_row, lane)     -> cache
       refresh(cache, payload (L,T,F), n_tok (L,)) -> cache
       chunk(params, tokens (m,s), cachem, pos0)   -> (next_tok (m,), cachem)
+      commit_state(cache, cachem, lanes (m,))     -> cache (state models only:
+                the prefill's final state encoded into the lanes' slots)
       spec_multistep(params, dparams, tok, cache, dcache, lo, hi, par,
                 pos (L,), page_ids (k,L), slots, k=, scratch_page=)
                 -> (greedy (L,k), n_emit (L,), cache, dcache, planes)
@@ -286,6 +324,7 @@ class PagedHelpers:
     refresh: Callable
     chunk: Callable
     spec_multistep: Optional[Callable] = None
+    commit_state: Optional[Callable] = None
 
     def __getitem__(self, name: str) -> Callable:
         fn = getattr(self, name)
@@ -325,8 +364,13 @@ def make_paged_helpers(
                 cfg=cfg, dcfg=draft_cfg, geom=geom, codec=codec,
             )
         )
+    state = shapes.has_state_layers(cfg)
+    skip = tuple(f"p{j}" for j in statestore.positions(cfg))
     return PagedHelpers(
         codec=codec,
+        commit_state=(
+            _jit_named("commit_state", statestore.commit, cfg=cfg) if state else None
+        ),
         prefill=spanned("decode.prefill")(jax.jit(make_prefill_step(cfg))),
         multistep=spanned("decode.multistep")(
             _jit_named("multistep", _multistep, cfg=cfg, geom=geom, codec=codec)
@@ -340,7 +384,7 @@ def make_paged_helpers(
             static_argnames=("start", "stop"),
             geom=geom,
         ),
-        load_lane=jax.jit(_load_lane),
+        load_lane=_jit_named("_load_lane", _load_lane, skip=skip),
         refresh=_jit_named("refresh", _refresh_cache, geom=geom),
         chunk=spanned("decode.chunk_prefill")(
             _jit_named("chunk_prefill", _chunk_prefill, cfg=cfg)

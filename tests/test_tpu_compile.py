@@ -22,7 +22,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs.qwen3_0_6b import config as qwen3_config
 from repro.core.kvpages import KVGeometry, _gather_pages, _scatter_pages
-from repro.kernels import ecc_matmul, inject_scrub, ops, paged_gather, secded
+from repro.kernels import ecc_matmul, ecc_ssd, inject_scrub, ops, paged_gather, secded
 
 CFG = qwen3_config()
 D, HD = CFG.d_model, CFG.hd
@@ -145,3 +145,18 @@ def test_dected79_lut_decode_has_no_lowering(chip):
     p32, pc = ((ROWS, LANES), jnp.uint32), ((ROWS, LANES), jnp.uint32)
     with pytest.raises(NotImplementedError, match="gather"):
         _compile(secded.decode_2d, chip, p32, p32, pc, codec="dected79", block=(256, LANES))
+
+
+def test_ecc_ssd_step_compiles(chip):
+    """The protected Mamba-2 step at granite-4.0-h-micro's widths: 8 lanes
+    of 64 heads, each head a (32, 128) tile of state codewords."""
+    lanes, heads, half, n = 8, 64, 32, 128
+    rows = ecc_ssd._heads_per_step(heads, half) * half
+    r = lanes * heads * half
+    p32, p8, col = ((r, n), jnp.uint32), ((r, n), jnp.uint8), ((r, 1), jnp.float32)
+    vec = ((lanes, 1, n), jnp.float32)
+    hlo = _compile(
+        ecc_ssd.ecc_ssd_step_2d, chip, p32, p32, p8, col, col, col, ((r, 1), jnp.int32),
+        vec, vec, rows=rows,
+    )
+    assert re.search(r"%ecc_ssd_step_2d[.\d]* = ", hlo.as_text())
