@@ -7,10 +7,22 @@ tile loop, unpacked to int8, dequantised, and fed straight to the MXU — one
 HBM pass, zero extra weight traffic for ECC beyond the 12.5% parity plane.
 
 Weight packing (see ops.pack_ecc_weights): codeword i of column n holds the 8
-int8 weights W[j*K/8 + i, n], j=0..7 (j<4 in `lo`, j>=4 in `hi`). The matching
-activation permutation x_perm[:, 8i+j] = x[:, j*K/8 + i] is a free
-reshape-transpose applied once per call in ops.py; the dot product is
-permutation-invariant so outputs are bit-identical to the plain matmul.
+int8 weights W[j*K/8 + i, n], j=0..7 (j<4 in `lo`, j>=4 in `hi`). A K block of
+bk8 codewords is unpacked block-contiguously: byte j of the block's codeword i'
+becomes tile row j*bk8 + i', so the tile is eight sublane-aligned (bk8, bn)
+pieces stacked along rows and needs no sublane shuffle. The matching activation
+permutation, x_perm[:, kb*bk + j*bk8 + i'] = x[:, j*K/8 + kb*bk8 + i'] for K
+block kb, is a free reshape-transpose applied once per call in ops.py
+(``permute_k``, with the bk8 of ``matmul_tiling``); the dot product is
+permutation-invariant, so only the order of the f32 sums differs from the
+plain matmul.
+
+Decode: syndrome bit r is the parity of the codeword's bits under Hsiao row r
+with the stored check bit folded in, taken by one population count; data bit
+d flips iff its column equals the syndrome, evaluated bit-sliced as the AND
+over the 8 rows of (syndrome bit r XNOR bit r of every column). A clean word,
+a check-bit error and an even-weight (double-error) syndrome match no data
+column, so they flip nothing.
 
 Grid: (M/bm, N/bn, K/bk), k innermost, f32 accumulator in VMEM scratch.
 VMEM per step (bm=128, bk=512, bn=256): x 256K + planes 148K + w 512K
@@ -23,6 +35,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -31,49 +44,31 @@ from repro.core import hsiao
 _U32 = jnp.uint32
 
 
-def _parity32(v):
-    v = v ^ (v >> 16)
-    v = v ^ (v >> 8)
-    v = v ^ (v >> 4)
-    v = v ^ (v >> 2)
-    v = v ^ (v >> 1)
-    return v & _U32(1)
-
-
 def _decode_planes(lo, hi, stored_parity):
     """Syndrome + single-bit correction (no status plane — fused fast path)."""
-    synd = jnp.zeros_like(lo)
+    p = stored_parity.astype(_U32)
+    flip_lo = jnp.full_like(lo, 0xFFFFFFFF)
+    flip_hi = jnp.full_like(hi, 0xFFFFFFFF)
     for r in range(hsiao.N_PARITY):
         mlo = _U32(int(hsiao.MASK_LO[r]))
         mhi = _U32(int(hsiao.MASK_HI[r]))
-        synd = synd | (_parity32((lo & mlo) ^ (hi & mhi)) << r)
-    synd = synd ^ stored_parity.astype(_U32)
-
-    flip_lo = jnp.zeros_like(lo)
-    flip_hi = jnp.zeros_like(hi)
-    for d in range(hsiao.N_DATA):
-        col = _U32(int(hsiao.DATA_COLS[d]))
-        m = synd == col
-        if d < 32:
-            flip_lo = jnp.where(m, flip_lo | _U32(1 << d), flip_lo)
-        else:
-            flip_hi = jnp.where(m, flip_hi | _U32(1 << (d - 32)), flip_hi)
+        t = (lo & mlo) ^ (hi & mhi) ^ (p & _U32(1 << r))
+        s = _U32(0) - (lax.population_count(t) & _U32(1))  # all ones iff set
+        flip_lo = flip_lo & (s ^ ~mlo)
+        flip_hi = flip_hi & (s ^ ~mhi)
     return lo ^ flip_lo, hi ^ flip_hi
 
 
 def _unpack_int8(lo, hi, out_dtype):
-    """(bk8, bn) u32 planes -> (bk, bn) weights, rows interleaved 8i+j."""
-    planes = []
+    """(bk8, bn) u32 planes -> (8*bk8, bn) weights, byte j of codeword i on
+    row j*bk8 + i."""
+    pieces = []
     for word in (lo, hi):
+        w = lax.bitcast_convert_type(word, jnp.int32)
         for j in range(4):
-            b = (word >> _U32(8 * j)) & _U32(0xFF)
-            planes.append(b)
-    w = jnp.stack(planes, axis=1)  # (bk8, 8, bn); plane order j then lo/hi = byte j
-    # reorder: plane index p in [0,8) corresponds to byte j=p%4 of lo (p<4) / hi.
-    # byte j of lo = weight row offset j; of hi = offset 4+j -> already in order.
-    w = (w.astype(jnp.int32) ^ 128) - 128  # sign-extend int8 stored as raw byte
-    bk8, _, bn = w.shape
-    return w.reshape(bk8 * 8, bn).astype(out_dtype)
+            b = w if j == 3 else w << (24 - 8 * j)
+            pieces.append((b >> 24).astype(out_dtype))  # arithmetic: sign-extends
+    return jnp.concatenate(pieces, axis=0)
 
 
 def _matmul_kernel(x_ref, lo_ref, hi_ref, par_ref, out_ref, acc_ref):

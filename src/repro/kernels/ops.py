@@ -230,20 +230,21 @@ def pack_ecc_weights(w: jnp.ndarray, axis_scale: int | None = 1, fuse: bool = Tr
     )
 
 
-def permute_k(x: jnp.ndarray, k: int) -> jnp.ndarray:
-    """Activation permutation matching the codeword packing (free transpose)."""
-    k8 = k // 8
+def permute_k(x: jnp.ndarray, k: int, bk8: int) -> jnp.ndarray:
+    """Activation permutation matching the kernel's block-contiguous unpack
+    (a free transpose): within each K block of ``bk8`` codewords,
+    x_perm[kb*8*bk8 + j*bk8 + i] = x[j*k/8 + kb*bk8 + i]."""
     lead = x.shape[:-1]
     return (
-        x.reshape(*lead, 8, k8).swapaxes(-1, -2).reshape(*lead, k)
+        x.reshape(*lead, 8, k // (8 * bk8), bk8).swapaxes(-3, -2).reshape(*lead, k)
     )
 
 
 def matmul_tiling(m: int, k: int, n: int, block=(128, 512, 256)):
     """Kernel block and padded (M, N) of the fused matmul for an
     (m, k) x (k, n) product: ``((bm, bk, bn), mp, np)``."""
-    # bk8 must divide K/8 exactly: the 8i+j interleave mapping is global,
-    # so the K dimension cannot be padded after packing.
+    # bk8 must divide K/8 exactly: byte j of codeword i is weight row
+    # j*K/8 + i, so the K dimension cannot be padded after packing.
     k8 = k // 8
     bk8 = block[1] // 8
     while k8 % bk8:
@@ -272,10 +273,9 @@ def ecc_matmul(
     lead = x.shape[:-1]
     x2 = x.reshape(-1, w.k)
     if fuse:
-        xp = permute_k(x2, w.k)
         m, n = x2.shape[0], w.n
         blk, mp, np_ = matmul_tiling(m, w.k, n, block)
-        xp = jnp.pad(xp, ((0, mp - m), (0, 0)))
+        xp = jnp.pad(permute_k(x2, w.k, blk[1] // 8), ((0, mp - m), (0, 0)))
         planes = (w.lo, w.hi, w.parity)
         pad_n = np_ - w.lo.shape[-1]
         if pad_n:

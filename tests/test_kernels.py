@@ -8,7 +8,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import backend, ops, ref
+from repro.kernels import backend, ecc_matmul, ops, ref
 
 
 @pytest.mark.parametrize(
@@ -36,7 +36,15 @@ def test_encode_decode_inject_match_oracle(shape, rng):
         assert np.array_equal(np.asarray(a), np.asarray(b))
 
 
-@pytest.mark.parametrize("mkn", [(8, 64, 128), (33, 512, 256), (128, 1024, 130)])
+# After the first three: M 8 at K 256 and 384 (matmul_tiling's bk8 of 32 and
+# 16; K 64 takes 8), then at every K of the benchmark's projections
+# (qwen3-0.6b, qwen2-7b, granite-4.0-h-micro): bk8 64 over 2 to 37 K blocks,
+# so the block-contiguous activation permutation meets each.
+@pytest.mark.parametrize(
+    "mkn",
+    [(8, 64, 128), (33, 512, 256), (128, 1024, 130)]
+    + [(8, k, 128) for k in (256, 384, 1024, 2048, 3072, 3584, 8192, 18944)],
+)
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_ecc_matmul_fused_naive_oracle(mkn, dtype, rng):
     m, k, n = mkn
@@ -66,6 +74,55 @@ def test_fused_kernel_corrects_all_single_bit_faults(rng):
     np.testing.assert_array_equal(out, clean)
     status = np.asarray(ops.scrub(faulty))
     assert (status == 1).sum() == sel.sum()
+
+
+def _bit_masks(bits):
+    """Codeword bit indices (0-31 lo, 32-63 hi, 64-71 check) -> XOR masks."""
+    one = np.uint32(1)
+    mlo = np.where(bits < 32, one << np.clip(bits, 0, 31).astype(np.uint32), 0)
+    mhi = np.where(
+        (bits >= 32) & (bits < 64), one << np.clip(bits - 32, 0, 31).astype(np.uint32), 0
+    )
+    mpar = np.where(bits >= 64, one << np.clip(bits - 64, 0, 7).astype(np.uint32), 0)
+    return mlo.astype(np.uint32), mhi.astype(np.uint32), mpar.astype(np.uint8)
+
+
+def _random_codewords(rng, n):
+    lo = rng.integers(0, 2**32, n, dtype=np.uint32)
+    hi = rng.integers(0, 2**32, n, dtype=np.uint32)
+    return lo, hi, np.asarray(ref.encode_ref(jnp.asarray(lo), jnp.asarray(hi)))
+
+
+_tile_decode = jax.jit(ecc_matmul._decode_planes)
+
+
+def _assert_tile_decode(lo, hi, par, flips, want_lo, want_hi):
+    faulty = [a ^ m for a, m in zip((lo, hi, par), flips)]
+    got = _tile_decode(*(jnp.asarray(a) for a in faulty))
+    oracle = ref.decode_ref(*(jnp.asarray(a) for a in faulty))
+    for g, o, w in zip(got, oracle, (want_lo, want_hi)):
+        assert np.array_equal(np.asarray(g), np.asarray(o))
+        assert np.array_equal(np.asarray(g), w)
+
+
+@pytest.mark.parametrize("bit", range(72))
+def test_tile_decode_corrects_every_single_bit_flip(bit):
+    """The fused kernel's tile decode, run as plain jnp, restores the word
+    for a flip of any one of the 72 codeword bits, as the oracle does."""
+    lo, hi, par = _random_codewords(np.random.default_rng(bit), 512)
+    _assert_tile_decode(lo, hi, par, _bit_masks(np.full(512, bit)), lo, hi)
+
+
+@pytest.mark.parametrize("n_flips", [0, 2])
+def test_tile_decode_leaves_clean_and_double_flipped_words(n_flips):
+    """Clean words, and every one of the 2,556 double flips (one random
+    codeword each), decode to the data as read, as the oracle does."""
+    pairs = np.array([(a, b) for a in range(72) for b in range(a + 1, 72)])
+    lo, hi, par = _random_codewords(np.random.default_rng(72 + n_flips), len(pairs))
+    flips = [np.zeros_like(a) for a in (lo, hi, par)]
+    for col in pairs.T[:n_flips]:
+        flips = [f ^ m for f, m in zip(flips, _bit_masks(col))]
+    _assert_tile_decode(lo, hi, par, flips, lo ^ flips[0], hi ^ flips[1])
 
 
 @pytest.mark.parametrize("kn", [(64, 128), (1024, 130)])

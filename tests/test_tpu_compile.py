@@ -34,6 +34,10 @@ PROJECTIONS = [
     (D, CFG.d_ff),
     (CFG.d_ff, D),
 ]
+# (K, N) of the wider cells' projections: qwen2-7b's MLP up and down, and
+# granite-4.0-h-micro's Mamba-2 in_proj (N 8,512, padded to the N block) and
+# MLP down, so the tile body is lowered at K 18944 and at a padded N
+WIDE_PROJECTIONS = [(3584, 18944), (18944, 3584), (2048, 8512), (8192, 2048)]
 ROWS, LANES = 2048, ops.LANES  # one 1M-word arena block in the ops 2D layout
 
 
@@ -69,7 +73,9 @@ def _compile(fn, chip, *shapes, **static):
 
 
 @pytest.mark.parametrize("m", [8, 512], ids=["decode", "prefill"])
-@pytest.mark.parametrize("kn", PROJECTIONS, ids=lambda kn: f"{kn[0]}x{kn[1]}")
+@pytest.mark.parametrize(
+    "kn", PROJECTIONS + WIDE_PROJECTIONS, ids=lambda kn: f"{kn[0]}x{kn[1]}"
+)
 def test_ecc_matmul_compiles(chip, kn, m):
     k, n = kn
     block, mp, np_ = ops.matmul_tiling(m, k, n)
