@@ -73,7 +73,7 @@ def domain_of(key: str, default: str = "mlp") -> str:
 
 # Default ECC scheme per memory domain (DESIGN.md §12). The built-in BRAM
 # SECDED everywhere, matching the paper; engines override per domain via
-# ReliabilityConfig.codecs, and the controller escalation ladder may move a
+# ReliabilityConfig.protection.codecs, and the controller escalation ladder may move a
 # domain up at runtime.
 from repro.codes import DEFAULT_CODEC  # noqa: E402 (single source of truth)
 

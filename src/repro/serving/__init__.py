@@ -6,7 +6,7 @@ decode-block helper contract external factories implement. Submodules stay
 importable directly (``repro.serving.engine`` etc.) — this package re-exports
 the stable names so callers stop reaching into module internals:
 
-    from repro.serving import ServingEngine, ReliabilityConfig, ServeRequest
+    from repro.serving import ServingEngine, ReliabilityConfig, Request
 
 Import order matters here: ``engine`` imports ``scheduler``/``steps``, so the
 protocol layers load first (keeps the package safe to import from any entry
@@ -20,7 +20,6 @@ from repro.serving.scheduler import (
     Request,
     RequestState,
     ServeReport,
-    ServeRequest,
     normalize_requests,
     partition_requests,
     serve_stream,
@@ -59,7 +58,6 @@ __all__ = [
     "Request",
     "RequestState",
     "ServeReport",
-    "ServeRequest",
     "ServingEngine",
     "TraceRecorder",
     "make_paged_helpers",

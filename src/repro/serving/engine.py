@@ -1,19 +1,19 @@
 """Batched serving engine with ECC-protected weights under an undervolted rail.
 
-The paper's §IV evaluation as a service: model weights live in an
-`EccMemoryDomain` ("BRAM") at a configurable rail voltage; every voltage
-change re-materialises the faulty-but-corrected view of the weights through
-the SECDED read path; the DED-canary `UndervoltController` consumes scrub
-telemetry between generation rounds and walks the rail down until the first
-detected-uncorrectable event. Power comes from the calibrated Table-I model.
+The paper's §IV evaluation as a service. The served layout is
+``mode="inline"``: the attention, MLP and SSM weight matrices (and the
+embedding, on multi-rail engines) are replaced by int8 SECDED planes
+(`kops.EccWeight`) held in one plane arena (`core/planestore.PlaneStore`), and
+every forward pass reads them through the fused decode-matmul kernel
+(DESIGN.md §2). A voltage step is one fused inject+scrub launch over the
+arena; the DED-canary controllers consume its telemetry between rounds and
+walk each rail down until the first detected-uncorrectable event. The KV
+cache is served from SECDED pages (`serve`). Power comes from the calibrated
+Table-I model.
 
-Two protection layouts:
-  * mode="domain"  — any arch: raw weight bits stored in the domain, decoded
-    view refreshed per voltage (matches the paper's BRAM-resident weights);
-  * mode="inline"  — dense archs: big matrices replaced by int8 EccWeight
-    planes; every forward pass runs the (Pallas) decode-matmul read path,
-    faults injected into the planes XOR-style. This is the TPU-native fused
-    path (DESIGN.md §2) and the paper-representative dry-run/hillclimb cell.
+``mode="domain"`` (the config default) keeps the raw weight bits in an
+`EccMemoryDomain` ("BRAM") and refreshes a decoded view per voltage; no
+benchmark cell serves it.
 """
 
 from __future__ import annotations
@@ -35,11 +35,10 @@ from repro.core import (
     scenario,
     voltage as vmod,
 )
-from repro.core.faultsim import FaultField
 from repro.core import statestore
 from repro.core.kvpages import PAGE_TOKENS, KVGeometry, KVPageArena
 from repro.core.memory import EccMemoryDomain
-from repro.core.planestore import PlaneStore, leaf_seed
+from repro.core.planestore import PlaneStore
 from repro.core.telemetry import DomainFaultStats, FaultStats, ShardFaultStats
 from repro.distributed import meshrel
 from repro.kernels import backend as kbackend
@@ -63,16 +62,17 @@ class ReliabilityConfigError(ValueError, AssertionError):
 class FaultModelConfig:
     """How faults are generated and applied (DESIGN.md §7/§14)."""
 
-    # "host": NumPy FaultField oracle (bit-identical to the per-leaf path);
+    # "host": NumPy FaultField masks, one stream per leaf (planestore.leaf_seed);
     # "device": counter-based jax.random masks, never materialised on host
     mask_source: str = "host"
-    # inline mode: one fused inject+scrub launch over the whole-model plane
-    # arena (True) vs the historical per-leaf loop (False, reference path)
-    batched: bool = True
     # Environment scenario: None (historical i.i.d. stream, bit-for-bit), a
-    # name from scenario.ENVIRONMENTS, or an EnvironmentProfile.
+    # name from scenario.ENVIRONMENTS ("consumer" / "avionics" / "space"), or
+    # an EnvironmentProfile. Scales every domain's fault flux, shapes the
+    # masks into correlated multi-bit bursts, and drifts each mesh shard's
+    # rate over the soak.
     environment: Any = None
-    # Override the environment's aging-drift sigma (scenario.resolve).
+    # Override the environment's aging-drift sigma (scenario.resolve); a bare
+    # drift with environment=None gets the neutral 1x-flux burst-free env.
     drift: float | None = None
 
 
@@ -80,18 +80,22 @@ class FaultModelConfig:
 class RailsConfig:
     """Voltage-rail topology and controller tuning (DESIGN.md §10/§13)."""
 
-    # partition the plane arena into memory domains, each with its own
-    # closed-loop rail (implies the batched inline path)
+    # partition the plane arena into memory domains (configs/shapes.domain_of),
+    # each with its own closed-loop rail
     multi_rail: bool = False
     # mesh engines: "uniform" locks one schedule at the worst shard's first
     # DED; "per_shard" walks every chip to its own V_min
     policy: str = "uniform"
-    # >0: per-domain fault-curve variation (lognormal sigma)
+    # >0: per-domain fault-curve variation (lognormal sigma) modelling
+    # block-to-block differences (arXiv:2005.04737 / MoRS); 0: shared curve
     spread: float = 0.0
     step_v: float = 0.01
-    # warm-start voltage for the canary search (None -> v_nom)
+    # warm-start voltage for the canary search (None -> v_nom); the
+    # guardband [v_min, v_nom] is fault-free by definition, so starting at
+    # its edge saves ~40 no-op rounds without changing the lock point
     start_v: float | None = None
-    # locked rails re-trip under drift: retreat another backoff step
+    # locked rails re-trip under drift: retreat another backoff step instead
+    # of holding (core/controller.py `adaptive`)
     adaptive: bool = False
 
 
@@ -100,11 +104,16 @@ class ProtectionConfig:
     """What is protected and under which ECC schemes (DESIGN.md §12)."""
 
     # a registered codec name for every domain, or a {domain: name} mapping
+    # (unnamed domains keep the built-in secded72; the dict form needs
+    # multi_rail)
     codecs: Any = None
-    # EscalationPolicy or tuple of codec names weakest -> strongest
+    # DED-canary escalation ladder (multi-rail only): an EscalationPolicy or
+    # a tuple of codec names weakest -> strongest. On a DED trip a rail steps
+    # up its code instead of retreating; the redundancy cost lands in
+    # power_report.
     escalation: Any = None
-    protect: tuple = ("weights",)
-    # include the embedding table in the protected arena (None -> multi_rail)
+    # include the embedding table in the protected arena (None -> multi_rail:
+    # single-rail engines keep the attn/mlp-only protected set)
     embed: bool | None = None
 
 
@@ -112,201 +121,44 @@ class ProtectionConfig:
 class CanaryConfig:
     """DED/accuracy canary behavior (DESIGN.md §15)."""
 
-    # >0 reserves this many fixed canary prompts per autotune round
+    # >0 reserves this many fixed canary prompts; each autotune round
+    # greedy-decodes them against a cached clean-nominal reference rollout
+    # and feeds the divergence score (1 - mean matched-prefix fraction,
+    # [0, 1]) to the controller alongside the DED counters. Inline mode only.
     prompts: int = 0
-    # decoded continuation length per canary prompt
+    # decoded continuation length per canary prompt (prompt length is
+    # core/campaign.CANARY_PROMPT_LEN)
     tokens: int = 12
-    # canary divergence scores above this trip the rail even when the DED
-    # counters are clean; None records but never trips
+    # canary divergence scores above this trip the rail (escalate if a ladder
+    # step remains, else back off + lock) even when the DED counters are
+    # clean; None records but never trips
     divergence_slo: float | None = None
     # also treat SILENT (ground-truth-only) events as canary trips
     paranoid: bool = False
-
-
-# flat legacy field -> (sub-config attribute) per group; the flat names stay
-# constructible (deprecation shim) and always mirror the resolved sub-configs
-_REL_GROUPS: dict = {
-    "fault_model": (
-        FaultModelConfig,
-        {
-            "mask_source": "mask_source",
-            "batched": "batched",
-            "environment": "environment",
-            "drift": "drift",
-        },
-    ),
-    "rails": (
-        RailsConfig,
-        {
-            "multi_rail": "multi_rail",
-            "rail_policy": "policy",
-            "rail_spread": "spread",
-            "controller_step_v": "step_v",
-            "controller_start_v": "start_v",
-            "adaptive_rails": "adaptive",
-        },
-    ),
-    "protection": (
-        ProtectionConfig,
-        {
-            "codecs": "codecs",
-            "escalation": "escalation",
-            "protect": "protect",
-            "protect_embed": "embed",
-        },
-    ),
-    "canary": (
-        CanaryConfig,
-        {
-            "canary_prompts": "prompts",
-            "canary_tokens": "tokens",
-            "divergence_slo": "divergence_slo",
-            "paranoid": "paranoid",
-        },
-    ),
-}
-
-_FLAT_KWARG_WARNED = False
 
 
 @dataclasses.dataclass(frozen=True)
 class ReliabilityConfig:
     """Reliability knobs for a ServingEngine.
 
-    The canonical surface is the four grouped sub-configs —
-    ``fault_model`` (:class:`FaultModelConfig`), ``rails``
-    (:class:`RailsConfig`), ``protection`` (:class:`ProtectionConfig`) and
-    ``canary`` (:class:`CanaryConfig`) — plus the ungrouped scalars below.
-    The historical flat keywords (``mask_source``, ``multi_rail``,
-    ``canary_prompts``, ...) remain constructible as a deprecation shim with
-    identical semantics; after ``__post_init__`` the flat attributes and the
-    sub-configs always agree (a non-default flat value wins over its group,
-    which is what makes ``dataclasses.replace(rel, batched=False)``
-    round-trip), so readers may use either view. The one shim blind spot: a
-    flat keyword handed its *default* value is indistinguishable from
-    "unspecified" and loses to an explicit sub-config — round-trip through
-    the grouped fields when a sub-config is in play. ``validate()`` — called by
-    ``ServingEngine.__init__`` — raises :class:`ReliabilityConfigError`
-    (a ``ValueError``) on contradictory combinations instead of the old
-    scattered inline asserts.
+    Five ungrouped scalars plus four grouped sub-configs — ``fault_model``
+    (:class:`FaultModelConfig`), ``rails`` (:class:`RailsConfig`),
+    ``protection`` (:class:`ProtectionConfig`) and ``canary``
+    (:class:`CanaryConfig`), each defaulting to its own defaults, never
+    None. ``validate()`` — called by ``ServingEngine.__init__`` — raises
+    :class:`ReliabilityConfigError` (a ``ValueError``) on contradictory
+    combinations.
     """
 
     platform: str = "vc707"
     ecc: bool = True
     voltage: float | None = None  # None -> nominal
-    protect: tuple = ("weights",)
     mode: str = "domain"  # domain | inline
-    fuse: bool = True  # inline mode: fused Pallas read path vs naive
     seed: int = 0
-    controller_step_v: float = 0.01
-    # inline mode: one fused inject+scrub launch over the whole-model plane
-    # arena (True) vs the historical per-leaf loop (False, reference path)
-    batched: bool = True
-    # "host": NumPy FaultField oracle (bit-identical to per-leaf path);
-    # "device": counter-based jax.random masks, never materialised on host
-    mask_source: str = "host"
-    # Multi-rail (DESIGN.md §10): partition the plane arena into memory
-    # domains (configs/shapes.domain_of) and give each its own closed-loop
-    # rail. Implies the batched inline path.
-    multi_rail: bool = False
-    # also treat SILENT (ground-truth-only) events as canary trips
-    paranoid: bool = False
-    # include the embedding table in the protected arena (None -> multi_rail:
-    # single-rail engines keep the historical attn/mlp-only protected set)
-    protect_embed: bool | None = None
-    # >0: per-domain fault-curve variation (lognormal sigma) modelling
-    # block-to-block differences (arXiv:2005.04737 / MoRS); 0: shared curve
-    rail_spread: float = 0.0
-    # warm-start voltage for the canary search (None -> v_nom); the
-    # guardband [v_min, v_nom] is fault-free by definition, so starting at
-    # its edge saves ~40 no-op rounds without changing the lock point
-    controller_start_v: float | None = None
-    # Per-domain ECC scheme selection (DESIGN.md §12): a registered codec
-    # name for every domain, or a {domain: name} mapping (unnamed domains
-    # keep the built-in secded72). Dict form implies multi_rail.
-    codecs: Any = None
-    # Optional DED-canary escalation ladder (multi-rail only): an
-    # EscalationPolicy, or a tuple of codec names weakest -> strongest. On a
-    # DED trip a rail steps up its code instead of retreating (see
-    # core/controller.py); the redundancy cost lands in power_report.
-    escalation: Any = None
-    # Mesh rail policy (DESIGN.md §13; engines built with a mesh):
-    # "uniform" locks one schedule at the worst shard's first DED;
-    # "per_shard" walks every chip to its own V_min.
-    rail_policy: str = "uniform"
-    # Environment scenario (DESIGN.md §14): None (historical i.i.d. stream,
-    # bit-for-bit), a name from scenario.ENVIRONMENTS ("consumer" /
-    # "avionics" / "space"), or an EnvironmentProfile. Scales every domain's
-    # fault flux, shapes the masks into correlated multi-bit bursts, and
-    # drifts each mesh shard's rate over the soak.
-    environment: Any = None
-    # Override the environment's aging-drift sigma (scenario.resolve); a bare
-    # drift with environment=None gets the neutral 1x-flux burst-free env.
-    drift: float | None = None
-    # Locked rails re-trip under drift: retreat another backoff step instead
-    # of holding (core/controller.py `adaptive`).
-    adaptive_rails: bool = False
-    # Accuracy canary (DESIGN.md §15): >0 reserves this many fixed canary
-    # prompts; each autotune round greedy-decodes them against a cached
-    # clean-nominal reference rollout and feeds the divergence score
-    # (1 - mean matched-prefix fraction, [0, 1]) to the controller alongside
-    # the DED counters. Inline mode only.
-    canary_prompts: int = 0
-    # decoded continuation length per canary prompt (prompt length is
-    # core/campaign.CANARY_PROMPT_LEN)
-    canary_tokens: int = 12
-    # Divergence SLO for the rails: canary scores above this trip the rail
-    # (escalate if a ladder step remains, else back off + lock) even when
-    # the DED counters are clean. None: canary scores are recorded in the
-    # controller history but never trip.
-    divergence_slo: float | None = None
-    # -- grouped sub-configs (the canonical surface; see class docstring) --
-    fault_model: FaultModelConfig | None = None
-    rails: RailsConfig | None = None
-    protection: ProtectionConfig | None = None
-    canary: CanaryConfig | None = None
-
-    def __post_init__(self):
-        global _FLAT_KWARG_WARNED
-        defaults = {f.name: f.default for f in dataclasses.fields(self)}
-        flat_used = []
-        for group, (cls_, fmap) in _REL_GROUPS.items():
-            sub = getattr(self, group)
-            vals = {}
-            for flat, name in fmap.items():
-                v = getattr(self, flat)
-                try:
-                    is_default = v == defaults[flat]
-                except Exception:
-                    is_default = v is defaults[flat]
-                if not is_default:
-                    # a non-default flat kwarg wins over its sub-config —
-                    # dataclasses.replace() re-passes every flat field, so
-                    # this rule is what makes replace(rel, x=y) round-trip
-                    vals[name] = v
-                    if sub is None or getattr(sub, name) != v:
-                        flat_used.append(flat)
-                elif sub is not None:
-                    vals[name] = getattr(sub, name)
-                else:
-                    vals[name] = v
-            # re-synthesize so flat attributes and sub-config always agree
-            for flat, name in fmap.items():
-                object.__setattr__(self, flat, vals[name])
-            object.__setattr__(self, group, cls_(**vals))
-        if flat_used and not _FLAT_KWARG_WARNED:
-            _FLAT_KWARG_WARNED = True
-            import warnings
-
-            warnings.warn(
-                "flat ReliabilityConfig keywords "
-                f"({', '.join(sorted(set(flat_used)))}) are deprecated; use "
-                "the grouped sub-configs (fault_model=FaultModelConfig(...), "
-                "rails=RailsConfig(...), protection=ProtectionConfig(...), "
-                "canary=CanaryConfig(...))",
-                DeprecationWarning,
-                stacklevel=3,
-            )
+    fault_model: FaultModelConfig = dataclasses.field(default_factory=FaultModelConfig)
+    rails: RailsConfig = dataclasses.field(default_factory=RailsConfig)
+    protection: ProtectionConfig = dataclasses.field(default_factory=ProtectionConfig)
+    canary: CanaryConfig = dataclasses.field(default_factory=CanaryConfig)
 
     def validate(self, *, mesh=None) -> "ReliabilityConfig":
         """Reject contradictory combinations with a typed error.
@@ -327,36 +179,28 @@ class ReliabilityConfig:
             self.platform in vmod.PLATFORMS,
             f"unknown platform {self.platform!r}",
         )
+        rails, prot = self.rails, self.protection
         _require(
-            self.rail_policy in ("uniform", "per_shard"),
-            f"rail_policy must be 'uniform' or 'per_shard', got "
-            f"{self.rail_policy!r}",
+            rails.policy in ("uniform", "per_shard"),
+            f"rails.policy must be 'uniform' or 'per_shard', got "
+            f"{rails.policy!r}",
         )
         if self.mode == "domain":
             _require(
-                self.codecs in (None, "secded72"),
+                prot.codecs in (None, "secded72"),
                 "domain mode stores raw bits behind the built-in SECDED; "
                 "codec selection needs mode='inline'",
             )
         else:
             _require(
-                not self.multi_rail or self.batched,
-                "multi_rail drives the batched plane arena",
-            )
-            _require(
-                self.batched or self.codecs in (None, "secded72"),
-                "the per-leaf reference path is SECDED-only; codec "
-                "selection needs the batched arena",
-            )
-            _require(
-                self.multi_rail
-                or self.codecs is None
-                or isinstance(self.codecs, str),
+                rails.multi_rail
+                or prot.codecs is None
+                or isinstance(prot.codecs, str),
                 "per-domain codec dicts need multi_rail=True",
             )
         if kbackend.resolve() == "compiled":
-            names = set(shapes.domain_codecs(self.codecs).values())
-            if self.escalation is not None:
+            names = set(shapes.domain_codecs(prot.codecs).values())
+            if prot.escalation is not None:
                 names |= set(self.escalation_policy.ladder)
             lut = sorted(n for n in names if codes.get(n).lut_input_arrays())
             _require(
@@ -370,36 +214,38 @@ class ReliabilityConfig:
             )
         if mesh is not None:
             _require(
-                self.multi_rail and self.mode == "inline",
-                "mesh engines drive the multi-rail batched plane arena",
+                rails.multi_rail and self.mode == "inline",
+                "mesh engines drive the multi-rail plane arena",
             )
             _require(
-                self.mask_source == "device",
+                self.fault_model.mask_source == "device",
                 "mesh engines need device masks (per-shard streams live "
                 "inside shard_map)",
             )
             _require(
-                self.rail_policy == "uniform" or self.escalation is None,
+                rails.policy == "uniform" or prot.escalation is None,
                 "per-shard codec escalation needs per-shard plane groups; "
-                "use rail_policy='uniform' with an escalation ladder",
+                "use rails.policy='uniform' with an escalation ladder",
             )
         return self
 
     @property
     def embed_protected(self) -> bool:
-        return self.multi_rail if self.protect_embed is None else self.protect_embed
+        embed = self.protection.embed
+        return self.rails.multi_rail if embed is None else embed
 
     @property
     def environment_profile(self):
-        return scenario.resolve(self.environment, drift=self.drift)
+        return scenario.resolve(
+            self.fault_model.environment, drift=self.fault_model.drift
+        )
 
     @property
     def escalation_policy(self) -> EscalationPolicy | None:
-        if self.escalation is None:
-            return None
-        if isinstance(self.escalation, EscalationPolicy):
-            return self.escalation
-        return EscalationPolicy(ladder=tuple(self.escalation))
+        escalation = self.protection.escalation
+        if escalation is None or isinstance(escalation, EscalationPolicy):
+            return escalation
+        return EscalationPolicy(ladder=tuple(escalation))
 
 
 def _decode_gather_table(ew: kops.EccWeight, codec: str = "secded72") -> jnp.ndarray:
@@ -507,19 +353,19 @@ class ServingEngine:
             rel.validate(mesh=mesh)
         elif mesh is not None:
             raise ReliabilityConfigError(
-                "mesh engines drive the multi-rail batched plane arena "
+                "mesh engines drive the multi-rail plane arena "
                 "(a ReliabilityConfig is required)"
             )
         self.platform = vmod.PLATFORMS[rel.platform] if rel else None
         self.controller = (
             UndervoltController(
                 self.platform,
-                step_v=rel.controller_step_v,
-                paranoid=rel.paranoid,
-                start_v=rel.controller_start_v,
-                divergence_slo=rel.divergence_slo,
+                step_v=rel.rails.step_v,
+                paranoid=rel.canary.paranoid,
+                start_v=rel.rails.start_v,
+                divergence_slo=rel.canary.divergence_slo,
             )
-            if rel and not rel.multi_rail
+            if rel and not rel.rails.multi_rail
             else None  # multi-rail controller is built once the arena exists
         )
         self._canary_ref = None  # clean-nominal canary rollout, built lazily
@@ -541,16 +387,14 @@ class ServingEngine:
             self.set_voltage(self.domain.voltage)
         else:  # inline (validate() already rejected the contradictory combos)
             self.domain = None
-            self.params, self._plane_sizes = protect_params_inline(
+            protected, _ = protect_params_inline(
                 params, cfg, seed=rel.seed, include_embed=rel.embed_protected
             )
-            self._clean_inline = self.params
-            self._fields: dict[str, FaultField] = {}
-            # Batched plane arena: flatten once, record which flat slots hold
+            # Plane arena: flatten once, record which flat slots hold
             # EccWeight planes, and key each by its tree path (the per-leaf
             # fault-field seeds depend on it).
             flat, self._inline_treedef = jax.tree_util.tree_flatten_with_path(
-                self._clean_inline,
+                protected,
                 is_leaf=lambda x: isinstance(x, kops.EccWeight),
             )
             self._inline_template = [leaf for _, leaf in flat]
@@ -559,36 +403,37 @@ class ServingEngine:
                 for i, (path, leaf) in enumerate(flat)
                 if isinstance(leaf, kops.EccWeight)
             ]
+            multi_rail = rel.rails.multi_rail
             rail_profiles = (
                 vmod.derive_domain_profiles(
                     self.platform, shapes.MEMORY_DOMAINS,
-                    spread=rel.rail_spread, seed=rel.seed,
+                    spread=rel.rails.spread, seed=rel.seed,
                 )
-                if rel.multi_rail and rel.rail_spread > 0
+                if multi_rail and rel.rails.spread > 0
                 else None
             )
-            if rel.multi_rail:
-                store_codecs = shapes.domain_codecs(rel.codecs)
+            if multi_rail:
+                store_codecs = shapes.domain_codecs(rel.protection.codecs)
             else:
-                store_codecs = rel.codecs
+                store_codecs = rel.protection.codecs
             self._store = PlaneStore(
                 [self._inline_template[i] for i, _ in self._ecc_slots],
                 [key for _, key in self._ecc_slots],
                 self.platform,
                 seed=rel.seed,
-                mask_source=rel.mask_source,
-                domain_key=shapes.domain_of if rel.multi_rail else None,
+                mask_source=rel.fault_model.mask_source,
+                domain_key=shapes.domain_of if multi_rail else None,
                 profiles=rail_profiles,
                 codecs=store_codecs,
                 mesh=mesh,
                 env=rel.environment_profile,
             )
             self.voltage = rel.voltage or self.platform.v_nom
-            if rel.multi_rail:
+            if multi_rail:
                 rail_kw = dict(
-                    step_v=rel.controller_step_v,
-                    paranoid=rel.paranoid,
-                    start_v=rel.controller_start_v,
+                    step_v=rel.rails.step_v,
+                    paranoid=rel.canary.paranoid,
+                    start_v=rel.rails.start_v,
                     profiles={
                         d: self._store.domain_profile(d)
                         for d in self._store.domains
@@ -597,15 +442,15 @@ class ServingEngine:
                     codecs={
                         d: self._store.codec_of(d) for d in self._store.domains
                     },
-                    adaptive=rel.adaptive_rails,
-                    divergence_slo=rel.divergence_slo,
+                    adaptive=rel.rails.adaptive,
+                    divergence_slo=rel.canary.divergence_slo,
                 )
                 if mesh is not None:
                     self.controller = MeshRailController(
                         self.platform,
                         self._store.domains,
                         self._store.n_shards,
-                        policy=rel.rail_policy,
+                        policy=rel.rails.policy,
                         **rail_kw,
                     )
                 else:
@@ -618,9 +463,6 @@ class ServingEngine:
         if recorder is not None and self.controller is not None:
             self.controller.bind_recorder(recorder)
 
-        self._decode = jax.jit(
-            lambda p, t, c, pos: lm.decode_step(p, t, cfg, c, pos)
-        )
         self._prefill = jax.jit(
             lambda p, t, c: lm.prefill(p, t, cfg, c)
         )
@@ -634,16 +476,20 @@ class ServingEngine:
         self.voltage = float(v)
         if self.rel is None:
             return
-        if self.rel.multi_rail:
+        if self.rel.rails.multi_rail:
             self.set_rails({d: float(v) for d in self._store.domains})
         elif self.rel.mode == "domain":
             self.domain.set_voltage(v)
             self.params, stats = self.domain.read_pytree("w", self._clean_params)
             self.stats.accumulate(stats)
-        elif self.rel.batched:
-            self._apply_inline_faults_batched(v)
         else:
-            self._apply_inline_faults(v)
+            # Whole-model voltage step: one fused inject+scrub kernel launch
+            # over the plane arena; only the (8,) counter vector crosses to
+            # host.
+            leaves, stats = self._store.set_voltage(v, ecc=self.rel.ecc)
+            self.params = self._reassemble_params(leaves)
+            self.stats.accumulate(stats)
+            self._last_scrub = stats
 
     def set_rails(self, volts: dict):
         """Per-domain voltage step: one fused launch, one counter row per
@@ -652,7 +498,7 @@ class ServingEngine:
         outside the weight arena) keep their current voltage — dropping
         them would silently skew the power accounting, which weights every
         domain in ``words_by_domain`` including the registered cache words."""
-        assert self.rel is not None and self.rel.multi_rail
+        assert self.rel is not None and self.rel.rails.multi_rail
         if self.mesh is not None:
             return self._set_rails_mesh(volts)
         new = {d: float(v) for d, v in volts.items()}
@@ -694,7 +540,7 @@ class ServingEngine:
         self._last_scrub = sstats
 
     def _leaf_codec(self, key: str) -> str:
-        if self.rel.multi_rail:
+        if self.rel.rails.multi_rail:
             return self._store.codec_of(shapes.domain_of(key))
         slots = self._store.slots
         return self._store.codec_of(slots[0].domain) if slots else "secded72"
@@ -715,67 +561,12 @@ class ServingEngine:
                 flat[i] = leaf
         return jax.tree_util.tree_unflatten(self._inline_treedef, flat)
 
-    def _apply_inline_faults_batched(self, v: float):
-        """Whole-model voltage step: one fused inject+scrub kernel launch over
-        the plane arena; only the (8,) counter vector crosses to host."""
-        leaves, stats = self._store.set_voltage(v, ecc=self.rel.ecc)
-        self.params = self._reassemble_params(leaves)
-        self.stats.accumulate(stats)
-        self._last_scrub = stats
-
-    def _apply_inline_faults(self, v: float):
-        """Per-leaf reference path (one inject + one scrub launch per leaf,
-        masks generated on host). Kept for parity tests and benchmarks."""
-        flat, treedef = jax.tree_util.tree_flatten_with_path(
-            self._clean_inline, is_leaf=lambda x: isinstance(x, kops.EccWeight)
-        )
-        out = []
-        agg = FaultStats()
-        for path, leaf in flat:
-            if not isinstance(leaf, kops.EccWeight):
-                out.append(leaf)
-                continue
-            key = jax.tree_util.keystr(path)
-            field = self._fields.get(key)
-            if field is None:
-                field = FaultField(
-                    self.platform, leaf.lo.size, seed=leaf_seed(self.rel.seed, key)
-                )
-                self._fields[key] = field
-            masks = field.masks(v)
-            mlo = jnp.asarray(masks.lo.reshape(leaf.lo.shape))
-            mhi = jnp.asarray(masks.hi.reshape(leaf.hi.shape))
-            mpar = jnp.asarray(masks.parity.reshape(leaf.parity.shape))
-            flo, fhi, fpar = kops.inject(leaf.lo, leaf.hi, leaf.parity, mlo, mhi, mpar)
-            faulty = dataclasses.replace(leaf, lo=flo, hi=fhi, parity=fpar)
-            if not self.rel.ecc:
-                # No-ECC baseline: zero the parity contribution by decoding off
-                # — we emulate by treating planes as raw (decode would mis-fire),
-                # so instead keep faulty planes and a pass-through decode: the
-                # raw faulty bits flow straight into the matmul.
-                faulty = dataclasses.replace(faulty, parity=kops.encode(faulty.lo, faulty.hi))
-            status = np.asarray(kops.scrub(faulty))
-            agg.accumulate(FaultStats.from_decode(status, masks.flip_counts()))
-            out.append(_decode_gather_table(faulty) if "embed" in key else faulty)
-        self.params = jax.tree_util.tree_unflatten(treedef, out)
-        self.stats.accumulate(agg)
-        self._last_scrub = agg
-
     # -- serving --------------------------------------------------------------
-    def generate(
-        self,
-        prompts: np.ndarray,
-        n_tokens: int,
-        *,
-        use_scan: bool = True,
-        params=None,
-    ):
+    def generate(self, prompts: np.ndarray, n_tokens: int, *, params=None):
         """Greedy-decode a batch. prompts: (B, S0) int32. Returns (B, n).
 
-        use_scan=True rolls the decode loop into one lax.scan program (one
-        dispatch for the whole rollout; compiled once per n_tokens value);
-        use_scan=False is the historical per-token Python loop, kept as the
-        reference the scan path is tested against. ``params`` overrides the
+        The decode loop is one lax.scan program (one dispatch for the whole
+        rollout; compiled once per n_tokens value). ``params`` overrides the
         engine's (possibly fault-injected) weights for this rollout — the
         accuracy canary uses it to decode the clean reference through the
         same jitted programs.
@@ -793,13 +584,6 @@ class ServingEngine:
             lambda c: jnp.pad(c, [(0, 0), (0, pad)] + [(0, 0)] * (c.ndim - 2)),
             cache,
         )
-        if not use_scan:
-            outs = [tok]
-            for i in range(n_tokens - 1):
-                logits, cache = self._decode(p, tok, cache, s0 + i)
-                tok = jnp.argmax(logits, -1).astype(jnp.int32)[:, None]
-                outs.append(tok)
-            return np.concatenate([np.asarray(o) for o in outs], axis=1)[:b]
         toks, _ = self._decode_loop(
             p, tok, cache, jnp.int32(s0), n_tokens - 1
         )
@@ -812,12 +596,12 @@ class ServingEngine:
 
         Returns ``1 - mean(matched prefix fraction)`` in [0, 1] (exactly 0.0
         when every canary continuation is bit-identical to the clean run), or
-        None when the canary is disabled (``rel.canary_prompts == 0``). The
+        None when the canary is disabled (``rel.canary.prompts == 0``). The
         reference is decoded once, lazily, from the *clean* plane templates
         through the same quantized ECC read path — so quantization noise
         cancels and only injected faults can score.
         """
-        if self.rel is None or not self.rel.canary_prompts:
+        if self.rel is None or not self.rel.canary.prompts:
             return None
         assert self.rel.mode == "inline", (
             "the accuracy canary decodes against the clean inline plane "
@@ -827,7 +611,7 @@ class ServingEngine:
 
         prompts = campaign.eval_prompts(
             self.cfg.vocab,
-            self.rel.canary_prompts,
+            self.rel.canary.prompts,
             campaign.CANARY_PROMPT_LEN,
             seed=self.rel.seed ^ 0xACC,
         )
@@ -836,9 +620,9 @@ class ServingEngine:
                 [self._inline_template[i] for i, _ in self._ecc_slots]
             )
             self._canary_ref = self.generate(
-                prompts, self.rel.canary_tokens, params=clean
+                prompts, self.rel.canary.tokens, params=clean
             )
-        cur = self.generate(prompts, self.rel.canary_tokens)
+        cur = self.generate(prompts, self.rel.canary.tokens)
         div = campaign.token_divergence(self._canary_ref, cur)
         if self.recorder:
             self.recorder.emit("canary_probe", divergence=float(div))
@@ -865,7 +649,7 @@ class ServingEngine:
         """Serve a stream of variable-length requests (DESIGN.md §11/§16).
 
         ``requests``: iterable of (prompt (s0,) int32, max_new_tokens) pairs
-        or scheduler.Request/``ServeRequest`` objects. The KV cache lives in
+        or ``scheduler.Request`` objects. The KV cache lives in
         SECDED pages on the `kv` voltage domain; every read scrubs. At
         nominal voltage the output tokens are bit-identical to `generate` on
         the same batch composition (tested): prefill groups must match, and
@@ -936,7 +720,7 @@ class ServingEngine:
             )
         profile = self.platform or vmod.PLATFORMS["vc707"]
         envp = self.rel.environment_profile if self.rel is not None else None
-        if self.rel is not None and self.rel.multi_rail:
+        if self.rel is not None and self.rel.rails.multi_rail:
             profile = self._store.domain_profile("kv")  # env-scaled flux
         elif envp is not None:
             profile = envp.scale_profile(profile)
@@ -944,7 +728,7 @@ class ServingEngine:
         if n_pages is None:
             n_pages = n_lanes * geom.pages_for(self.max_len)
         kv_codec = (
-            shapes.domain_codecs(self.rel.codecs)["kv"]
+            shapes.domain_codecs(self.rel.protection.codecs)["kv"]
             if self.rel is not None
             else shapes.DEFAULT_CODEC
         )
@@ -982,7 +766,7 @@ class ServingEngine:
 
         kv_controller = None
         if walk_kv:
-            assert self.rel is not None and self.rel.multi_rail, (
+            assert self.rel is not None and self.rel.rails.multi_rail, (
                 "walk_kv needs a multi-rail engine"
             )
             kv_controller = self.controller.add_rail("kv", profile, codec=kv_codec)
@@ -1072,7 +856,7 @@ class ServingEngine:
         parts = sched.partition_requests(
             sched.normalize_requests(requests), n_shards
         )
-        base_codec = shapes.domain_codecs(self.rel.codecs)["kv"]
+        base_codec = shapes.domain_codecs(self.rel.protection.codecs)["kv"]
         kv_rails = (
             self.controller.add_rail("kv", profile, codec=base_codec)
             if walk_kv
@@ -1180,7 +964,7 @@ class ServingEngine:
         assert self.rel is not None and self.controller is not None
         if self.mesh is not None:
             return self._autotune_rails_mesh(max_rounds)
-        if self.rel.multi_rail:
+        if self.rel.rails.multi_rail:
             return self._autotune_rails(max_rounds)
         for _ in range(max_rounds):
             if self.recorder:
@@ -1314,7 +1098,7 @@ class ServingEngine:
             )
             return {
                 "n_shards": self._store.n_shards,
-                "policy": self.rel.rail_policy,
+                "policy": self.rel.rails.policy,
                 "codecs": self._store.codecs_by_domain(),
                 "check_bits": bits,
                 "shards": per_shard,

@@ -72,12 +72,6 @@ class Request:
     max_new_tokens: int
 
 
-#: Public name of the request protocol type (`repro.serving.ServeRequest`):
-#: the consolidated serving API exports the dataclass under the name the
-#: engine/scheduler docs use; `Request` remains for existing call sites.
-ServeRequest = Request
-
-
 @dataclasses.dataclass
 class RequestState:
     req: Request

@@ -29,6 +29,12 @@ from repro.core.controller import (
 from repro.core.sweep import campaign_voltage_grid
 from repro.core.telemetry import FaultStats
 from repro.core.voltage import PLATFORMS
+from repro.serving.engine import (
+    CanaryConfig,
+    RailsConfig,
+    ReliabilityConfig,
+    ServingEngine,
+)
 
 VC707 = PLATFORMS["vc707"]
 
@@ -270,8 +276,6 @@ def tiny_setup():
 
 
 def _engine(cfg, params, **rel_kw):
-    from repro.serving.engine import ReliabilityConfig, ServingEngine
-
     rel = ReliabilityConfig(platform="vc707", mode="inline", **rel_kw)
     return ServingEngine(cfg, params, rel=rel, max_len=32)
 
@@ -280,7 +284,7 @@ def test_canary_divergence_disabled_and_clean(tiny_setup):
     cfg, params = tiny_setup
     eng = _engine(cfg, params)
     assert eng.canary_divergence() is None  # off by default
-    eng2 = _engine(cfg, params, canary_prompts=2, canary_tokens=8)
+    eng2 = _engine(cfg, params, canary=CanaryConfig(prompts=2, tokens=8))
     assert eng2.canary_divergence() == 0.0  # nominal == clean, exactly
 
 
@@ -293,7 +297,7 @@ def test_canary_retreat_where_ded_counters_are_blind(tiny_setup):
     cfg, params = tiny_setup
     # control: DED-only walk from the guardband edge never retreats — it
     # descends straight to the crash floor
-    ctl = _engine(cfg, params, ecc=False, controller_start_v=VC707.v_min)
+    ctl = _engine(cfg, params, ecc=False, rails=RailsConfig(start_v=VC707.v_min))
     v_ctl, hist_ctl = ctl.autotune_voltage(max_rounds=12)
     assert all(h.detected == 0 for h in hist_ctl)
     assert not any("backoff" in h.action for h in hist_ctl)
@@ -303,8 +307,8 @@ def test_canary_retreat_where_ded_counters_are_blind(tiny_setup):
 
     # canary: same blind counters, but the divergence SLO trips the rail
     eng = _engine(
-        cfg, params, ecc=False, controller_start_v=VC707.v_min,
-        canary_prompts=2, canary_tokens=8, divergence_slo=0.05,
+        cfg, params, ecc=False, rails=RailsConfig(start_v=VC707.v_min),
+        canary=CanaryConfig(prompts=2, tokens=8, divergence_slo=0.05),
     )
     v, hist = eng.autotune_voltage(max_rounds=12)
     assert all(h.detected == 0 for h in hist)  # DED never fired
@@ -319,9 +323,9 @@ def test_canary_multirail_retreats_all_rails(tiny_setup):
     arena rail backs off on the SLO violation, counters silent."""
     cfg, params = tiny_setup
     eng = _engine(
-        cfg, params, ecc=False, multi_rail=True,
-        controller_start_v=VC707.v_min, canary_prompts=2, canary_tokens=8,
-        divergence_slo=0.05,
+        cfg, params, ecc=False,
+        rails=RailsConfig(multi_rail=True, start_v=VC707.v_min),
+        canary=CanaryConfig(prompts=2, tokens=8, divergence_slo=0.05),
     )
     eng.autotune_voltage(max_rounds=12)
     tripped = [

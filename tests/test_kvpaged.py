@@ -24,7 +24,7 @@ from repro.configs.shapes import supports_paged_kv
 from repro.core import voltage as vmod
 from repro.core.kvpages import KVGeometry, KVPageArena, PageAllocator
 from repro.models import lm
-from repro.serving.engine import ReliabilityConfig, ServingEngine
+from repro.serving.engine import RailsConfig, ReliabilityConfig, ServingEngine
 
 
 @pytest.fixture(scope="module")
@@ -317,7 +317,7 @@ def test_kv_rail_walks_independently_of_weight_rails(setup):
         cfg, params,
         rel=ReliabilityConfig(
             platform="vc707", ecc=True, voltage=1.0, mode="inline",
-            multi_rail=True, controller_start_v=0.60,
+            rails=RailsConfig(multi_rail=True, start_v=0.60),
         ),
         max_len=48,
     )

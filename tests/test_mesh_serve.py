@@ -17,7 +17,13 @@ import jax
 from conftest import tiny_cfg
 from repro.launch.mesh import make_reliability_mesh
 from repro.models import lm
-from repro.serving.engine import ReliabilityConfig, ServingEngine
+from repro.serving.engine import (
+    FaultModelConfig,
+    ProtectionConfig,
+    RailsConfig,
+    ReliabilityConfig,
+    ServingEngine,
+)
 
 
 @pytest.fixture(scope="module")
@@ -32,13 +38,13 @@ def setup():
     return cfg, params, reqs
 
 
-def _rel(**kw):
-    base = dict(
-        mode="inline", multi_rail=True, mask_source="device", voltage=0.60,
-        seed=1,
+def _rel(mask_source="device", protection=ProtectionConfig(), **rails):
+    return ReliabilityConfig(
+        mode="inline", voltage=0.60, seed=1,
+        fault_model=FaultModelConfig(mask_source=mask_source),
+        rails=RailsConfig(**{"multi_rail": True, **rails}),
+        protection=protection,
     )
-    base.update(kw)
-    return ReliabilityConfig(**base)
 
 
 def test_engine_mesh_1dev_bit_identical(setup):
@@ -49,7 +55,7 @@ def test_engine_mesh_1dev_bit_identical(setup):
     e1 = ServingEngine(cfg, params, _rel(), max_len=64)
     r1 = e1.serve(reqs, n_lanes=2, scrub_interval=2, kv_voltage=0.57, walk_kv=True)
     e2 = ServingEngine(
-        cfg, params, _rel(rail_policy="per_shard"), max_len=64,
+        cfg, params, _rel(policy="per_shard"), max_len=64,
         mesh=make_reliability_mesh(1),
     )
     r2 = e2.serve(reqs, n_lanes=2, scrub_interval=2, kv_voltage=0.57, walk_kv=True)
@@ -87,11 +93,14 @@ def test_engine_mesh_guards(setup):
     with pytest.raises(AssertionError):
         ServingEngine(
             cfg, params,
-            _rel(rail_policy="per_shard", escalation=("secded72", "dected79")),
+            _rel(
+                policy="per_shard",
+                protection=ProtectionConfig(escalation=("secded72", "dected79")),
+            ),
             mesh=mesh,
         )
     with pytest.raises(AssertionError):
-        ServingEngine(cfg, params, _rel(rail_policy="per_chip"), mesh=mesh)
+        ServingEngine(cfg, params, _rel(policy="per_chip"), mesh=mesh)
 
 
 def test_mesh8_serve_acceptance(tmp_path):
@@ -109,7 +118,9 @@ def test_mesh8_serve_acceptance(tmp_path):
         from conftest import tiny_cfg
         from repro.launch.mesh import make_reliability_mesh
         from repro.models import lm
-        from repro.serving.engine import ReliabilityConfig, ServingEngine
+        from repro.serving.engine import (
+            FaultModelConfig, RailsConfig, ReliabilityConfig, ServingEngine,
+        )
 
         cfg = tiny_cfg(d_model=64, n_layers=2, d_ff=128, vocab=128)
         params = lm.init_params(cfg, jax.random.PRNGKey(0))
@@ -122,8 +133,9 @@ def test_mesh8_serve_acceptance(tmp_path):
         ]
         mesh = make_reliability_mesh(8)
         rel = ReliabilityConfig(
-            mode="inline", multi_rail=True, mask_source="device", voltage=0.60,
-            seed=1, rail_policy="per_shard", controller_start_v=0.60,
+            mode="inline", voltage=0.60, seed=1,
+            fault_model=FaultModelConfig(mask_source="device"),
+            rails=RailsConfig(multi_rail=True, policy="per_shard", start_v=0.60),
         )
         e = ServingEngine(cfg, params, rel, max_len=64, mesh=mesh)
         r = e.serve(reqs, n_lanes=2, scrub_interval=1, walk_kv=True)
@@ -132,8 +144,9 @@ def test_mesh8_serve_acceptance(tmp_path):
         # equal-voltage power comparison vs the unsharded 1-device engine
         e.set_rails({d: 0.56 for d in e._store.domains})
         e1 = ServingEngine(cfg, params, ReliabilityConfig(
-            mode="inline", multi_rail=True, mask_source="device", voltage=0.60,
-            seed=1,
+            mode="inline", voltage=0.60, seed=1,
+            fault_model=FaultModelConfig(mask_source="device"),
+            rails=RailsConfig(multi_rail=True),
         ), max_len=64)
         r1 = e1.serve(reqs, n_lanes=2, scrub_interval=1, kv_voltage=0.56)
         e1.set_rails({d: 0.56 for d in e1._store.domains})
@@ -185,13 +198,13 @@ def test_mesh_uniform_policy_shared_walk(setup):
     unsharded controller; rails list still has one entry per shard."""
     cfg, params, reqs = setup
     e = ServingEngine(
-        cfg, params, _rel(rail_policy="uniform", controller_start_v=0.62),
+        cfg, params, _rel(policy="uniform", start_v=0.62),
         max_len=64, mesh=make_reliability_mesh(1),
     )
     schedules, history = e.autotune_voltage(max_rounds=40)
     assert len(schedules) == 1
     ref = ServingEngine(
-        cfg, params, _rel(controller_start_v=0.62), max_len=64
+        cfg, params, _rel(start_v=0.62), max_len=64
     )
     v_ref, _ = ref.autotune_voltage(max_rounds=40)
     assert schedules[0] == v_ref

@@ -312,14 +312,20 @@ def test_engine_multirail_beats_single_rail_and_is_clean_at_nominal(lm_setup):
     """Acceptance: per-domain autotune locks at least one domain below the
     global single-rail lock, saves at least as much power, and the multi-rail
     machinery is bit-invisible at nominal voltage."""
-    from repro.serving.engine import ReliabilityConfig, ServingEngine
+    from repro.serving.engine import (
+        ProtectionConfig,
+        RailsConfig,
+        ReliabilityConfig,
+        ServingEngine,
+    )
 
     cfg, params, prompts = lm_setup
     single = ServingEngine(
         cfg, params,
         rel=ReliabilityConfig(
             platform="vc707", ecc=True, voltage=1.0, mode="inline",
-            protect_embed=True, controller_start_v=0.62,
+            rails=RailsConfig(start_v=0.62),
+            protection=ProtectionConfig(embed=True),
         ),
         max_len=32,
     )
@@ -330,7 +336,7 @@ def test_engine_multirail_beats_single_rail_and_is_clean_at_nominal(lm_setup):
         cfg, params,
         rel=ReliabilityConfig(
             platform="vc707", ecc=True, voltage=1.0, mode="inline",
-            multi_rail=True, controller_start_v=0.62,
+            rails=RailsConfig(multi_rail=True, start_v=0.62),
         ),
         max_len=32,
     )
@@ -357,14 +363,20 @@ def test_engine_multirail_beats_single_rail_and_is_clean_at_nominal(lm_setup):
 
 
 def test_engine_multirail_generates_under_locked_schedule(lm_setup):
-    from repro.serving.engine import ReliabilityConfig, ServingEngine
+    from repro.serving.engine import (
+        FaultModelConfig,
+        RailsConfig,
+        ReliabilityConfig,
+        ServingEngine,
+    )
 
     cfg, params, prompts = lm_setup
     eng = ServingEngine(
         cfg, params,
         rel=ReliabilityConfig(
-            platform="vc707", mode="inline", multi_rail=True,
-            mask_source="device", controller_start_v=0.62,
+            platform="vc707", mode="inline",
+            fault_model=FaultModelConfig(mask_source="device"),
+            rails=RailsConfig(multi_rail=True, start_v=0.62),
         ),
         max_len=32,
     )

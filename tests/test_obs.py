@@ -25,7 +25,12 @@ from repro.obs import (
     validate_events,
 )
 from repro.obs import profile as obs_profile
-from repro.serving import ReliabilityConfig, ServingEngine
+from repro.serving import (
+    FaultModelConfig,
+    RailsConfig,
+    ReliabilityConfig,
+    ServingEngine,
+)
 
 
 # ---------------------------------------------------------------------------
@@ -194,8 +199,9 @@ def _engine(cfg, params, recorder=None):
     return ServingEngine(
         cfg, params,
         rel=ReliabilityConfig(
-            mode="inline", voltage=0.58, multi_rail=True,
-            mask_source="device", seed=1,
+            mode="inline", voltage=0.58, seed=1,
+            fault_model=FaultModelConfig(mask_source="device"),
+            rails=RailsConfig(multi_rail=True),
         ),
         max_len=64, recorder=recorder,
     )
@@ -450,7 +456,9 @@ def test_mesh_serve_single_causal_trace():
         from repro.launch.mesh import make_reliability_mesh
         from repro.models import lm
         from repro.obs import TraceRecorder, validate_events
-        from repro.serving.engine import ReliabilityConfig, ServingEngine
+        from repro.serving.engine import (
+            FaultModelConfig, RailsConfig, ReliabilityConfig, ServingEngine,
+        )
 
         cfg = tiny_cfg(d_model=64, n_layers=2, d_ff=128, vocab=128)
         params = lm.init_params(cfg, jax.random.PRNGKey(0))
@@ -465,9 +473,9 @@ def test_mesh_serve_single_causal_trace():
         e = ServingEngine(
             cfg, params,
             ReliabilityConfig(
-                mode="inline", multi_rail=True, mask_source="device",
-                voltage=0.58, seed=1, rail_policy="per_shard",
-                controller_start_v=0.58,
+                mode="inline", voltage=0.58, seed=1,
+                fault_model=FaultModelConfig(mask_source="device"),
+                rails=RailsConfig(multi_rail=True, policy="per_shard", start_v=0.58),
             ),
             max_len=64, mesh=make_reliability_mesh(2), recorder=rec,
         )
@@ -520,7 +528,8 @@ def test_autotune_rail_steps_advance_clock(serve_setup):
     eng = ServingEngine(
         cfg, params,
         rel=ReliabilityConfig(
-            mode="inline", voltage=0.62, mask_source="device", seed=1
+            mode="inline", voltage=0.62, seed=1,
+            fault_model=FaultModelConfig(mask_source="device"),
         ),
         max_len=64, recorder=rec,
     )

@@ -15,7 +15,6 @@ decode emits exactly the greedy rollout no matter how bad the draft is.
 """
 
 import dataclasses
-import warnings
 
 import numpy as np
 import pytest
@@ -43,7 +42,6 @@ from repro.serving import (
     ReliabilityConfigError,
     ServingEngine,
 )
-import repro.serving.engine as engine_mod
 
 
 # ---------------------------------------------------------------------------
@@ -385,63 +383,65 @@ def test_speculative_composes_with_prefix_sharing():
 
 
 # ---------------------------------------------------------------------------
-# ReliabilityConfig redesign (satellite: grouped sub-configs + shim)
+# ReliabilityConfig: one grouped surface
 # ---------------------------------------------------------------------------
 
-
-def test_grouped_subconfigs_equal_flat_kwargs():
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        flat = ReliabilityConfig(
-            platform="vc707", mode="inline", multi_rail=True,
-            controller_start_v=0.6, mask_source="device",
-            codecs={"mlp": "dected79"}, canary_prompts=2,
-        )
-    grouped = ReliabilityConfig(
-        platform="vc707", mode="inline",
-        fault_model=FaultModelConfig(mask_source="device"),
-        rails=RailsConfig(multi_rail=True, start_v=0.6),
-        protection=ProtectionConfig(codecs={"mlp": "dected79"}),
-        canary=CanaryConfig(prompts=2),
+# the flat keywords the grouped sub-configs replaced, and two dead options
+_REMOVED_KEYWORDS = [
+    (ReliabilityConfig, name)
+    for name in (
+        "mask_source", "batched", "environment", "drift",
+        "multi_rail", "rail_policy", "rail_spread", "controller_step_v",
+        "controller_start_v", "adaptive_rails",
+        "codecs", "escalation", "protect", "protect_embed",
+        "canary_prompts", "canary_tokens", "divergence_slo", "paranoid",
+        "fuse",
     )
-    assert flat == grouped
-    # flat mirrors stay readable either way
-    assert grouped.multi_rail and grouped.controller_start_v == 0.6
-    assert grouped.rails.start_v == 0.6
-    assert grouped.canary.prompts == 2 and grouped.canary_prompts == 2
+] + [(FaultModelConfig, "batched"), (ProtectionConfig, "protect")]
 
 
-def test_flat_kwargs_warn_once_per_process():
-    engine_mod._FLAT_KWARG_WARNED = False
-    with pytest.warns(DeprecationWarning, match="multi_rail"):
-        ReliabilityConfig(multi_rail=True)
-    with warnings.catch_warnings():
-        warnings.simplefilter("error", DeprecationWarning)
-        ReliabilityConfig(multi_rail=True)  # second use: silent
-        # grouped construction never warms the shim at all
-        engine_mod._FLAT_KWARG_WARNED = False
-        ReliabilityConfig(rails=RailsConfig(multi_rail=True))
-    assert not engine_mod._FLAT_KWARG_WARNED
+def test_flat_keywords_raise_type_error():
+    for cls, name in _REMOVED_KEYWORDS:
+        with pytest.raises(TypeError, match=name):
+            cls(**{name: True})
+        assert not hasattr(cls(), name), (cls.__name__, name)
 
 
-def test_dataclasses_replace_roundtrip():
-    rel = ReliabilityConfig(rails=RailsConfig(multi_rail=True), mode="inline")
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        flipped = dataclasses.replace(rel, batched=False)
-    # a non-default flat override wins and re-synthesizes its sub-config
-    assert flipped.batched is False and flipped.fault_model.batched is False
-    assert flipped.multi_rail and flipped.rails.multi_rail
-    # flipping *back* through the flat name hands in the default value,
-    # which is indistinguishable from "unspecified" — the sub-config wins
-    # (documented shim limitation); the grouped field restores exactly
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        assert dataclasses.replace(flipped, batched=True) == flipped
-    restored = dataclasses.replace(
-        flipped, batched=True, fault_model=FaultModelConfig(batched=True)
+def test_subconfigs_default_to_instances():
+    # never None: engine code reads the groups unguarded
+    rel = ReliabilityConfig()
+    assert rel.fault_model == FaultModelConfig()
+    assert rel.rails == RailsConfig()
+    assert rel.protection == ProtectionConfig()
+    assert rel.canary == CanaryConfig()
+    assert ReliabilityConfig() == rel
+    assert not rel.embed_protected and rel.escalation_policy is None
+    assert [f.name for f in dataclasses.fields(rel)] == [
+        "platform", "ecc", "voltage", "mode", "seed",
+        "fault_model", "rails", "protection", "canary",
+    ]
+    assert [f.name for f in dataclasses.fields(FaultModelConfig)] == [
+        "mask_source", "environment", "drift",
+    ]
+    assert [f.name for f in dataclasses.fields(ProtectionConfig)] == [
+        "codecs", "escalation", "embed",
+    ]
+
+
+def test_replace_roundtrips_through_subconfigs():
+    rel = ReliabilityConfig(
+        mode="inline", rails=RailsConfig(multi_rail=True), seed=3
     )
-    assert restored == rel
+    moved = dataclasses.replace(rel, rails=dataclasses.replace(rel.rails, start_v=0.6))
+    assert moved.rails.start_v == 0.6 and moved.rails.multi_rail
+    assert moved != rel
+    # exactly that one field differs
+    assert dataclasses.replace(moved.rails, start_v=None) == rel.rails
+    for f in dataclasses.fields(rel):
+        if f.name != "rails":
+            assert getattr(moved, f.name) == getattr(rel, f.name), f.name
+    back = dataclasses.replace(moved, rails=dataclasses.replace(moved.rails, start_v=None))
+    assert back == rel
 
 
 def test_validate_raises_typed_errors():

@@ -15,7 +15,12 @@ import pytest
 
 from repro.configs import get_smoke_config
 from repro.models import lm
-from repro.serving.engine import ReliabilityConfig, ServingEngine
+from repro.serving.engine import (
+    ProtectionConfig,
+    RailsConfig,
+    ReliabilityConfig,
+    ServingEngine,
+)
 
 
 @pytest.fixture(scope="module")
@@ -83,7 +88,7 @@ def test_overlap_identical_rail_walk(setup):
             cfg, params,
             rel=ReliabilityConfig(
                 platform="vc707", ecc=True, voltage=1.0, mode="inline",
-                multi_rail=True, controller_start_v=0.60,
+                rails=RailsConfig(multi_rail=True, start_v=0.60),
             ),
             max_len=48,
         )
@@ -111,8 +116,8 @@ def test_overlap_auto_demotes_under_escalation(setup):
         cfg, params,
         rel=ReliabilityConfig(
             platform="vc707", ecc=True, voltage=1.0, mode="inline",
-            multi_rail=True, controller_start_v=0.60,
-            escalation=("secded72", "dected79"),
+            rails=RailsConfig(multi_rail=True, start_v=0.60),
+            protection=ProtectionConfig(escalation=("secded72", "dected79")),
         ),
         max_len=48,
     )
