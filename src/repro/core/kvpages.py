@@ -18,9 +18,11 @@ Layout
     continuous-batching scheduler (serving/scheduler.py) allocates one page
     per ``page_tokens`` positions per request and frees them on retire or
     preemption.
-  * Writes encode (kernels/ops.encode); reads slice each page out of the
-    flat planes as one contiguous ``words_per_page`` window addressed by its
-    start offset (`_gather_pages`), travel through the scrub-on-read kernel
+  * Writes encode (kernels/ops.encode) and land each token as one
+    contiguous ``token_words`` window at its start offset (`_commit_tokens`);
+    reads slice each page out of the flat planes as one contiguous
+    ``words_per_page`` window addressed by its start offset
+    (`_gather_pages`), travel through the scrub-on-read kernel
     (kernels/paged_gather.py) which corrects single-bit faults and emits
     per-page (clean, corrected, detected) counters, and the corrected pages
     are written back window by window (`_scatter_pages`).
@@ -445,8 +447,9 @@ def _xor_into(plane, mask):
     return plane ^ mask
 
 
-# A flat plane addressed by page: one int32 start offset ``page * W`` per
-# page and a contiguous W-word window, so no per-word index exists.
+# A flat plane addressed by window: one int32 start offset per row and a
+# contiguous window after it (a page's W words, or a token's token_words),
+# so no per-word index exists.
 _PAGE_WINDOW = jax.lax.GatherDimensionNumbers(
     offset_dims=(1,), collapsed_slice_dims=(), start_index_map=(0,)
 )
@@ -466,17 +469,24 @@ def _gather_pages(plane, page_ids, words_per_page):
     )
 
 
-def _scatter_pages(plane, page_ids, rows, words_per_page):
-    """Write (P, words_per_page) rows back over their pages of the flat
-    plane. Repeated ids (idle lanes' scratch page) carry identical rows, so
-    the order in which duplicates land does not matter."""
+def _scatter_windows(plane, starts, rows):
+    """Write (N, w) rows over the w-word windows of the flat plane that
+    begin at ``starts`` (N,). Where windows repeat, which row lands last is
+    unspecified: callers repeat only windows whose order does not matter."""
     return jax.lax.scatter(
         plane,
-        (page_ids * words_per_page)[:, None],
+        starts[:, None],
         rows,
         _PAGE_UPDATE,
         mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS,
     )
+
+
+def _scatter_pages(plane, page_ids, rows, words_per_page):
+    """Write (P, words_per_page) rows back over their pages of the flat
+    plane. Repeated ids (idle lanes' scratch page) carry identical rows, so
+    the order in which duplicates land does not matter."""
+    return _scatter_windows(plane, page_ids * words_per_page, rows)
 
 
 @functools.partial(jax.jit, static_argnames=("words_per_page",))
@@ -497,11 +507,19 @@ def _commit_tokens(
     lo, hi, par, payload, page_ids, slots, *, token_words, words_per_page,
     codec: str = "secded72",
 ):
-    """Encode token payload rows and scatter them into the arena planes."""
+    """Encode token payload rows and scatter them into the arena planes.
+
+    A token row is one contiguous ``token_words`` window of each plane,
+    starting at ``page * words_per_page + slot * token_words``. Real rows
+    never share a (page, slot); padding rows of a prefill group and idle
+    decode lanes repeat scratch-page windows with different payloads, but
+    the scratch page is never read, so the order they land in does not
+    matter."""
     rlo, rhi, rpar = _payload_to_planes(payload, codec)
-    base = page_ids * words_per_page + slots * token_words
-    idx = base[:, None] + jnp.arange(token_words, dtype=jnp.int32)[None, :]
-    return lo.at[idx].set(rlo), hi.at[idx].set(rhi), par.at[idx].set(rpar)
+    starts = page_ids * words_per_page + slots * token_words
+    return tuple(
+        _scatter_windows(p, starts, r) for p, r in ((lo, rlo), (hi, rhi), (par, rpar))
+    )
 
 
 @functools.partial(

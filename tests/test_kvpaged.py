@@ -311,6 +311,76 @@ def test_page_window_scrub_matches_word_index_form(codec, table):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
 
 
+def _word_index_commit(lo, hi, par, payload, page_ids, slots, *, token_words,
+                       words_per_page, codec):
+    """The commit as it addressed tokens through one int32 index per word:
+    the reference the token-window form must match bit for bit."""
+    from repro.core.kvpages import _payload_to_planes
+
+    rlo, rhi, rpar = _payload_to_planes(payload, codec)
+    base = page_ids * words_per_page + slots * token_words
+    idx = base[:, None] + jnp.arange(token_words, dtype=jnp.int32)[None, :]
+    return lo.at[idx].set(rlo), hi.at[idx].set(rhi), par.at[idx].set(rpar)
+
+
+def _commit_rows(case, n_pages, page_tokens, s):
+    """(page_ids, slots) of one commit, laid out as its caller lays them."""
+    if case == "one_row":
+        return [2], [1]
+    if case == "decode_idle_lanes":  # idle lanes steered to the scratch page
+        return [0, s, 3, s, s, 1], [2, 0, 1, 0, 0, 3]
+    if case == "prompt_across_pages":  # 11 tokens over pages 3, 1, 4
+        t = np.arange(11)
+        return np.array([3, 1, 4])[t // page_tokens], t % page_tokens
+    if case == "prompt_group_padding":  # 2 prompts of 6 and 2 padding rows
+        t = np.arange(6)
+        table = [[0, 2], [4, 1], [s, s], [s, s]]
+        pages = np.stack([np.array(row)[t // page_tokens] for row in table])
+        return pages.reshape(-1), np.tile(t % page_tokens, len(table))
+    assert case == "last_page_last_slot"
+    return [0, n_pages - 1], [0, page_tokens - 1]
+
+
+@pytest.mark.parametrize(
+    "case",
+    ["one_row", "decode_idle_lanes", "prompt_across_pages", "prompt_group_padding",
+     "last_page_last_slot"],
+)
+@pytest.mark.parametrize("codec", ["secded72", "dected79"])
+def test_token_window_commit_matches_word_index_form(codec, case):
+    """`_commit_tokens` writes each token as one ``token_words`` window at
+    its start offset; outside the scratch page its planes equal the
+    per-word-index formulation's, over an arena whose untouched words hold
+    earlier data."""
+    from repro.core.kvpages import _commit_tokens
+
+    arena, geom = _mk_arena(page_tokens=4, n_pages=5, codec=codec)
+    rng = np.random.default_rng(11)
+    n_tok = geom.page_tokens * (arena.n_pages + 1)
+    arena.commit_tokens(
+        jnp.asarray(rng.standard_normal((n_tok, geom.token_f32)).astype(np.float32)),
+        np.repeat(np.arange(arena.n_pages + 1), geom.page_tokens),
+        np.tile(np.arange(geom.page_tokens), arena.n_pages + 1),
+    )
+    pages, slots = _commit_rows(case, arena.n_pages, geom.page_tokens, arena.scratch_page)
+    pages, slots = jnp.asarray(pages, jnp.int32), jnp.asarray(slots, jnp.int32)
+    payload = jnp.asarray(
+        rng.standard_normal((pages.shape[0], geom.token_f32)).astype(np.float32)
+    )
+    planes = (arena.lo, arena.hi, arena.parity)
+    kw = dict(token_words=geom.token_words, words_per_page=geom.words_per_page, codec=codec)
+    want = _word_index_commit(*planes, payload, pages, slots, **kw)
+    got = _commit_tokens(*planes, payload, pages, slots, **kw)
+    real = arena.n_words  # the scratch page follows the real pages
+    for name, before, a, b in zip(("lo", "hi", "par"), planes, want, got):
+        assert a.shape == b.shape and a.dtype == b.dtype, name
+        np.testing.assert_array_equal(np.asarray(a)[:real], np.asarray(b)[:real], err_msg=name)
+        if not (pages == arena.scratch_page).any():
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b), err_msg=name)
+    changed = np.asarray(want[0])[:real] != np.asarray(planes[0])[:real]
+    assert changed.any()  # the commit overwrote earlier data
+
+
 def test_kv_rail_walks_independently_of_weight_rails(setup):
     cfg, params, prompts = setup
     eng = ServingEngine(

@@ -11,6 +11,7 @@ The topology is described inside a module-scoped fixture (never at import):
 only the worker that runs this file loads the TPU compiler library.
 """
 
+import functools
 import os
 import re
 
@@ -21,7 +22,7 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.qwen3_0_6b import config as qwen3_config
-from repro.core.kvpages import KVGeometry, _gather_pages, _scatter_pages
+from repro.core.kvpages import KVGeometry, _commit_tokens, _gather_pages, _scatter_pages
 from repro.kernels import ecc_matmul, ecc_ssd, inject_scrub, ops, paged_gather, secded
 
 CFG = qwen3_config()
@@ -105,26 +106,36 @@ def test_gather_scrub_compiles(chip, pages):
     _compile(paged_gather.gather_scrub_2d, chip, p32, p32, p8, page_block=bp)
 
 
-@pytest.mark.parametrize("helper", ["gather", "scatter"])
+@pytest.mark.parametrize("helper", ["gather", "scatter", "commit"])
 def test_page_window_addressing_has_no_word_index(chip, helper):
-    """The interval scrub's page addressing at the qwen3-0.6b page width and
-    a 128-entry table: one int32 start offset per page, so the compiled
-    program holds no int32 array of P x W elements (a per-word index)."""
-    pages, words = 128, KVGeometry.from_config(CFG).words_per_page
+    """The KV store's window addressing at the qwen3-0.6b widths: the
+    interval scrub's pages over a 128-entry table, and the commit of 128
+    token rows. One int32 start offset per window, so the compiled program
+    holds no int32 array of one element per word moved (a per-word index)."""
+    geom = KVGeometry.from_config(CFG)
+    rows, words = 128, geom.words_per_page
     arena = ((115 * words,), jnp.uint32)
-    shapes = [arena, ((pages,), jnp.int32)]
-    fn = _gather_pages
-    if helper == "scatter":
-        shapes.append(((pages, words), jnp.uint32))
-        fn = _scatter_pages
+    ids = ((rows,), jnp.int32)
+    if helper == "gather":
+        fn, shapes = functools.partial(_gather_pages, words_per_page=words), [arena, ids]
+    elif helper == "scatter":
+        fn = functools.partial(_scatter_pages, words_per_page=words)
+        shapes = [arena, ids, ((rows, words), jnp.uint32)]
+    else:
+        words = geom.token_words
+        fn = functools.partial(
+            _commit_tokens, token_words=words, words_per_page=geom.words_per_page
+        )
+        shapes = [arena, arena, ((115 * geom.words_per_page,), jnp.uint8),
+                  ((rows, geom.token_f32), jnp.float32), ids, ids]
     args = [jax.ShapeDtypeStruct(s, dt, sharding=chip) for s, dt in shapes]
-    text = jax.jit(fn, static_argnums=len(args)).lower(*args, words).compile().as_text()
-    assert f"u32[{pages},{words}]" in text
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert f"u32[{rows},{words}]" in text
     for dims in re.findall(r"s32\[([\d,]+)\]", text):
         n = 1
         for d in dims.split(","):
             n *= int(d)
-        assert n < pages * words, f"s32[{dims}]"
+        assert n < rows * words, f"s32[{dims}]"
 
 
 def test_secded_encode_decode_compile(chip):
